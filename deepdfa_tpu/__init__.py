@@ -23,6 +23,17 @@ Layer map (ours; reference layers cited in each module's docstring):
 
 __version__ = "0.1.0"
 
+import os as _os
+
+# pandas 3 backs ``str`` columns with pyarrow, and with Arrow's default
+# allocator (mimalloc) ``pa.array`` segfaults when the extraction / encode
+# pool THREADS build string Indexes in a process that has also loaded jaxlib:
+# the extraction suite run alone died 5 of 9 times on an 8-core host, 0 of 6
+# with the system allocator. Arrow picks its pool when it is first loaded, so
+# the choice is made here, before anything in the package imports pandas;
+# an operator's own setting wins.
+_os.environ.setdefault("ARROW_DEFAULT_MEMORY_POOL", "system")
+
 from deepdfa_tpu.utils import (  # noqa: F401
     cache_dir,
     dfmp,

@@ -64,6 +64,7 @@ __all__ = [
     "solve_sets",
     "solve_bitvec",
     "solve_native",
+    "native_solver_status",
     "reaching_definitions",
     "liveness",
     "uninitialized",
@@ -386,6 +387,17 @@ def _try_native_lib() -> ctypes.CDLL | None:
             stacklevel=3,
         )
         return None
+
+
+def native_solver_status() -> str:
+    """Which dataflow backend this host runs: ``"native"`` when the C++
+    solver built (``make`` on first use) and loaded, else
+    ``"python-fallback: <reason>"`` — a machine without ``g++`` takes the
+    NumPy bit-vector solver after one warning, and a run should be able to
+    say so."""
+    if _try_native_lib() is not None:
+        return "native"
+    return f"python-fallback: {_NATIVE_ERROR}"
 
 
 def _pack_bits(mat: np.ndarray) -> np.ndarray:

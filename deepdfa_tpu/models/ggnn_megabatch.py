@@ -27,6 +27,8 @@ aggregation, graph-level labels, classifier head. The excluded variants
 
 from __future__ import annotations
 
+import logging
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -40,6 +42,8 @@ from deepdfa_tpu.ops.megabatch import (
     fused_ggnn_model,
     megabatch_reference,
 )
+
+logger = logging.getLogger("deepdfa_tpu")
 
 __all__ = ["GGNNMegabatch"]
 
@@ -162,7 +166,15 @@ class GGNNMegabatch(GGNN):
                 n_steps=cfg.n_steps, n_graphs=batch.max_graphs,
                 interpret=interpret, edges_sorted=True,
             )
-        # over-plan: bit-identical segment-twin math, same params
+        # over-plan: bit-identical segment-twin math, same params. Said out
+        # loud, once per traced shape — the Trainer counts the steps it
+        # routes itself, a direct model.apply has only this line
+        logger.warning(
+            "layout=megabatch: shape (%d nodes, %d edges, %d graphs) is "
+            "over the whole-model kernel's plan (%.1f MiB working set) — "
+            "computing through the segment-twin math",
+            batch.max_nodes, batch.senders.shape[0], batch.max_graphs,
+            plan.working_set / 2**20)
         return megabatch_reference(
             table, ids, batch.senders, batch.receivers,
             batch.node_gidx, batch.node_mask,

@@ -1,7 +1,7 @@
 """Perf-regression ledger — rolling-baseline verdicts over bench history.
 
-Five generations of ``BENCH_r0*.json`` / ``MULTICHIP_r0*.json`` sit in the
-repo root with no trend tracking; Morphling and the GNN-acceleration survey
+Generations of ``BENCH_*.json`` / ``MULTICHIP_r0*.json`` artifacts sit in
+the repo root with no trend tracking; Morphling and the GNN-acceleration survey
 (PAPERS.md) both stress that fused-kernel wins are fragile across code
 revisions. This module is the perf twin of :mod:`deepdfa_tpu.obs.drift`:
 where drift judges score *distributions* against a frozen reference, the

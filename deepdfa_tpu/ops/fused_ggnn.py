@@ -62,7 +62,9 @@ __all__ = [
     "fits_vmem",
     "train_working_set_bytes",
     "fits_vmem_train",
+    "edge_smem_bytes",
     "VMEM_CAP_BYTES",
+    "SMEM_CAP_BYTES",
 ]
 
 
@@ -83,6 +85,28 @@ def _round_up(a: int, b: int) -> int:
 # bucketing can emit so a config change fails in CI rather than on-chip.
 VMEM_BYTES = 128 * 2**20
 VMEM_CAP_BYTES = 96 * 2**20
+# SMEM is 1 MiB per core on the v5e (the compiler reports "Used 1.00M of
+# 1.00M smem" at 2 x 512 KiB of prefetched operands plus 144 B of its own);
+# the edge indices live there, so the plans cap them with a margin.
+SMEM_CAP_BYTES = 960 * 2**10
+
+# The plan's cap IS the scoped-VMEM limit handed to Mosaic: without
+# ``vmem_limit_bytes`` the compiler applies its small default scoped limit
+# and refuses buckets the plan admits. The grid axis is the round index —
+# sequential by construction, never sharded across cores.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary",),
+    vmem_limit_bytes=VMEM_CAP_BYTES,
+)
+
+
+def _resident(shape) -> pl.BlockSpec:
+    """Whole-array VMEM block with a constant index map. Single-buffered:
+    the block index never changes across the round grid, so the default
+    double buffer would only double what the working-set plans count."""
+    return pl.BlockSpec(shape, lambda *_: (0,) * len(shape),
+                        pipeline_mode=pl.Buffered(1),
+                        memory_space=pltpu.VMEM)
 
 
 def working_set_bytes(n_nodes: int, n_edges: int, width: int) -> int:
@@ -92,9 +116,11 @@ def working_set_bytes(n_nodes: int, n_edges: int, width: int) -> int:
     and ``agg`` scratch), the GRU intermediates (two 3-gate projection
     outputs plus the r/z/n gate temps — transient, but Mosaic materialises
     vector temporaries in VMEM), the padded weight/bias blocks, and the
-    edge index vectors (stored ``(1, E)`` so the lane axis carries E; the
-    sublane axis pads to 8). Shapes are padded exactly as the wrapper pads
-    them.
+    edge index vectors. The indices are scalar-prefetched into SMEM (the
+    edge loop addresses VMEM rows with them; :func:`edge_smem_bytes` is
+    their real budget) — their sublane-padded size stays in this count as
+    headroom for Mosaic's own scratch. Shapes are padded exactly as the
+    wrapper pads them.
     """
     np_ = _round_up(max(n_nodes, 8), 8)
     dp = _round_up(max(width, 1), 128)
@@ -106,11 +132,18 @@ def working_set_bytes(n_nodes: int, n_edges: int, width: int) -> int:
     return node_blocks + gru_temps + weights + edges
 
 
+def edge_smem_bytes(n_edges: int) -> int:
+    """SMEM footprint of the scalar-prefetched sender/receiver vectors
+    (1-D int32, padded exactly as the wrapper pads them)."""
+    return 2 * _round_up(max(n_edges, 1), 128) * 4
+
+
 def fits_vmem(n_nodes: int, n_edges: int, width: int) -> bool:
     """Whether a bucket shape is safe for the fused kernel on-chip. Buckets
-    over the cap (e.g. the worst-case overflow rescue bucket) take the
+    over either cap (e.g. the worst-case overflow rescue bucket) take the
     segment-layout fallback — correctness is never gated on VMEM."""
-    return working_set_bytes(n_nodes, n_edges, width) <= VMEM_CAP_BYTES
+    return (working_set_bytes(n_nodes, n_edges, width) <= VMEM_CAP_BYTES
+            and edge_smem_bytes(n_edges) <= SMEM_CAP_BYTES)
 
 
 def train_working_set_bytes(
@@ -149,6 +182,7 @@ def fits_vmem_train(
     return (
         train_working_set_bytes(n_nodes, n_edges, width, n_steps)
         <= VMEM_CAP_BYTES
+        and edge_smem_bytes(n_edges) <= SMEM_CAP_BYTES
     )
 
 
@@ -166,7 +200,21 @@ def _pack_gate_bias(b: jnp.ndarray, d: int, dp: int) -> jnp.ndarray:
     return b3.reshape(1, 3 * dp)
 
 
-def _kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
+def _pad_conv_weights(ew, eb, xw, xb, hw, hb, d: int, dp: int):
+    """The conv's six weight operands as f32 blocks padded to the lane
+    tile, gates packed per gate (every kernel wrapper pads them alike)."""
+    f32 = jnp.float32
+    return (
+        jnp.pad(ew.astype(f32), ((0, dp - d), (0, dp - d))),
+        jnp.pad(eb.astype(f32), (0, dp - d)).reshape(1, dp),
+        _pack_gates(xw.astype(f32), d, dp),
+        _pack_gate_bias(xb.astype(f32), d, dp),
+        _pack_gates(hw.astype(f32), d, dp),
+        _pack_gate_bias(hb.astype(f32), d, dp),
+    )
+
+
+def _kernel(snd_ref, rcv_ref, h0_ref, ew_ref, eb_ref, xw_ref, xb_ref,
             hw_ref, hb_ref, out_ref, msg_ref, agg_ref, *, n_edges: int,
             width: int):
     """One message round. Grid axis 0 is the round index: TPU executes the
@@ -191,8 +239,8 @@ def _kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
     # by receiver (the ``batch_np`` contract), so this loop IS the sorted-
     # segment sum — at VMEM latency instead of the HBM scatter path.
     def edge_body(e, carry):
-        s = snd_ref[0, e]
-        r = rcv_ref[0, e]
+        s = snd_ref[e]
+        r = rcv_ref[e]
         agg_ref[pl.ds(r, 1), :] += msg_ref[pl.ds(s, 1), :]
         return carry
 
@@ -218,7 +266,7 @@ def _unpack_gate_bias(bp: jnp.ndarray, d: int, dp: int) -> jnp.ndarray:
     return bp.reshape(3, dp)[:, :d].reshape(3 * d)
 
 
-def _train_kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
+def _train_kernel(snd_ref, rcv_ref, h0_ref, ew_ref, eb_ref, xw_ref, xb_ref,
                   hw_ref, hb_ref, g_ref,
                   dh0_ref, dew_ref, deb_ref, dxw_ref, dxb_ref, dhw_ref,
                   dhb_ref, hist_ref, hcur_ref, msg_ref, agg_ref, dagg_ref,
@@ -258,8 +306,8 @@ def _train_kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
         agg_ref[:] = jnp.zeros_like(agg_ref)
 
         def edge_body(e, carry):
-            s = snd_ref[0, e]
-            r = rcv_ref[0, e]
+            s = snd_ref[e]
+            r = rcv_ref[e]
             agg_ref[pl.ds(r, 1), :] += msg_ref[pl.ds(s, 1), :]
             return carry
 
@@ -290,8 +338,8 @@ def _train_kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
         agg_ref[:] = jnp.zeros_like(agg_ref)
 
         def edge_body(e, carry):
-            s = snd_ref[0, e]
-            r = rcv_ref[0, e]
+            s = snd_ref[e]
+            r = rcv_ref[e]
             agg_ref[pl.ds(r, 1), :] += msg_ref[pl.ds(s, 1), :]
             return carry
 
@@ -331,8 +379,8 @@ def _train_kernel(h0_ref, snd_ref, rcv_ref, ew_ref, eb_ref, xw_ref, xb_ref,
         dmsg_ref[:] = jnp.zeros_like(dmsg_ref)
 
         def edge_body_t(e, carry):
-            s = snd_ref[0, e]
-            r = rcv_ref[0, e]
+            s = snd_ref[e]
+            r = rcv_ref[e]
             dmsg_ref[pl.ds(s, 1), :] += dagg_ref[pl.ds(r, 1), :]
             return carry
 
@@ -358,41 +406,44 @@ def _pallas_train_bwd(h0, senders, receivers, ew, eb, xw, xb, hw, hb, g,
 
     h0p = jnp.pad(h0.astype(jnp.float32), ((0, np_ - n), (0, dp - d)))
     gp = jnp.pad(g.astype(jnp.float32), ((0, np_ - n), (0, dp - d)))
-    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    ewp = jnp.pad(ew.astype(jnp.float32), ((0, dp - d), (0, dp - d)))
-    ebp = jnp.pad(eb.astype(jnp.float32), (0, dp - d)).reshape(1, dp)
-    xwp = _pack_gates(xw.astype(jnp.float32), d, dp)
-    xbp = _pack_gate_bias(xb.astype(jnp.float32), d, dp)
-    hwp = _pack_gates(hw.astype(jnp.float32), d, dp)
-    hbp = _pack_gate_bias(hb.astype(jnp.float32), d, dp)
+    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e))
+    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e))
+    ewp, ebp, xwp, xbp, hwp, hbp = _pad_conv_weights(
+        ew, eb, xw, xb, hw, hb, d, dp)
 
-    full = lambda shape: pl.BlockSpec(shape, lambda s: tuple(0 for _ in shape),
-                                      memory_space=pltpu.VMEM)
     outs = pl.pallas_call(
         functools.partial(_train_kernel, n_edges=e, width=dp, n_steps=n_steps),
-        grid=(2 * n_steps,),
-        in_specs=[
-            full((np_, dp)),            # h0
-            full((1, ep)),              # senders
-            full((1, ep)),              # receivers
-            full((dp, dp)),             # edge_linear kernel
-            full((1, dp)),              # edge_linear bias
-            full((dp, 3 * dp)),         # gru x_proj kernel
-            full((1, 3 * dp)),          # gru x_proj bias
-            full((dp, 3 * dp)),         # gru h_proj kernel
-            full((1, 3 * dp)),          # gru h_proj bias
-            full((np_, dp)),            # incoming cotangent g
-        ],
-        out_specs=[
-            full((np_, dp)),            # dh0 (doubles as the dh carry)
-            full((dp, dp)),             # dew
-            full((1, dp)),              # deb
-            full((dp, 3 * dp)),         # dxw
-            full((1, 3 * dp)),          # dxb
-            full((dp, 3 * dp)),         # dhw
-            full((1, 3 * dp)),          # dhb
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,      # senders, receivers → SMEM
+            grid=(2 * n_steps,),
+            in_specs=[
+                _resident((np_, dp)),           # h0
+                _resident((dp, dp)),            # edge_linear kernel
+                _resident((1, dp)),             # edge_linear bias
+                _resident((dp, 3 * dp)),        # gru x_proj kernel
+                _resident((1, 3 * dp)),         # gru x_proj bias
+                _resident((dp, 3 * dp)),        # gru h_proj kernel
+                _resident((1, 3 * dp)),         # gru h_proj bias
+                _resident((np_, dp)),           # incoming cotangent g
+            ],
+            out_specs=[
+                _resident((np_, dp)),           # dh0 (doubles as the dh carry)
+                _resident((dp, dp)),            # dew
+                _resident((1, dp)),             # deb
+                _resident((dp, 3 * dp)),        # dxw
+                _resident((1, 3 * dp)),         # dxb
+                _resident((dp, 3 * dp)),        # dhw
+                _resident((1, 3 * dp)),         # dhb
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((n_steps, np_, dp), jnp.float32),   # hist
+                pltpu.VMEM((np_, dp), jnp.float32),            # hcur
+                pltpu.VMEM((np_, dp), jnp.float32),            # msg
+                pltpu.VMEM((np_, dp), jnp.float32),            # agg
+                pltpu.VMEM((np_, dp), jnp.float32),            # dagg
+                pltpu.VMEM((np_, dp), jnp.float32),            # dmsg
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((np_, dp), jnp.float32),
             jax.ShapeDtypeStruct((dp, dp), jnp.float32),
@@ -402,16 +453,9 @@ def _pallas_train_bwd(h0, senders, receivers, ew, eb, xw, xb, hw, hb, g,
             jax.ShapeDtypeStruct((dp, 3 * dp), jnp.float32),
             jax.ShapeDtypeStruct((1, 3 * dp), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((n_steps, np_, dp), jnp.float32),   # hist
-            pltpu.VMEM((np_, dp), jnp.float32),            # hcur
-            pltpu.VMEM((np_, dp), jnp.float32),            # msg
-            pltpu.VMEM((np_, dp), jnp.float32),            # agg
-            pltpu.VMEM((np_, dp), jnp.float32),            # dagg
-            pltpu.VMEM((np_, dp), jnp.float32),            # dmsg
-        ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(h0p, sndp, rcvp, ewp, ebp, xwp, xbp, hwp, hbp, gp)
+    )(sndp, rcvp, h0p, ewp, ebp, xwp, xbp, hwp, hbp, gp)
     dh0p, dewp, debp, dxwp, dxbp, dhwp, dhbp = outs
     return (
         dh0p[:n, :d],
@@ -461,41 +505,35 @@ def _fused_ggnn(h0, senders, receivers, ew, eb, xw, xb, hw, hb,
     ep = _round_up(max(e, 1), 128)
 
     h0p = jnp.pad(h0.astype(jnp.float32), ((0, np_ - n), (0, dp - d)))
-    # (1, E) layout: the lane axis carries E (a padded (E, 1) column would
-    # burn 128 lanes per edge index)
-    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    ewp = jnp.pad(ew.astype(jnp.float32), ((0, dp - d), (0, dp - d)))
-    ebp = jnp.pad(eb.astype(jnp.float32), (0, dp - d)).reshape(1, dp)
-    xwp = _pack_gates(xw.astype(jnp.float32), d, dp)
-    xbp = _pack_gate_bias(xb.astype(jnp.float32), d, dp)
-    hwp = _pack_gates(hw.astype(jnp.float32), d, dp)
-    hbp = _pack_gate_bias(hb.astype(jnp.float32), d, dp)
+    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e))
+    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e))
+    ewp, ebp, xwp, xbp, hwp, hbp = _pad_conv_weights(
+        ew, eb, xw, xb, hw, hb, d, dp)
 
-    full = lambda shape: pl.BlockSpec(shape, lambda s: (0, 0),
-                                      memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_kernel, n_edges=e, width=dp),
-        grid=(n_steps,),
-        in_specs=[
-            full((np_, dp)),            # h0
-            full((1, ep)),              # senders
-            full((1, ep)),              # receivers
-            full((dp, dp)),             # edge_linear kernel
-            full((1, dp)),              # edge_linear bias
-            full((dp, 3 * dp)),         # gru x_proj kernel
-            full((1, 3 * dp)),          # gru x_proj bias
-            full((dp, 3 * dp)),         # gru h_proj kernel
-            full((1, 3 * dp)),          # gru h_proj bias
-        ],
-        out_specs=full((np_, dp)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,      # senders, receivers → SMEM
+            grid=(n_steps,),
+            in_specs=[
+                _resident((np_, dp)),           # h0
+                _resident((dp, dp)),            # edge_linear kernel
+                _resident((1, dp)),             # edge_linear bias
+                _resident((dp, 3 * dp)),        # gru x_proj kernel
+                _resident((1, 3 * dp)),         # gru x_proj bias
+                _resident((dp, 3 * dp)),        # gru h_proj kernel
+                _resident((1, 3 * dp)),         # gru h_proj bias
+            ],
+            out_specs=_resident((np_, dp)),
+            scratch_shapes=[
+                pltpu.VMEM((np_, dp), jnp.float32),   # msg
+                pltpu.VMEM((np_, dp), jnp.float32),   # agg
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((np_, dp), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((np_, dp), jnp.float32),   # msg
-            pltpu.VMEM((np_, dp), jnp.float32),   # agg
-        ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(h0p, sndp, rcvp, ewp, ebp, xwp, xbp, hwp, hbp)
+    )(sndp, rcvp, h0p, ewp, ebp, xwp, xbp, hwp, hbp)
     return out[:n, :d]
 
 

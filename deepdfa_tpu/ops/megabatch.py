@@ -51,9 +51,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepdfa_tpu.data.graphs import BatchedGraphs, Graph, batch_np, padding_efficiency
 from deepdfa_tpu.ops.fused_ggnn import (
+    _COMPILER_PARAMS,
+    SMEM_CAP_BYTES,
     VMEM_CAP_BYTES,
+    _pad_conv_weights,
+    _resident,
     _round_up,
     _unrolled_reference,
+    edge_smem_bytes,
     working_set_bytes,
 )
 from deepdfa_tpu.ops.segment import segment_softmax, segment_sum
@@ -62,6 +67,7 @@ __all__ = [
     "MegabatchPlan",
     "PackResult",
     "megabatch_working_set_bytes",
+    "megabatch_smem_bytes",
     "fits_vmem_megabatch",
     "pack_megabatches",
     "fused_ggnn_model",
@@ -87,19 +93,21 @@ def megabatch_working_set_bytes(
 
     On top of the message-passing forward's blocks (node states, GRU temps,
     conv weights, edge vectors) the single launch must also hold: the
-    stacked embedding table and id rows (prologue), the node→graph one-hot
+    lane-placed embedding table (full conv width per row — ``embed_width``
+    only fixes which lanes a sub-table owns), the node→graph one-hot
     matrix and its masked-max temp (the pooling softmax runs as MXU
     matmuls against it), the ``concat([h, h0])`` block, the gate/head
-    weights, and the per-graph activations of the classifier head.
+    weights, and the per-graph activations of the classifier head. The id
+    rows are scalar-prefetched (:func:`megabatch_smem_bytes`); their
+    sublane-padded size stays in this count as headroom, like the edges'.
     """
     np_ = _round_up(max(n_nodes, 8), 8)
     dp = _round_up(max(width, 1), 128)
     gp = _round_up(max(n_graphs, 1), 128)
     tp = _round_up(max(table_rows, 8), 8)
-    edp = _round_up(max(embed_width, 1), 128)
     npl = _round_up(np_, 128)
     base = working_set_bytes(n_nodes, n_edges, width)
-    table = tp * edp * 4
+    table = tp * dp * 4
     ids = 8 * npl * 4
     gidx_mask = 2 * np_ * 128 * 4          # gidx + node-mask columns
     onehot = 2 * np_ * gp * 4              # M and the masked-max temp S
@@ -116,6 +124,14 @@ def megabatch_working_set_bytes(
             + gate_w + head_w + head_act + out + small)
 
 
+def megabatch_smem_bytes(n_nodes: int, n_edges: int, n_sub: int) -> int:
+    """SMEM footprint of the whole-model kernel's scalar-prefetched
+    operands: the edge vectors plus ``n_sub`` id rows of the padded node
+    count (flattened 1-D int32, as the wrapper builds them)."""
+    np_ = _round_up(max(n_nodes, 8), 8)
+    return edge_smem_bytes(n_edges) + n_sub * np_ * 4
+
+
 def fits_vmem_megabatch(
     n_nodes: int,
     n_edges: int,
@@ -129,10 +145,14 @@ def fits_vmem_megabatch(
     """Whether a megabatch shape is safe for the whole-model kernel. Shapes
     over the plan route bit-identically to the segment twin
     (:func:`megabatch_reference`) — correctness is never gated on VMEM."""
-    return megabatch_working_set_bytes(
-        n_nodes, n_edges, width, n_graphs, table_rows=table_rows,
-        embed_width=embed_width, n_head_layers=n_head_layers,
-    ) <= VMEM_CAP_BYTES
+    n_sub = max(width // max(embed_width, 1), 1)
+    return (
+        megabatch_working_set_bytes(
+            n_nodes, n_edges, width, n_graphs, table_rows=table_rows,
+            embed_width=embed_width, n_head_layers=n_head_layers,
+        ) <= VMEM_CAP_BYTES
+        and megabatch_smem_bytes(n_nodes, n_edges, n_sub) <= SMEM_CAP_BYTES
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,7 +180,11 @@ class MegabatchPlan:
 
     @property
     def fits(self) -> bool:
-        return self.working_set <= VMEM_CAP_BYTES
+        return fits_vmem_megabatch(
+            self.max_nodes, self.max_edges, self.width, self.max_graphs,
+            table_rows=self.table_rows, embed_width=self.embed_width,
+            n_head_layers=self.n_head_layers,
+        )
 
 
 @dataclasses.dataclass
@@ -305,19 +329,23 @@ def pack_megabatches(
 # --------------------------------------------------------------------------
 
 
-def _model_kernel(table_ref, ids_ref, snd_ref, rcv_ref, gidx_ref, mask_ref,
+def _model_kernel(ids_ref, snd_ref, rcv_ref, table_ref, gidx_ref, mask_ref,
                   ew_ref, eb_ref, xw_ref, xb_ref, hw_ref, hb_ref,
                   gw_ref, gb_ref, *rest, n_nodes: int, n_edges: int,
-                  n_sub: int, embed_w: int, width: int, n_steps: int,
+                  n_sub: int, ids_stride: int, width: int, n_steps: int,
                   gp: int, n_layers: int, encoder: bool = False):
     """One grid step of the whole-model forward. Grid ``(n_steps + 1,)``,
     executed sequentially on TPU, so the node-state scratch persists across
     the prologue, every message round, and the epilogue:
 
     - step 0 prologue: gather the stacked embedding table rows into the
-      node states (``n_sub`` static sub-tables, each ``embed_w`` lanes of
-      a row write — the fused single-gather of ``GGNN.embed_nodes`` as an
-      in-VMEM loop) and bank a copy for the classifier concat;
+      node states. The wrapper lays sub-table ``k``'s rows out in lanes
+      ``[k·embed, (k+1)·embed)`` of a full-width row (zeros elsewhere), so
+      a node's embedding is the SUM of its ``n_sub`` gathered rows — exact
+      in f32 (``x + 0``), and every load/store is a whole 128-lane row
+      instead of a 32-lane write at a lane offset. The ids are scalar-
+      prefetched (SMEM, ``ids_ref[k·ids_stride + i]``): they address VMEM
+      rows. A copy is banked for the classifier concat;
     - steps ``0..n_steps-1``: the fused message round (identical math to
       ``ops.fused_ggnn._kernel``);
     - step ``n_steps`` epilogue: attention pooling as matmuls against the
@@ -338,11 +366,10 @@ def _model_kernel(table_ref, ids_ref, snd_ref, rcv_ref, gidx_ref, mask_ref,
         hcur_ref[:] = jnp.zeros_like(hcur_ref)
 
         def node_body(i, carry):
-            for k in range(n_sub):
-                idk = ids_ref[k, i]
-                hcur_ref[pl.ds(i, 1), k * embed_w:(k + 1) * embed_w] = (
-                    table_ref[pl.ds(idk, 1), :embed_w]
-                )
+            row = table_ref[pl.ds(ids_ref[i], 1), :]
+            for k in range(1, n_sub):
+                row = row + table_ref[pl.ds(ids_ref[k * ids_stride + i], 1), :]
+            hcur_ref[pl.ds(i, 1), :] = row
             return carry
 
         jax.lax.fori_loop(0, n_nodes, node_body, 0)
@@ -357,8 +384,8 @@ def _model_kernel(table_ref, ids_ref, snd_ref, rcv_ref, gidx_ref, mask_ref,
         agg_ref[:] = jnp.zeros_like(agg_ref)
 
         def edge_body(e, carry):
-            s = snd_ref[0, e]
-            r = rcv_ref[0, e]
+            s = snd_ref[e]
+            r = rcv_ref[e]
             agg_ref[pl.ds(r, 1), :] += msg_ref[pl.ds(s, 1), :]
             return carry
 
@@ -440,6 +467,105 @@ def _pack_half_cols(w2: jnp.ndarray, d: int, dp: int) -> jnp.ndarray:
 def _pack_half_bias(b: jnp.ndarray, d: int, dp: int) -> jnp.ndarray:
     b2 = jnp.pad(b.reshape(2, d), ((0, 0), (0, dp - d)))
     return b2.reshape(1, 2 * dp)
+
+
+def _model_call(table, ids, senders, receivers, gidx, mask,
+                ew, eb, xw, xb, hw, hb, gw, gb, head, *,
+                n_steps: int, n_graphs: int, interpret: bool,
+                encoder: bool) -> jnp.ndarray:
+    """Pad every operand to its tile, lay the embedding table out for the
+    whole-row prologue gather, and launch :func:`_model_kernel` once.
+    Returns the PADDED output block: ``(gp, 2·dp)`` pooled embeddings in
+    the packed-half layout when ``encoder``, else ``(gp, 128)`` logits in
+    lane 0."""
+    n, n_sub = ids.shape
+    e = senders.shape[0]
+    d = ew.shape[0]
+    ed = table.shape[1]
+    t_rows = table.shape[0]
+    if n_sub * ed != d:
+        raise ValueError(
+            f"embed width {n_sub}·{ed} != conv width {d} — the whole-model "
+            "kernel requires the concat-subkey config (embed == hidden)")
+    if t_rows % n_sub:
+        raise ValueError(
+            f"stacked table has {t_rows} rows, not a multiple of the "
+            f"{n_sub} sub-tables")
+    np_ = _round_up(max(n, 8), 8)
+    dp = _round_up(max(d, 1), 128)
+    ep = _round_up(max(e, 1), 128)
+    gp = _round_up(max(n_graphs, 1), 128)
+    tp = _round_up(max(t_rows, 8), 8)
+    f32 = jnp.float32
+
+    # sub-table k (rows [k·input_dim, (k+1)·input_dim)) lands in lanes
+    # [k·ed, (k+1)·ed): summing a node's n_sub gathered rows IS the concat
+    sub = jnp.arange(t_rows, dtype=jnp.int32) // (t_rows // n_sub)
+    lane_sub = jnp.arange(d, dtype=jnp.int32) // ed
+    tablep = jnp.where(sub[:, None] == lane_sub[None, :],
+                       jnp.tile(table.astype(f32), (1, n_sub)), 0.0)
+    tablep = jnp.pad(tablep, ((0, tp - t_rows), (0, dp - d)))
+    idsp = jnp.pad(ids.astype(jnp.int32).T, ((0, 0), (0, np_ - n))).reshape(-1)
+    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e))
+    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e))
+    gidxp = jnp.pad(gidx.astype(jnp.int32)[:, None],
+                    ((0, np_ - n), (0, 127)))
+    maskp = jnp.pad(mask.astype(f32)[:, None], ((0, np_ - n), (0, 127)))
+    ewp, ebp, xwp, xbp, hwp, hbp = _pad_conv_weights(
+        ew, eb, xw, xb, hw, hb, d, dp)
+    gwp = _pack_half_rows(gw.astype(f32), d, dp, 128)
+    gbp = jnp.pad(gb.astype(f32), (0, 127)).reshape(1, 128)
+    n_layers = len(head)
+    head_p: list[jnp.ndarray] = []
+    head_specs = []
+    for li, (w, b) in enumerate(head):
+        if li == n_layers - 1:
+            head_p.append(_pack_half_rows(w.astype(f32), d, dp, 128))
+            head_p.append(jnp.pad(b.astype(f32), (0, 127)).reshape(1, 128))
+            head_specs += [_resident((2 * dp, 128)), _resident((1, 128))]
+        else:
+            wp = _pack_half_rows(w.astype(f32), d, dp, 2 * d)
+            head_p.append(_pack_half_cols(wp, d, dp))
+            head_p.append(_pack_half_bias(b.astype(f32), d, dp))
+            head_specs += [_resident((2 * dp, 2 * dp)),
+                           _resident((1, 2 * dp))]
+    out_cols = 2 * dp if encoder else 128
+    return pl.pallas_call(
+        functools.partial(
+            _model_kernel, n_nodes=n, n_edges=e, n_sub=n_sub, ids_stride=np_,
+            width=dp, n_steps=n_steps, gp=gp, n_layers=n_layers,
+            encoder=encoder),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # ids, senders, receivers → SMEM
+            grid=(n_steps + 1,),
+            in_specs=[
+                _resident((tp, dp)),            # lane-placed embedding table
+                _resident((np_, 128)),          # node_gidx column
+                _resident((np_, 128)),          # node_mask column
+                _resident((dp, dp)),            # edge_linear kernel
+                _resident((1, dp)),             # edge_linear bias
+                _resident((dp, 3 * dp)),        # gru x_proj kernel
+                _resident((1, 3 * dp)),         # gru x_proj bias
+                _resident((dp, 3 * dp)),        # gru h_proj kernel
+                _resident((1, 3 * dp)),         # gru h_proj bias
+                _resident((2 * dp, 128)),       # pooling gate kernel
+                _resident((1, 128)),            # pooling gate bias
+                *head_specs,
+            ],
+            out_specs=_resident((gp, out_cols)),
+            scratch_shapes=[
+                pltpu.VMEM((np_, dp), f32),       # hcur (node states)
+                pltpu.VMEM((np_, dp), f32),       # h0 bank (classifier concat)
+                pltpu.VMEM((np_, dp), f32),       # msg
+                pltpu.VMEM((np_, dp), f32),       # agg
+                pltpu.VMEM((np_, 2 * dp), f32),   # hcat
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((gp, out_cols), f32),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(idsp, sndp, rcvp, tablep, gidxp, maskp, ewp, ebp, xwp, xbp, hwp, hbp,
+      gwp, gbp, *head_p)
 
 
 def megabatch_reference(table, ids, senders, receivers, gidx, mask,
@@ -528,77 +654,12 @@ def fused_ggnn_encoder(
     params). Callers are expected to check :func:`fits_vmem_megabatch`
     and route over-plan shapes to :func:`megabatch_encoder_reference`.
     """
-    n, n_sub = ids.shape
-    e = senders.shape[0]
     d = ew.shape[0]
-    ed = table.shape[1]
-    t_rows = table.shape[0]
-    if n_sub * ed != d:
-        raise ValueError(
-            f"embed width {n_sub}·{ed} != conv width {d} — the whole-model "
-            "kernel requires the concat-subkey config (embed == hidden)")
-    np_ = _round_up(max(n, 8), 8)
     dp = _round_up(max(d, 1), 128)
-    ep = _round_up(max(e, 1), 128)
-    gp = _round_up(max(n_graphs, 1), 128)
-    tp = _round_up(max(t_rows, 8), 8)
-    edp = _round_up(max(ed, 1), 128)
-    npl = _round_up(np_, 128)
-    f32 = jnp.float32
-
-    from deepdfa_tpu.ops.fused_ggnn import _pack_gate_bias, _pack_gates
-
-    tablep = jnp.pad(table.astype(f32), ((0, tp - t_rows), (0, edp - ed)))
-    idsp = jnp.pad(ids.astype(jnp.int32).T, ((0, 8 - n_sub), (0, npl - n)))
-    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    gidxp = jnp.pad(gidx.astype(jnp.int32)[:, None],
-                    ((0, np_ - n), (0, 127)))
-    maskp = jnp.pad(mask.astype(f32)[:, None], ((0, np_ - n), (0, 127)))
-    ewp = jnp.pad(ew.astype(f32), ((0, dp - d), (0, dp - d)))
-    ebp = jnp.pad(eb.astype(f32), (0, dp - d)).reshape(1, dp)
-    xwp = _pack_gates(xw.astype(f32), d, dp)
-    xbp = _pack_gate_bias(xb.astype(f32), d, dp)
-    hwp = _pack_gates(hw.astype(f32), d, dp)
-    hbp = _pack_gate_bias(hb.astype(f32), d, dp)
-    gwp = _pack_half_rows(gw.astype(f32), d, dp, 128)
-    gbp = jnp.pad(gb.astype(f32), (0, 127)).reshape(1, 128)
-
-    full = lambda shape: pl.BlockSpec(shape, lambda s: tuple(0 for _ in shape),
-                                      memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(
-            _model_kernel, n_nodes=n, n_edges=e, n_sub=n_sub, embed_w=ed,
-            width=dp, n_steps=n_steps, gp=gp, n_layers=0, encoder=True),
-        grid=(n_steps + 1,),
-        in_specs=[
-            full((tp, edp)),            # stacked embedding table
-            full((8, npl)),             # per-subkey offset ids
-            full((1, ep)),              # senders
-            full((1, ep)),              # receivers
-            full((np_, 128)),           # node_gidx column
-            full((np_, 128)),           # node_mask column
-            full((dp, dp)),             # edge_linear kernel
-            full((1, dp)),              # edge_linear bias
-            full((dp, 3 * dp)),         # gru x_proj kernel
-            full((1, 3 * dp)),          # gru x_proj bias
-            full((dp, 3 * dp)),         # gru h_proj kernel
-            full((1, 3 * dp)),          # gru h_proj bias
-            full((2 * dp, 128)),        # pooling gate kernel
-            full((1, 128)),             # pooling gate bias
-        ],
-        out_specs=full((gp, 2 * dp)),
-        out_shape=jax.ShapeDtypeStruct((gp, 2 * dp), f32),
-        scratch_shapes=[
-            pltpu.VMEM((np_, dp), f32),       # hcur (node states)
-            pltpu.VMEM((np_, dp), f32),       # h0 bank (classifier concat)
-            pltpu.VMEM((np_, dp), f32),       # msg
-            pltpu.VMEM((np_, dp), f32),       # agg
-            pltpu.VMEM((np_, 2 * dp), f32),   # hcat
-        ],
-        interpret=interpret,
-    )(tablep, idsp, sndp, rcvp, gidxp, maskp, ewp, ebp, xwp, xbp, hwp, hbp,
-      gwp, gbp)
+    out = _model_call(table, ids, senders, receivers, gidx, mask,
+                      ew, eb, xw, xb, hw, hb, gw, gb, (),
+                      n_steps=n_steps, n_graphs=n_graphs,
+                      interpret=interpret, encoder=True)
     # unpad the packed-half layout [h (dp) | h0 (dp)] back to [2·d]
     return jnp.concatenate(
         [out[:n_graphs, :d], out[:n_graphs, dp:dp + d]], axis=-1)
@@ -609,94 +670,10 @@ def _megabatch_model(table, ids, senders, receivers, gidx, mask,
                      ew, eb, xw, xb, hw, hb, gw, gb, head,
                      n_steps: int, n_graphs: int, interpret: bool,
                      edges_sorted: bool):
-    n, n_sub = ids.shape
-    e = senders.shape[0]
-    d = ew.shape[0]
-    ed = table.shape[1]
-    t_rows = table.shape[0]
-    if n_sub * ed != d:
-        raise ValueError(
-            f"embed width {n_sub}·{ed} != conv width {d} — the whole-model "
-            "kernel requires the concat-subkey config (embed == hidden)")
-    np_ = _round_up(max(n, 8), 8)
-    dp = _round_up(max(d, 1), 128)
-    ep = _round_up(max(e, 1), 128)
-    gp = _round_up(max(n_graphs, 1), 128)
-    tp = _round_up(max(t_rows, 8), 8)
-    edp = _round_up(max(ed, 1), 128)
-    npl = _round_up(np_, 128)
-    f32 = jnp.float32
-
-    from deepdfa_tpu.ops.fused_ggnn import _pack_gate_bias, _pack_gates
-
-    tablep = jnp.pad(table.astype(f32), ((0, tp - t_rows), (0, edp - ed)))
-    idsp = jnp.pad(ids.astype(jnp.int32).T, ((0, 8 - n_sub), (0, npl - n)))
-    sndp = jnp.pad(senders.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    rcvp = jnp.pad(receivers.astype(jnp.int32), (0, ep - e)).reshape(1, ep)
-    gidxp = jnp.pad(gidx.astype(jnp.int32)[:, None],
-                    ((0, np_ - n), (0, 127)))
-    maskp = jnp.pad(mask.astype(f32)[:, None], ((0, np_ - n), (0, 127)))
-    ewp = jnp.pad(ew.astype(f32), ((0, dp - d), (0, dp - d)))
-    ebp = jnp.pad(eb.astype(f32), (0, dp - d)).reshape(1, dp)
-    xwp = _pack_gates(xw.astype(f32), d, dp)
-    xbp = _pack_gate_bias(xb.astype(f32), d, dp)
-    hwp = _pack_gates(hw.astype(f32), d, dp)
-    hbp = _pack_gate_bias(hb.astype(f32), d, dp)
-    gwp = _pack_half_rows(gw.astype(f32), d, dp, 128)
-    gbp = jnp.pad(gb.astype(f32), (0, 127)).reshape(1, 128)
-    n_layers = len(head)
-    head_p: list[jnp.ndarray] = []
-    for li, (w, b) in enumerate(head):
-        if li == n_layers - 1:
-            head_p.append(_pack_half_rows(w.astype(f32), d, dp, 128))
-            head_p.append(jnp.pad(b.astype(f32), (0, 127)).reshape(1, 128))
-        else:
-            wp = _pack_half_rows(w.astype(f32), d, dp, 2 * d)
-            head_p.append(_pack_half_cols(wp, d, dp))
-            head_p.append(_pack_half_bias(b.astype(f32), d, dp))
-
-    full = lambda shape: pl.BlockSpec(shape, lambda s: tuple(0 for _ in shape),
-                                      memory_space=pltpu.VMEM)
-    head_specs = []
-    for li in range(n_layers):
-        if li == n_layers - 1:
-            head_specs += [full((2 * dp, 128)), full((1, 128))]
-        else:
-            head_specs += [full((2 * dp, 2 * dp)), full((1, 2 * dp))]
-    out = pl.pallas_call(
-        functools.partial(
-            _model_kernel, n_nodes=n, n_edges=e, n_sub=n_sub, embed_w=ed,
-            width=dp, n_steps=n_steps, gp=gp, n_layers=n_layers),
-        grid=(n_steps + 1,),
-        in_specs=[
-            full((tp, edp)),            # stacked embedding table
-            full((8, npl)),             # per-subkey offset ids
-            full((1, ep)),              # senders
-            full((1, ep)),              # receivers
-            full((np_, 128)),           # node_gidx column
-            full((np_, 128)),           # node_mask column
-            full((dp, dp)),             # edge_linear kernel
-            full((1, dp)),              # edge_linear bias
-            full((dp, 3 * dp)),         # gru x_proj kernel
-            full((1, 3 * dp)),          # gru x_proj bias
-            full((dp, 3 * dp)),         # gru h_proj kernel
-            full((1, 3 * dp)),          # gru h_proj bias
-            full((2 * dp, 128)),        # pooling gate kernel
-            full((1, 128)),             # pooling gate bias
-            *head_specs,
-        ],
-        out_specs=full((gp, 128)),
-        out_shape=jax.ShapeDtypeStruct((gp, 128), f32),
-        scratch_shapes=[
-            pltpu.VMEM((np_, dp), f32),       # hcur (node states)
-            pltpu.VMEM((np_, dp), f32),       # h0 bank (classifier concat)
-            pltpu.VMEM((np_, dp), f32),       # msg
-            pltpu.VMEM((np_, dp), f32),       # agg
-            pltpu.VMEM((np_, 2 * dp), f32),   # hcat
-        ],
-        interpret=interpret,
-    )(tablep, idsp, sndp, rcvp, gidxp, maskp, ewp, ebp, xwp, xbp, hwp, hbp,
-      gwp, gbp, *head_p)
+    out = _model_call(table, ids, senders, receivers, gidx, mask,
+                      ew, eb, xw, xb, hw, hb, gw, gb, head,
+                      n_steps=n_steps, n_graphs=n_graphs,
+                      interpret=interpret, encoder=False)
     return out[:n_graphs, 0]
 
 

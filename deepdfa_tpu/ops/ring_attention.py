@@ -36,17 +36,6 @@ __all__ = ["full_attention", "ring_attention", "ring_attention_sharded"]
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/where() NaN-free
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public alias only exists
-    on newer jax; older releases carry it as ``jax.experimental.shard_map``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     """GQA: repeat KV heads to match query heads. [b, s, h_kv, d] -> [b, s, h, d]."""
     if n_rep == 1:
@@ -168,10 +157,8 @@ def ring_attention(
     )
     # Match the manual-axes "varying" type of the loop outputs: constants start
     # unvarying under shard_map, while ppermute/collective outputs vary.
-    # jax without jax.typeof/lax.pcast predates vma checking — no-op there.
     def _vma_of(x):
-        typeof = getattr(jax, "typeof", None)
-        return getattr(typeof(x), "vma", frozenset()) if typeof else frozenset()
+        return jax.typeof(x).vma
 
     target_vma = frozenset().union(*(_vma_of(x) for x in (q, k, v)))
 
@@ -205,14 +192,14 @@ def ring_attention_sharded(
     mask_spec = P(batch_axis, seq_axis)
     body = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
     if kv_mask is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: body(q, k, v),
             mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
             out_specs=qkv_spec,
         )
         return fn(q, k, v)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, m: body(q, k, v, kv_mask=m),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),

@@ -31,21 +31,6 @@ from deepdfa_tpu.train.metrics import ConfusionState, update_confusion
 __all__ = ["stack_batches", "make_dp_train_step", "make_dp_eval_step", "dp_init_state"]
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: the public alias (and its
-    ``check_vma`` kwarg) only exists on newer jax; older releases carry the
-    same transform as ``jax.experimental.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
-
-
 def stack_batches(batches: list) -> BatchedGraphs:
     """Stack ``dp`` same-shape batches along a new leading device axis.
     Works on either layout (:class:`BatchedGraphs` or
@@ -163,7 +148,7 @@ def make_dp_train_step(
 
     def wrapped(state, stacked_batch, metrics):
         batch_specs = _batch_pspecs(stacked_batch)
-        fn = _shard_map(
+        fn = jax.shard_map(
             spmd_step,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), state), batch_specs,
@@ -193,7 +178,7 @@ def make_dp_eval_step(
         return metrics, loss_num / jnp.maximum(wsum, 1.0), wsum
 
     def wrapped(params, stacked_batch, metrics):
-        fn = _shard_map(
+        fn = jax.shard_map(
             spmd_eval,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params), _batch_pspecs(stacked_batch),

@@ -74,11 +74,10 @@ def local_mesh(n_devices: int | None = None, **axis_sizes: int) -> Mesh:
 
 def probed_devices(deadline_s: float, on_timeout=None) -> list:
     """Device init behind the hung-collective watchdog: the first
-    ``jax.devices()`` touch initialises the backend, which on a wedged
-    device grant blocks forever (BENCH_r05: >2000 s with zero signal).
-    Raises :class:`~deepdfa_tpu.resilience.watchdog.WatchdogTimeout` after
-    ``deadline_s`` instead — callers journal and abort/fall back cleanly.
-    The bench device probe routes through the same wrapper."""
+    ``jax.devices()`` touch initialises the backend, a host-blocking call
+    with no timeout of its own. Raises
+    :class:`~deepdfa_tpu.resilience.watchdog.WatchdogTimeout` after
+    ``deadline_s`` instead — callers journal and abort cleanly."""
     from deepdfa_tpu.resilience.watchdog import HangWatchdog
 
     return HangWatchdog(deadline_s, on_timeout=on_timeout).call(

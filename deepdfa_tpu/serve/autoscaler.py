@@ -39,6 +39,7 @@ from __future__ import annotations
 import http.client
 import json
 import logging
+import os
 import re
 import signal
 import subprocess
@@ -126,23 +127,66 @@ class SubprocessReplica:
 class SubprocessLauncher:
     """Spawns replica subprocesses and blocks until each prints its
     ``{"status": "serving", ...}`` line (the serve CLI contract), which
-    carries the bound port and the warm-store join report."""
+    carries the bound port and the warm-store join report.
+
+    ``chips``: how many accelerator chips this host gives the fleet. A
+    chip belongs to one process at a time, and a child that is handed the
+    whole host takes every chip — the second ``deepdfa-tpu serve`` then
+    fails or hangs. With ``chips=N`` each live child owns exactly one chip,
+    named in its environment through libtpu's visible-chips variables, and
+    a spawn with no chip free raises :class:`SpawnError` instead of
+    starting a second device process on a held chip; a dead child's chip
+    returns to the pool. ``chips=0`` (stub children, CPU) assigns nothing.
+    The launcher never imports JAX: the parent must not hold a chip."""
 
     def __init__(self, build_argv, host: str = "127.0.0.1", env=None,
-                 startup_timeout_s: float = 120.0):
+                 startup_timeout_s: float = 120.0, chips: int = 0):
         # build_argv(index) -> argv for the index-th launch, or a static argv
         self._build_argv = build_argv
         self._host = host
         self._env = env
         self._startup_timeout_s = float(startup_timeout_s)
         self._spawned = 0
+        self._chips = int(chips)
+        self._chip_owner: dict[int, subprocess.Popen] = {}
+
+    def _claim_chip(self) -> int | None:
+        """Lowest chip index no live child holds (None when unmanaged)."""
+        if not self._chips:
+            return None
+        for chip, owner in list(self._chip_owner.items()):
+            if owner.poll() is not None:
+                del self._chip_owner[chip]
+        for chip in range(self._chips):
+            if chip not in self._chip_owner:
+                return chip
+        raise SpawnError(
+            f"all {self._chips} chip(s) are held by live replicas — refusing "
+            "to start a second device process on a held chip")
+
+    @staticmethod
+    def chip_env(chip: int) -> dict[str, str]:
+        """The variables that make ONE chip of a multi-chip host the whole
+        world of a child process (libtpu reads them at load)."""
+        return {
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+        }
 
     def spawn(self) -> SubprocessReplica:
         argv = (self._build_argv(self._spawned)
                 if callable(self._build_argv) else list(self._build_argv))
+        chip = self._claim_chip()
+        env = self._env
+        if chip is not None:
+            env = dict(os.environ if env is None else env) | self.chip_env(chip)
         proc = subprocess.Popen(argv, stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True,
-                                env=self._env)
+                                env=env)
+        if chip is not None:
+            self._chip_owner[chip] = proc
         serving: dict = {}
         found = threading.Event()
         tail: deque[str] = deque(maxlen=50)

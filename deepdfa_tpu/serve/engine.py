@@ -439,12 +439,15 @@ class ScoringEngine:
 
     def bucket_key(self, bucket: ServeBucket) -> str:
         """Warm-store content address of one bucket's compiled program."""
+        import jax
+
         from .warmstore import bucket_artifact_key
 
         return bucket_artifact_key(
             self.vocab_hash, self.model_rev, self.precision,
             self.label_style, self.feat_keys, bucket.spec.max_graphs,
-            bucket.spec.max_nodes, bucket.spec.max_edges)
+            bucket.spec.max_nodes, bucket.spec.max_edges,
+            platform=jax.default_backend())
 
     def _dummy_graph(self) -> Graph:
         n = 2
@@ -548,8 +551,13 @@ class ScoringEngine:
                 row.update(source="compile",
                            compile_seconds=round(compile_s, 3))
                 if use_store:
+                    # a lowering/serialization failure is a bug and
+                    # surfaces; only the store WRITE is best-effort (the
+                    # store is an optimization — a full or read-only disk
+                    # must not take down a warm, compiled bucket)
+                    payload, export_s = self._export_fn(b)
+                    row["export_seconds"] = round(export_s, 3)
                     try:
-                        payload, export_s = self._export_fn(b)
                         warm_store.put(key, payload, {
                             "compile_seconds": compile_s,
                             "vocab_hash": self.vocab_hash,
@@ -560,12 +568,9 @@ class ScoringEngine:
                             "spec": [b.spec.max_graphs, b.spec.max_nodes,
                                      b.spec.max_edges],
                         })
-                        row["export_seconds"] = round(export_s, 3)
-                    except Exception as exc:  # noqa: BLE001 — store is an
-                        # optimization: a failed export must not take down
-                        # warmup (the bucket is already compiled and warm)
+                    except OSError as exc:
                         warnings.warn(
-                            f"warm-store export failed for bucket "
+                            f"warm-store write failed for bucket "
                             f"{b.graph_nodes}: {type(exc).__name__}: {exc}",
                             stacklevel=2)
                         row["export_error"] = f"{type(exc).__name__}: {exc}"
@@ -858,7 +863,12 @@ def _plain_score_callable(model, params, label_style: str):
 def _make_export_fn(model, params, label_style: str, feat_keys):
     """``bucket -> (serialized StableHLO, export_seconds)`` for the warm
     store — the same ``jax.export`` path :func:`deepdfa_tpu.serving.
-    export_ggnn` uses, specialized to one bucket's padded shape."""
+    export_ggnn` uses, specialized to one bucket's padded shape and lowered
+    for THIS host's platform only: the trace already chose Mosaic or the
+    Pallas interpreter from the backend (the int8 conv), and a Mosaic
+    kernel has no CPU lowering (``ValueError: Only interpret mode is
+    supported on CPU backend``) while an interpret-mode trace would hand a
+    TPU joiner the interpreter. The store key carries the platform."""
 
     def export_bucket(bucket: ServeBucket):
         import jax
@@ -881,8 +891,8 @@ def _make_export_fn(model, params, label_style: str, feat_keys):
             lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype),
             ex)
         score = _plain_score_callable(model, params, label_style)
-        exported = jexport.export(jax.jit(score),
-                                  platforms=["cpu", "tpu"])(args_spec)
+        exported = jexport.export(
+            jax.jit(score), platforms=[jax.default_backend()])(args_spec)
         return exported.serialize(), time.perf_counter() - t0
 
     return export_bucket
@@ -898,14 +908,12 @@ def _make_replicated_fn(scorer, params, mesh):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from deepdfa_tpu.parallel.dp import _shard_map
-
     def one(ps, stacked):
         batch = jax.tree.map(lambda x: x[0], stacked)
         fn_p, _ = scorer(ps, batch)
         return fn_p[None]
 
-    replicated = jax.jit(_shard_map(
+    replicated = jax.jit(jax.shard_map(
         one, mesh=mesh, in_specs=(P(), P("dp")), out_specs=P("dp"),
         check_vma=False))
 

@@ -38,6 +38,7 @@ from contextlib import nullcontext
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 
+from deepdfa_tpu import utils
 from deepdfa_tpu.config import ExperimentConfig, ServeConfig
 from deepdfa_tpu.obs import (
     FlightRecorder,
@@ -704,8 +705,6 @@ def build_server(cfg: ExperimentConfig, run_dir: Path | None = None,
     """Wire vocabs + engine + server from a config: either a checkpoint
     run (``run_dir``/``ckpt_dir``) or a pre-exported ``artifact`` dir.
     ``serve.warm_store_dir`` attaches the fleet warm-start store."""
-    from deepdfa_tpu import utils
-
     if shard_dir is None:
         sample = "_sample" if cfg.data.sample else ""
         shard_dir = utils.processed_dir() / cfg.data.dsname / f"shards{sample}"
@@ -739,8 +738,11 @@ def serve_command(cfg: ExperimentConfig, run_dir: Path | None = None,
     warmed = server.warmup()
     server.install_signal_handlers()
     server.start()
+    where = utils.describe_backend()
     print(json.dumps({
         "status": "serving", "host": server.cfg.host, "port": server.port,
+        "backend": where["backend"], "device_kind": where["device_kind"],
+        "device_count": where["device_count"],
         "replica_id": server.replica_id,
         "buckets_warmed": warmed["buckets"],
         "warm_store": {k: warmed[k] for k in
@@ -781,6 +783,7 @@ def main(argv=None) -> dict:
     parser.add_argument("--journal", default=None,
                         help="journal file for warmup / int8-gate events")
     args = parser.parse_args(argv)
+    utils.setup_compile_cache()
 
     layers = list(args.config)
     if args.run_dir and (Path(args.run_dir) / "config.json").exists():

@@ -35,14 +35,20 @@ __all__ = ["WarmEntry", "WarmStore", "bucket_artifact_key"]
 def bucket_artifact_key(vocab_hash: str | None, model_rev: str | None,
                         precision: str, label_style: str, feat_keys,
                         max_graphs: int, max_nodes: int,
-                        max_edges: int) -> str:
+                        max_edges: int, platform: str | None = None) -> str:
     """Content address of one bucket's compiled program. Everything that
     changes the lowered module must be in the key — two replicas agree on
-    a key exactly when the loaded program is bit-for-bit usable."""
+    a key exactly when the loaded program is bit-for-bit usable.
+    ``platform`` is the backend the program was lowered FOR: a bucket is
+    exported for its host's platform only (a Mosaic kernel has no CPU
+    lowering, and an interpret-mode trace from a CPU host must never be
+    what a TPU joiner loads), so programs of different platforms never
+    share a key."""
     payload = "|".join([
         vocab_hash or "novocab", model_rev or "norev", precision,
         label_style, ",".join(feat_keys),
         f"{max_graphs}x{max_nodes}x{max_edges}",
+        *([platform] if platform else []),
     ])
     return hashlib.sha256(payload.encode()).hexdigest()[:24]
 

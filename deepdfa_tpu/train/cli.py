@@ -508,6 +508,10 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
 
     last_val: dict[str, float] = {}
     route: dict[str, int] = {}
+    # per-epoch step accounting of THIS process (steps, compiles, twin-routed
+    # steps): the journal is single-record, so the completed record carries
+    # the list — "epoch 2 compiled nothing" stays checkable after the run
+    epoch_rows: list[dict] = []
     epoch = start_epoch
     try:
         while epoch < cfg.optim.max_epochs:
@@ -636,8 +640,13 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
                                         step=int(state.step), epoch=epoch)
                 telemetry.record_event("ckpt.commit", step=int(state.step),
                                        epoch=epoch)
+            epoch_rows.append({
+                "epoch": epoch,
+                "twin_routed_steps": trainer.twin_routed_steps,
+                **({"telemetry": telemetry.epoch_stats()}
+                   if telemetry is not None else {}),
+            })
             journal.write(
-                epoch=epoch,
                 global_step=int(state.step),
                 seed=cfg.seed,
                 sampler={
@@ -652,8 +661,7 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
                 mesh=topology,
                 resharded=resharded,
                 **(sentinel.stats() if sentinel is not None else {}),
-                **({"telemetry": telemetry.epoch_stats()}
-                   if telemetry is not None else {}),
+                **epoch_rows[-1],
             )
             with open(tuning_file, "a") as f:
                 f.write(json.dumps({"epoch": epoch, "val_F1Score": val_m["val_F1Score"]}) + "\n")
@@ -715,6 +723,7 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
         rollbacks=n_rollbacks,
         mesh=topology,
         resharded=resharded,
+        epochs=epoch_rows,
         completed=True,
     )
     atomic_write_text(run_dir / "final_metrics.json", json.dumps(last_val, indent=2))
@@ -1268,6 +1277,7 @@ def main(argv: Sequence[str] | None = None) -> dict:
                         "the demo localization study vs the gate's 0/12 — "
                         "BASELINE.md); gate = readout attention, 1 forward")
     args = parser.parse_args(argv)
+    utils.setup_compile_cache()
     if args.command == "predict" and not args.source:
         parser.error("predict requires at least one --source")
     if args.command == "scan" and not (args.subcommand or args.source):
@@ -1332,7 +1342,10 @@ def main(argv: Sequence[str] | None = None) -> dict:
         # (README usage) and must not overwrite the trained run's recorded
         # config — but a FRESH predict run dir still gets provenance
         atomic_write_text(run_dir / "config.json", to_json(cfg))
-    logger.info("run %s: %s devices=%s", run_id, args.command, jax.device_count())
+    where = utils.describe_backend()
+    logger.info("run %s: %s backend=%s device_kind=%r devices=%d",
+                run_id, args.command, where["backend"],
+                where["device_kind"], where["device_count"])
 
     try:
         if args.command == "fit":

@@ -31,6 +31,10 @@ __all__ = [
     "chunks",
     "seed_all",
     "debug_nans",
+    "setup_compile_cache",
+    "describe_backend",
+    "require_backend",
+    "BackendError",
 ]
 
 
@@ -170,3 +174,68 @@ def debug_nans(enable: bool = True) -> None:
     import jax
 
     jax.config.update("jax_debug_nans", enable)
+
+
+def setup_compile_cache() -> Path:
+    """Place JAX's persistent compilation cache; every entry point calls
+    this first, before anything compiles. Returns the cache directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the program sets no
+    directory in code — JAX reads the variable itself, so an operator (or
+    the chip tool) moves the cache by exporting it. Where it is not, the
+    cache goes to ONE fixed path inside the checkout, ``<project_dir>/
+    .jax_cache`` (git-ignored): the path is part of the cache key, so a
+    directory made from a pid, a run id, ``tempfile`` or the clock would
+    never hit. The minimum-compile-time threshold drops to zero — JAX's
+    default (1 s) would skip the small GGNN programs (serve buckets, the
+    train step), which are exactly what a cold start recompiles."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = Path(env)
+    else:
+        path = project_dir() / ".jax_cache"
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
+class BackendError(RuntimeError):
+    """The process initialised a backend other than the one it was given."""
+
+
+def describe_backend() -> dict:
+    """The device as JAX reports it — what every entry point states at
+    start (JAX drops to CPU with only a warning when libtpu fails to
+    initialise, so a run that does not say where it ran proves nothing)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "backend": jax.default_backend(),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
+def require_backend() -> dict:
+    """:func:`describe_backend`, refusing a backend the caller did not ask
+    for. The accelerator is the default: with ``JAX_PLATFORMS`` unset (or
+    empty) anything but ``tpu`` raises :class:`BackendError`. A caller that
+    pins a platform (``JAX_PLATFORMS=cpu`` for tests and rehearsals) gets
+    exactly that platform, labelled as such — never a silent substitute."""
+    import jax
+
+    info = describe_backend()
+    asked = (jax.config.jax_platforms or "").split(",")[0].strip().lower()
+    want = asked or "tpu"
+    if info["backend"] != want:
+        raise BackendError(
+            f"backend is {info['backend']!r} ({info['device_kind']}, "
+            f"{info['device_count']} device(s)) but "
+            + (f"JAX_PLATFORMS asked for {want!r}" if asked else
+               "no platform was given and the default is 'tpu' — set "
+               "JAX_PLATFORMS=cpu explicitly for a CPU-labelled run"))
+    return info

@@ -82,7 +82,7 @@ def main(argv=None) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import assemble_hier_result
+    from bench import assemble_hier_result, start_on_device
     from deepdfa_tpu.config import GGNNConfig
     from deepdfa_tpu.cpg.interproc import build_supergraph, merge_cpgs
     from deepdfa_tpu.data.graphs import Graph, batch_np
@@ -92,6 +92,7 @@ def main(argv=None) -> dict:
     from deepdfa_tpu.pipeline import encode_source
     from deepdfa_tpu.serve.embcache import FunctionEmbeddingCache
 
+    backend, device_kind = start_on_device()
     vocabs = _build_vocabs()
     units = _chain_units(args.chains)
 
@@ -169,9 +170,12 @@ def main(argv=None) -> dict:
     )
     result["n_chains"] = args.chains
     result["reps"] = reps
+    result["backend"] = backend
+    result["device_kind"] = device_kind
     print(json.dumps(result))
     return result
 
 
 if __name__ == "__main__":
-    main()
+    # the JSON line carries the measured numbers; the exit code carries ok
+    raise SystemExit(0 if main()["ok"] else 1)

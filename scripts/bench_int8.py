@@ -5,8 +5,9 @@ XLA dequantize-then-matmul, at CodeLlama-7B projection shapes.
 Prints ONE JSON line. The int8 kernel's case is HBM traffic: at low batch
 the matmul is weight-bandwidth-bound, and int8-resident weights halve that
 term — this measures whether the kernel actually cashes the cheque on real
-hardware. On CPU backends the kernel runs in interpret mode: correctness
-only, timings meaningless, flagged in the output.
+hardware. Under an explicit ``JAX_PLATFORMS=cpu`` the kernel runs in
+interpret mode: correctness only, timings meaningless, flagged in the
+output; with no platform given anything but the TPU is refused.
 
 Usage: python scripts/bench_int8.py [--m 8 128 1024] [--trials 5]
 """
@@ -63,8 +64,10 @@ def main(argv=None) -> dict:
     from deepdfa_tpu.llm.quant import _quantize
     from deepdfa_tpu.ops.int8_matmul import int8_matmul
 
-    backend = jax.default_backend()
-    interpret = backend == "cpu"
+    from bench import start_on_device
+
+    backend, device_kind = start_on_device()
+    interpret = backend != "tpu"
     rng = np.random.default_rng(0)
     rows = []
     for name, K, N in SHAPES:
@@ -103,6 +106,7 @@ def main(argv=None) -> dict:
     result = {
         "metric": "int8_matmul_microbench",
         "backend": backend,
+        "device_kind": device_kind,
         "interpret_mode": interpret,
         "note": ("interpret mode: correctness only, timings meaningless"
                  if interpret else
@@ -114,16 +118,4 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
-    import os
-    import sys
-    from pathlib import Path
-
-    if os.environ.get("_BENCH_CHILD") == "1":
-        main()
-    else:
-        sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-        from bench import run_with_device_watchdog
-
-        raise SystemExit(run_with_device_watchdog(
-            __file__, sys.argv[1:], fallback_argv=["--tiny"],
-        ))
+    main()

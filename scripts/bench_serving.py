@@ -1605,8 +1605,6 @@ def main(argv=None) -> dict:
     import argparse
     import tempfile
 
-    import jax
-
     from bench import assemble_serve_result
 
     ap = argparse.ArgumentParser()
@@ -1682,8 +1680,9 @@ def main(argv=None) -> dict:
     if args.federation == 1:
         ap.error("--federation needs N >= 2 (one cell cannot spill over)")
 
-    backend = jax.default_backend()
-    device_kind = jax.devices()[0].device_kind
+    from bench import start_on_device
+
+    backend, device_kind = start_on_device()
     cfg, vocabs, base_sources = _build_corpus(args.corpus)
     ckpt = _build_ckpt(cfg, vocabs)
     bodies = [
@@ -1794,20 +1793,10 @@ def main(argv=None) -> dict:
             "int8_refused_reason": tier_refusal,
         },
     )
-    # rc stays 0 even when a gate fails: the artifact carries ok:false +
-    # the measured numbers — a nonzero rc would make the watchdog misread
-    # a serving regression as device trouble and overwrite this JSON with
-    # a CPU fallback (same policy as check_serving.py)
     print(json.dumps(result))
     return result
 
 
 if __name__ == "__main__":
-    import os
-
-    if os.environ.get("_BENCH_CHILD") == "1":
-        main()
-    else:
-        from bench import run_with_device_watchdog
-
-        raise SystemExit(run_with_device_watchdog(__file__, sys.argv[1:]))
+    # the JSON line carries the measured numbers; the exit code carries ok
+    raise SystemExit(0 if main()["ok"] else 1)

@@ -462,6 +462,54 @@ def test_launcher_parses_serving_line_and_join_report(tmp_path):
         h.kill()
 
 
+_CHIP_STUB = r'''
+import json, os, time
+print(json.dumps({"status": "serving", "host": "127.0.0.1", "port": 1,
+                  "replica_id": os.environ.get("TPU_VISIBLE_CHIPS"),
+                  "bounds": os.environ.get("TPU_CHIPS_PER_PROCESS_BOUNDS")}),
+      flush=True)
+time.sleep(60)
+'''
+
+
+def test_launcher_gives_each_live_child_its_own_chip(tmp_path):
+    """One process per chip: with ``chips=2`` two live children name
+    different chips in their environment, a third spawn is refused, and a
+    dead child's chip goes back to the pool. ``chips=0`` assigns nothing."""
+    from deepdfa_tpu.serve.autoscaler import SpawnError
+
+    stub = tmp_path / "chip_stub.py"
+    stub.write_text(_CHIP_STUB)
+    launcher = SubprocessLauncher([sys.executable, str(stub)],
+                                  startup_timeout_s=30.0, chips=2)
+    a = launcher.spawn()
+    b = launcher.spawn()
+    try:
+        assert {a.serving["replica_id"], b.serving["replica_id"]} == {"0", "1"}
+        assert a.serving["bounds"] == b.serving["bounds"] == "1,1,1"
+        with pytest.raises(SpawnError, match="refusing"):
+            launcher.spawn()
+        a.kill()
+        a.wait(timeout=10)
+        c = launcher.spawn()
+        try:
+            assert c.serving["replica_id"] == a.serving["replica_id"]
+        finally:
+            c.kill()
+    finally:
+        a.kill()
+        b.kill()
+    unmanaged = SubprocessLauncher(
+        [sys.executable, str(stub)], startup_timeout_s=30.0,
+        env={k: v for k, v in os.environ.items()
+             if k != "TPU_VISIBLE_CHIPS"})
+    d = unmanaged.spawn()
+    try:
+        assert d.serving["replica_id"] is None
+    finally:
+        d.kill()
+
+
 def test_router_admin_endpoint_add_list_remove(tmp_path):
     launcher = _launcher_for(tmp_path)
     h = launcher.spawn()

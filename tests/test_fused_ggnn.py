@@ -286,6 +286,30 @@ def test_trainer_fused_routes_vmem_oversize_to_segment_twin():
     assert ts is tr.fallback_train_step and es is tr.fallback_eval_step
 
 
+def test_trainer_counts_and_logs_twin_routed_steps(monkeypatch, caplog):
+    """Routing to the segment twin is never silent: an admitted bucket
+    counts 0 twin-routed steps, an over-plan bucket counts 1 and logs the
+    first one."""
+    import logging
+
+    tr, _cfg = _trainer()
+    batch = _batch(_corpus(6, seed=7))
+    state = tr.init_state(batch)
+    with caplog.at_level(logging.WARNING, logger="deepdfa_tpu"):
+        state, _m, loss = tr.train_epoch(state, [batch])
+    assert np.isfinite(loss) and tr.twin_routed_steps == 0
+    assert "segment twin" not in caplog.text
+
+    monkeypatch.setattr(fg, "VMEM_CAP_BYTES", 0)  # every bucket over-plan
+    with caplog.at_level(logging.WARNING, logger="deepdfa_tpu"):
+        state, _m, loss = tr.train_epoch(state, [batch, batch])
+    assert np.isfinite(loss) and tr.twin_routed_steps == 2
+    assert caplog.text.count("trains on the segment twin") == 1  # first only
+    monkeypatch.undo()
+    state, _m, _loss = tr.train_epoch(state, [batch])
+    assert tr.twin_routed_steps == 0  # per-epoch count
+
+
 # ------------------------------------------------- VMEM budget guard
 
 
@@ -402,3 +426,18 @@ def test_working_set_is_monotone_and_counts_padding():
             <= fg.working_set_bytes(100, 200, 129))
     # padding rules: width pads to the 128-lane tile, nodes to sublane 8
     assert fg.working_set_bytes(1, 1, 1) == fg.working_set_bytes(8, 1, 128)
+
+
+def test_plan_caps_the_edge_indices_at_smem():
+    """The edge endpoints are scalar-prefetched into SMEM (1 MiB on the
+    v5e): an edge-heavy bucket is refused by the plan even when its VMEM
+    working set is small — the compiler would refuse it at 2 x 512 KiB."""
+    assert fg.edge_smem_bytes(1) == 2 * 128 * 4
+    assert fg.edge_smem_bytes(4352) == 2 * 4352 * 4
+    n, width = 1024, 128
+    assert fg.working_set_bytes(n, 131072, width) < fg.VMEM_CAP_BYTES
+    assert fg.edge_smem_bytes(131072) > fg.SMEM_CAP_BYTES
+    assert not fg.fits_vmem(n, 131072, width)
+    assert not fg.fits_vmem_train(n, 131072, width, 5)
+    assert fg.fits_vmem(n, 65536, width)  # 512 KiB of indices: admitted
+    assert fg.SMEM_CAP_BYTES < 2**20

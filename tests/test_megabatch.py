@@ -430,3 +430,74 @@ def test_batcher_feeds_padding_gauges():
     (bucket,) = {k for k in eff}
     assert 0.0 < eff[bucket]["graphs"] <= 1.0
     assert 0.0 < eff[bucket]["nodes"] <= 1.0
+
+
+def test_plan_counts_smem_ids_and_edges():
+    """ids (n_sub rows of the padded node count) and edge endpoints share
+    the 1 MiB of SMEM: the plan refuses what the compiler would."""
+    from deepdfa_tpu.ops.fused_ggnn import SMEM_CAP_BYTES, edge_smem_bytes
+
+    assert mb.megabatch_smem_bytes(2176, 4352, 4) == (
+        edge_smem_bytes(4352) + 4 * 2176 * 4)
+    kw = dict(width=128, n_steps=5, table_rows=4008, embed_width=32,
+              n_head_layers=3)
+    ok = mb.MegabatchPlan(max_graphs=129, max_nodes=2176, max_edges=4352,
+                          **kw)
+    assert ok.fits
+    edge_heavy = mb.MegabatchPlan(max_graphs=9, max_nodes=1024,
+                                  max_edges=131072, **kw)
+    assert edge_heavy.working_set <= mb.VMEM_CAP_BYTES
+    assert mb.megabatch_smem_bytes(1024, 131072, 4) > SMEM_CAP_BYTES
+    assert not edge_heavy.fits
+    assert not mb.fits_vmem_megabatch(
+        1024, 131072, 128, 9, table_rows=4008, embed_width=32,
+        n_head_layers=3)
+
+
+def test_whole_model_kernel_rejects_a_ragged_stacked_table():
+    """The prologue gathers whole lane-placed rows; which lanes a row owns
+    comes from its sub-table, so the stack must split evenly."""
+    import jax.numpy as jnp
+
+    f32, i32 = jnp.float32, jnp.int32
+    d, ed, n, e, g = 8, 4, 8, 8, 2
+    with pytest.raises(ValueError, match="not a multiple"):
+        mb.fused_ggnn_encoder(
+            jnp.zeros((7, ed), f32), jnp.zeros((n, 2), i32),
+            jnp.zeros((e,), i32), jnp.zeros((e,), i32),
+            jnp.zeros((n,), i32), jnp.ones((n,), bool),
+            jnp.zeros((d, d), f32), jnp.zeros((d,), f32),
+            jnp.zeros((d, 3 * d), f32), jnp.zeros((3 * d,), f32),
+            jnp.zeros((d, 3 * d), f32), jnp.zeros((3 * d,), f32),
+            jnp.zeros((2 * d, 1), f32), jnp.zeros((1,), f32),
+            n_steps=1, n_graphs=g, interpret=True)
+
+
+def test_model_says_when_it_computes_through_the_twin(monkeypatch, caplog):
+    """GGNNMegabatch's own over-plan path is not silent: a direct
+    model.apply on a refused shape logs the routing (once per trace)."""
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepdfa_tpu.config import GGNNConfig
+    from deepdfa_tpu.data.graphs import batch_np
+    from deepdfa_tpu.data.synthetic import random_dataset
+    from deepdfa_tpu.models import make_model
+
+    cfg = GGNNConfig(hidden_dim=8, n_steps=2, num_output_layers=2,
+                     layout="megabatch")
+    model = make_model(cfg, input_dim=40)
+    graphs = random_dataset(4, seed=0, input_dim=40, mean_nodes=8)
+    batch = jax.tree.map(jnp.asarray, batch_np(graphs, 5, 64, 128))
+    params = model.init(jax.random.key(0), batch)["params"]
+    with caplog.at_level(logging.WARNING, logger="deepdfa_tpu"):
+        admitted = model.apply({"params": params}, batch)
+    assert "segment-twin math" not in caplog.text
+    monkeypatch.setattr(mb, "VMEM_CAP_BYTES", 0)
+    with caplog.at_level(logging.WARNING, logger="deepdfa_tpu"):
+        routed = model.apply({"params": params}, batch)
+    assert "over the whole-model kernel's plan" in caplog.text
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(admitted),
+                               atol=1e-5)

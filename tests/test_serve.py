@@ -1029,6 +1029,56 @@ def test_warm_store_join_loads_ladder_with_zero_recompiles(live_model,
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+def test_warm_store_programs_are_for_the_hosts_platform_only(live_model,
+                                                            tmp_path):
+    """A bucket is exported for the platform that compiled it (a Mosaic
+    kernel has no CPU lowering; an interpret-mode trace must never be what
+    a TPU joiner loads), and the key carries that platform."""
+    from jax import export as jexport
+
+    from deepdfa_tpu.serve import WarmStore, bucket_artifact_key
+
+    ws = WarmStore(tmp_path / "store")
+    eng = _live_engine(live_model)
+    eng.warmup(warm_store=ws)
+    for key in ws.keys():
+        exported = jexport.deserialize(ws.get(key).payload)
+        assert tuple(exported.platforms) == ("cpu",)
+    b = eng.buckets[0]
+    args = (eng.vocab_hash, eng.model_rev, eng.precision, eng.label_style,
+            eng.feat_keys, b.spec.max_graphs, b.spec.max_nodes,
+            b.spec.max_edges)
+    assert eng.bucket_key(b) == bucket_artifact_key(*args, platform="cpu")
+    assert eng.bucket_key(b) != bucket_artifact_key(*args, platform="tpu")
+    assert eng.bucket_key(b) != bucket_artifact_key(*args)
+
+
+def test_warm_store_export_failure_surfaces_but_write_failure_degrades(
+        live_model, tmp_path):
+    """A lowering/serialization failure is a bug and raises out of warmup;
+    only the store WRITE is best-effort (the bucket is already warm)."""
+    from deepdfa_tpu.serve import WarmStore
+
+    eng = _live_engine(live_model)
+
+    def broken_export(bucket):
+        raise ValueError("Only interpret mode is supported on CPU backend.")
+
+    eng._export_fn = broken_export
+    with pytest.raises(ValueError, match="interpret mode"):
+        eng.warmup(warm_store=WarmStore(tmp_path / "a"))
+
+    class _FullDisk(WarmStore):
+        def put(self, key, payload, meta):
+            raise OSError(28, "No space left on device")
+
+    eng2 = _live_engine(live_model)
+    with pytest.warns(UserWarning, match="warm-store write failed"):
+        rep = eng2.warmup(warm_store=_FullDisk(tmp_path / "b"))
+    assert rep["misses"] == 3 and eng2.warm_buckets
+    assert all("export_error" in row for row in rep["per_bucket"].values())
+
+
 def test_warm_store_keys_change_with_model_rev(live_model, tmp_path):
     """Different weights → different model_rev → a joiner must MISS (and
     recompile) rather than load another revision's program."""
