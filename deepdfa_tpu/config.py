@@ -333,7 +333,6 @@ class ObsConfig:
     slo_error_rate: float = 0.95  # serve non-error (2xx) floor
     slo_p99_ms: float = 2000.0  # serve/router p99 latency ceiling
     slo_step_ms: float = 0.0  # train mean-step ceiling (0 disables)
-    slo_mfu_floor: float = 0.0  # train MFU floor (0 disables)
     slo_fast_window_s: float = 300.0
     slo_slow_window_s: float = 3600.0
     slo_burn_threshold: float = 2.0  # ratio SLOs page above this burn
@@ -367,8 +366,6 @@ class ObsConfig:
             raise ValueError("slo_p99_ms must be > 0")
         if self.slo_step_ms < 0:
             raise ValueError("slo_step_ms must be >= 0 (0 disables)")
-        if self.slo_mfu_floor < 0:
-            raise ValueError("slo_mfu_floor must be >= 0 (0 disables)")
         if not 0 < self.slo_fast_window_s <= self.slo_slow_window_s:
             raise ValueError(
                 "need 0 < slo_fast_window_s <= slo_slow_window_s")

@@ -16,14 +16,25 @@ Usage::
 Exceptions raised by the producer (e.g. an oversize graph rejected by the
 batcher mid-stream) are re-raised in the consumer at the point of ``next()``
 — never swallowed in the thread.
+
+With a ``tracer`` (:class:`deepdfa_tpu.obs.Tracer`) the producer records, on
+its own thread, one ``batch.build`` span round each pull from the upstream
+iterator (whatever builds the batch runs inside it and may set its counts on
+``tracer.current_span().attrs``; the pull that finds the iterator exhausted
+is marked ``exhausted``) and one ``batch.h2d`` round each ``device_put``,
+both under the span that was open where the stream was made. ``on_span`` is
+called on that thread with each of them once it has closed
+(``TrainTelemetry.observe_producer`` keeps the lifetime totals).
 """
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from typing import Any, Iterable, Iterator
 
+from deepdfa_tpu.obs.tracing import no_span
 from deepdfa_tpu.resilience import faults
 
 __all__ = ["prefetch_to_device"]
@@ -37,7 +48,8 @@ class _ProducerError:
 
 
 def prefetch_to_device(
-    iterator: Iterable[Any], size: int = 2, device=None
+    iterator: Iterable[Any], size: int = 2, device=None, tracer=None,
+    on_span=None,
 ) -> Iterator[Any]:
     """Yield items from ``iterator`` staged on device ``size`` items ahead.
 
@@ -46,10 +58,40 @@ def prefetch_to_device(
     ``jax.Device`` (or ``NamedSharding``) to pin. ``size <= 0`` disables
     prefetching and yields pass-through (useful to A/B the overlap).
     """
+    if tracer is None:
+        return _prefetch(iterator, size, device, no_span)
+    # the producer's thread has no open span: hang its under this one's
+    parent = tracer.current()
+
+    @contextlib.contextmanager
+    def span(name):
+        with tracer.span(name, parent=parent) as sp:
+            yield sp
+        if on_span is not None:
+            on_span(sp)
+
+    return _prefetch(iterator, size, device, span)
+
+
+def _pulls(iterator: Iterable[Any], span) -> Iterator[Any]:
+    """``iterator``'s items, each pulled inside a ``batch.build`` span."""
+    it = iter(iterator)
+    while True:
+        with span("batch.build") as sp:
+            try:
+                item = next(it)
+            except StopIteration:
+                if sp is not None:
+                    sp.attrs["exhausted"] = True
+                return
+        yield item
+
+
+def _prefetch(iterator, size, device, span) -> Iterator[Any]:
     import jax
 
     if size <= 0:
-        yield from iterator
+        yield from _pulls(iterator, span)
         return
 
     q: queue.Queue = queue.Queue(maxsize=size)
@@ -70,15 +112,16 @@ def prefetch_to_device(
 
     def produce():
         try:
-            for item in iterator:
+            for item in _pulls(iterator, span):
                 # chaos point: a batcher blowing up mid-stream inside the
                 # thread (must surface at the consumer's next(), never hang)
                 faults.raise_if("prefetch.producer_raises")
-                staged = (
-                    jax.device_put(item, device)
-                    if device is not None
-                    else jax.device_put(item)
-                )
+                with span("batch.h2d"):
+                    staged = (
+                        jax.device_put(item, device)
+                        if device is not None
+                        else jax.device_put(item)
+                    )
                 if not _put(staged):
                     return
         except BaseException as e:  # re-raised consumer-side

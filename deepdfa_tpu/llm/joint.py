@@ -273,7 +273,8 @@ def make_joint_steps(
         )
         labels = jnp.asarray(batch.text.labels)
         mask = jnp.asarray(batch.mask)
-        loss, probs = fusion_loss(logits, labels, mask)
+        with jax.named_scope("loss"):
+            loss, probs = fusion_loss(logits, labels, mask)
         return loss, probs
 
     @jax.jit
@@ -282,8 +283,11 @@ def make_joint_steps(
         (loss, probs), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, llm_params, batch, sub
         )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        # flax scopes the modules; clip + AdamW + apply_updates would lower
+        # as bare jit(train_step)/mul — name them for the device trace
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return JointState(params, opt_state, rng, state.step + 1), loss, probs
 
     @jax.jit
@@ -302,7 +306,8 @@ def make_joint_steps(
         )
         labels = jnp.asarray(batch.text.labels)
         mask = jnp.asarray(batch.mask)
-        loss, probs = fusion_loss(logits, labels, mask)
+        with jax.named_scope("loss"):
+            loss, probs = fusion_loss(logits, labels, mask)
         return loss, probs
 
     return train_step, eval_step
@@ -318,11 +323,18 @@ class JointTrainer:
     cfg: JointConfig
     join: GraphJoin | None  # None = no_flowgnn mode
     run_dir: Path | None = None
+    # obs.TrainTelemetry the loop and its prefetch producer record into
+    # (timing only); None = the process-wide one, obs.train_telemetry()
+    telemetry: Any = None
 
     def __post_init__(self):
         self._steps: tuple[Callable, Callable] | None = None
         self.num_missing = 0
         self.history: list[dict] = []
+        if self.telemetry is None:
+            from deepdfa_tpu.obs import train_telemetry
+
+            self.telemetry = train_telemetry()
 
     @property
     def _llm_arg(self):
@@ -335,6 +347,19 @@ class JointTrainer:
         if self.join is not None:
             return self.join.join(batch)
         return JoinedBatch(text=batch, graphs=None, mask=batch.mask)
+
+    def _built(self, batches: Iterable) -> Iterable[JoinedBatch]:
+        """The joined batches, as the prefetch producer pulls them: each pull
+        runs inside the producer's ``batch.build`` span, whose counts of real
+        and padded tokens are set here, from the numpy mask, before H2D."""
+        tracer = self.telemetry.tracer
+        for tb in batches:
+            jb = self._joined(tb)
+            build = tracer.current_span()
+            if build is not None:
+                build.attrs.update(
+                    tokens_real=int(tb.pad_mask.sum()), tokens=tb.pad_mask.size)
+            yield jb
 
     def _build(
         self, steps_per_epoch: int, example: JoinedBatch, params: Any | None = None
@@ -378,8 +403,10 @@ class JointTrainer:
         state: JointState | None = None,
     ) -> JointState:
         cfg = self.cfg
+        telemetry, tracer = self.telemetry, self.telemetry.tracer
         n_batches = -(-len(train_examples) // cfg.train_batch_size)
         for epoch in range(cfg.epochs):
+            telemetry.observe_epoch(epoch)
             batches = text_batches(
                 train_examples,
                 cfg.train_batch_size,
@@ -388,32 +415,58 @@ class JointTrainer:
             )
             points = eval_points(n_batches, epoch, cfg)
             tr_loss, tr_num = 0.0, 0
-            # overlap the host-side graph join + H2D transfer with the
-            # running step (the index-join per batch is real host work —
-            # the reference hides it in DataLoader workers)
-            joined = prefetch_to_device(
-                (self._joined(tb) for tb in batches), size=cfg.prefetch
-            )
-            for step, jb in enumerate(joined):
-                if self._steps is None or state is None:
-                    built = self._build(
-                        n_batches, jb,
-                        params=None if state is None else state.params,
-                    )
-                    state = state if state is not None else built
-                train_step, _ = self._steps
-                state, loss, _probs = train_step(state, self._llm_arg, jb)
-                tr_loss += float(loss)
-                tr_num += 1
-                if step in points:
-                    self.history.append(
-                        {"epoch": epoch, "step": step, **self.evaluate(state.params, eval_examples)}
-                    )
-            self.history.append(
-                {"epoch": epoch, "train_loss": tr_loss / max(tr_num, 1)}
-            )
-            if self.run_dir is not None:
-                self.save(state, f"epoch_{epoch}")
+            with tracer.span("train.epoch", root=True, epoch=epoch):
+                # overlap the host-side graph join + H2D transfer with the
+                # running step (the index-join per batch is real host work —
+                # the reference hides it in DataLoader workers)
+                joined = prefetch_to_device(
+                    self._built(batches), size=cfg.prefetch, tracer=tracer,
+                    on_span=telemetry.observe_producer,
+                )
+                try:
+                    # text_batches yields exactly n_batches
+                    for step in range(n_batches):
+                        with jax.profiler.StepTraceAnnotation(
+                            "train", step_num=epoch * n_batches + step
+                        ):
+                            with tracer.span("data.wait", step=step) as wait:
+                                jb = next(joined)
+                            if self._steps is None or state is None:
+                                built = self._build(
+                                    n_batches, jb,
+                                    params=None if state is None else state.params,
+                                )
+                                state = state if state is not None else built
+                            train_step, _ = self._steps
+                            with tracer.span("step.dispatch", step=step) as dispatch:
+                                state, loss, _probs = train_step(
+                                    state, self._llm_arg, jb
+                                )
+                            # the loop reads each loss: where it waits for
+                            # the device
+                            with tracer.span("loss.sync", step=step) as sync:
+                                tr_loss += float(loss)
+                            tr_num += 1
+                            telemetry.observe_step(
+                                wait.dur_s, dispatch.dur_s, sync.dur_s
+                            )
+                            if step in points:
+                                with tracer.span("eval", step=step):
+                                    report = self.evaluate(state.params, eval_examples)
+                                self.history.append(
+                                    {"epoch": epoch, "step": step, **report}
+                                )
+                finally:
+                    # the producer still holds its end-of-stream marker (or,
+                    # after an exception, staged batches): stop and join it
+                    joined.close()
+                self.history.append(
+                    {"epoch": epoch, "train_loss": tr_loss / max(tr_num, 1),
+                     "telemetry": telemetry.epoch_stats()}
+                )
+                if self.run_dir is not None:
+                    with tracer.span("checkpoint.save", checkpoint=f"epoch_{epoch}"):
+                        self.save(state, f"epoch_{epoch}")
         if self.join is not None:
             self.num_missing = self.join.num_missing
         return state

@@ -145,24 +145,26 @@ class GatedGraphConv(nn.Module):
         # happens once per batch as a numpy argsort on the host.
         # Python loop, unrolled by trace: n_steps is small (5) and static;
         # unrolling lets XLA pipeline the matmuls instead of a lax.scan barrier.
+        # Each round (message, scatter, GRU) is named for the device trace.
         for _step in range(self.n_steps):
-            msg_src = edge_linear(h)
-            if self.aggregation == "sum":
-                agg = segment_sum(gather(msg_src, senders), receivers, n_nodes,
-                                  indices_are_sorted=self.edges_sorted)
-            else:
-                # union space is [0,1] soft membership: messages AND the
-                # node's own state map through sigmoid (the reference fold
-                # starts from ``nodes.data["h"]``, clipper.py:70-73, with h
-                # living in bit space in its experiments; sigmoid keeps the
-                # union algebra valid for our unconstrained GRU state and
-                # matches exactly at saturation)
-                msgs = nn.sigmoid(msg_src)
-                agg = union(nn.sigmoid(h), msgs, senders, receivers,
-                            indices_are_sorted=self.edges_sorted)
-            h = gru(agg, h)
-            if taps is not None:
-                h = h + taps[_step]
+            with jax.named_scope(f"round_{_step}"):
+                msg_src = edge_linear(h)
+                if self.aggregation == "sum":
+                    agg = segment_sum(gather(msg_src, senders), receivers, n_nodes,
+                                      indices_are_sorted=self.edges_sorted)
+                else:
+                    # union space is [0,1] soft membership: messages AND the
+                    # node's own state map through sigmoid (the reference fold
+                    # starts from ``nodes.data["h"]``, clipper.py:70-73, with h
+                    # living in bit space in its experiments; sigmoid keeps the
+                    # union algebra valid for our unconstrained GRU state and
+                    # matches exactly at saturation)
+                    msgs = nn.sigmoid(msg_src)
+                    agg = union(nn.sigmoid(h), msgs, senders, receivers,
+                                indices_are_sorted=self.edges_sorted)
+                h = gru(agg, h)
+                if taps is not None:
+                    h = h + taps[_step]
         return h
 
 

@@ -17,7 +17,11 @@ from deepdfa_tpu.obs.slo import (
     train_specs,
     write_alerts_artifact,
 )
-from deepdfa_tpu.obs.telemetry import TelemetryServer, TrainTelemetry
+from deepdfa_tpu.obs.telemetry import (
+    TelemetryServer,
+    TrainTelemetry,
+    train_telemetry,
+)
 from deepdfa_tpu.obs.tracing import (
     Span,
     SpanContext,
@@ -56,5 +60,6 @@ __all__ = [
     "router_specs",
     "serve_specs",
     "train_specs",
+    "train_telemetry",
     "write_alerts_artifact",
 ]

@@ -354,17 +354,12 @@ def federation_specs(*, availability: float = 0.99,
     )
 
 
-def train_specs(*, step_ms: float = 0.0,
-                mfu_floor: float = 0.0) -> tuple[SLOSpec, ...]:
-    """Train-side objectives; 0 disables a spec (step time and MFU floors
-    are hardware-specific, so there is no honest universal default)."""
-    specs = []
+def train_specs(*, step_ms: float = 0.0) -> tuple[SLOSpec, ...]:
+    """Train-side objectives; 0 disables the spec (a step-time ceiling is
+    hardware-specific, so there is no honest universal default)."""
     if step_ms > 0:
-        specs.append(SLOSpec("step_time", "max", step_ms,
-                             value="mean_step_ms"))
-    if mfu_floor > 0:
-        specs.append(SLOSpec("mfu_floor", "min", mfu_floor, value="mfu"))
-    return tuple(specs)
+        return (SLOSpec("step_time", "max", step_ms, value="mean_step_ms"),)
+    return ()
 
 
 # ---------------------------------------------------------------------------

@@ -1,25 +1,28 @@
-"""Training-step timelines: per-step host wall / data-wait / dispatch
-accounting, jit-compile counting, MFU vs the bench roofline, an optional
-trainer HTTP ``/metrics``+``/healthz`` endpoint, and per-epoch journal
-stats.
+"""Training-step timelines: per-step host wall / data-wait / dispatch /
+loss-sync accounting, exact jit-compile events, an optional trainer HTTP
+``/metrics``+``/healthz`` endpoint, and per-epoch journal stats.
 
 The trainer's ``StepProfiler`` writes jsonl files nobody scrapes; this
 is the live complement: :class:`TrainTelemetry` is fed from inside
-``Trainer.train_epoch`` (wait/dispatch wall times measured around the
-prefetch iterator and the step call) and renders through the same
-:class:`~deepdfa_tpu.obs.registry.MetricsRegistry` as the serve and
-router endpoints, so all three expositions share one formatter and one
-conformance test.
+``Trainer.train_epoch`` and ``JointTrainer.train`` (the spans they open
+round the prefetch iterator, the step call and the loss read) and renders
+through the same :class:`~deepdfa_tpu.obs.registry.MetricsRegistry` as
+the serve and router endpoints, so all three expositions share one
+formatter and one conformance test.
 
-Compile counting is a heuristic that matches how jax actually behaves:
-``jax.jit`` compiles once per distinct argument-shape signature, so the
-first step carrying an unseen batch-leaf-shape tuple is counted as a
-compile (exact under bucketed batching, where shape signatures are the
-bucket ladder).
+Compiles are not guessed: jax reports every trace, lowering and backend
+compile (a persistent-cache read included) as a ``jax.monitoring``
+duration event when it is over. One pair of listeners a process, registered
+by the first :class:`TrainTelemetry` made, hands them to every live one:
+each becomes a ``jit.trace`` / ``jit.lower`` / ``jit.backend_compile``
+span ``[now - duration, now]`` under whatever span is open on the compiling
+thread (so a re-jit inside the loop carries its ``step``), and
+``jit.backend_compile`` is what ``compiles`` counts. Trace events nest (a
+function's covers those of the functions it calls): sum them as a union.
 
-MFU is only reported when the caller supplies both a per-step FLOP count
-and a roofline (FLOP/s ceiling, the number ``bench.measure_roofline``
-produces) — no silent guessing.
+:func:`train_telemetry` is the process-wide instance a trainer records into
+when it is handed none — always recording into a bounded ring, exporting
+nothing unless asked.
 """
 
 from __future__ import annotations
@@ -27,71 +30,177 @@ from __future__ import annotations
 import json
 import threading
 import time
+import weakref
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from deepdfa_tpu.obs.registry import MetricsRegistry
 from deepdfa_tpu.obs.tracing import Tracer
 
-__all__ = ["TrainTelemetry", "TelemetryServer"]
+__all__ = ["TrainTelemetry", "TelemetryServer", "train_telemetry"]
+
+# a 22 s benchmark window at three times today's 7-8 steps/s, 6 spans a
+# step, with room for the set-up's compile events before it
+RING_SPANS = 16_384
+
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.backend_compile",
+}
+# jax reports a trace event for every jnp call inside a function being
+# traced, each nested in its caller's: a train step fires thousands, a few
+# microseconds long. Those are counted in their caller's event; only a trace
+# of at least this long becomes a span (compiles are always spans).
+MIN_TRACE_SPAN_S = 1e-3
+_JIT_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_lock = threading.RLock()
+_listening: weakref.WeakSet | None = None  # None until the listeners are registered
+_process_telemetry: "TrainTelemetry | None" = None
+
+
+def _listen(telemetry: "TrainTelemetry") -> None:
+    """Feed ``telemetry`` jax's compile events; the first call registers the
+    process's listeners (jax.monitoring cannot say whether one is there)."""
+    global _listening
+    with _lock:
+        if _listening is None:
+            from jax import monitoring
+
+            _listening = weakref.WeakSet()
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+        _listening.add(telemetry)
+
+
+def _live() -> list["TrainTelemetry"]:
+    with _lock:
+        return list(_listening or ())
+
+
+# jax calls the listeners from inside its compile path: they never raise there
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    name = _JIT_SPANS.get(event)
+    if name is None:
+        return
+    try:
+        for telemetry in _live():
+            telemetry.observe_jit(name, duration_secs, kwargs.get("fun_name"))
+    except Exception:  # noqa: BLE001 — telemetry must not fail a compile
+        pass
+
+
+def _on_event(event: str, **_kwargs) -> None:
+    name = _JIT_COUNTS.get(event)
+    if name is None:
+        return
+    try:
+        for telemetry in _live():
+            telemetry.observe_count(name)
+    except Exception:  # noqa: BLE001
+        pass
+
+
+def train_telemetry() -> "TrainTelemetry":
+    """The process-wide :class:`TrainTelemetry`: what ``JointTrainer``
+    records into when it is given none, and how anything else in the
+    process (a benchmark's reader, a debugger) reaches those spans:
+    ``train_telemetry().tracer.spans()``. A ring of ``RING_SPANS``, no
+    exemplar directory, no HTTP server."""
+    global _process_telemetry
+    with _lock:
+        if _process_telemetry is None:
+            _process_telemetry = TrainTelemetry()
+        return _process_telemetry
 
 
 class TrainTelemetry:
     """Aggregates per-step timings; thread-safe (the watchdog may drive
-    steps from a worker thread)."""
+    steps from a worker thread, the prefetch producer records from its
+    own)."""
 
-    def __init__(self, tracer: Tracer | None = None,
-                 roofline_flops_per_s: float | None = None,
-                 slo=None, flight=None):
-        self.tracer = tracer if tracer is not None else Tracer(proc="train")
-        self.roofline_flops_per_s = roofline_flops_per_s
+    def __init__(self, tracer: Tracer | None = None, slo=None, flight=None):
+        if tracer is None:
+            from jax.profiler import TraceAnnotation
+
+            tracer = Tracer(proc="train", max_spans=RING_SPANS,
+                            annotation=TraceAnnotation)
+        self.tracer = tracer
         # verdict-layer attachments (both optional): the SLO engine backs
         # the /slo endpoint; the flight recorder takes step/fault events
         self.slo = slo
         self.flight = flight
         self._lock = threading.Lock()
-        self._shapes: set = set()
         self._started_s = time.time()
         # cumulative (lifetime) and window (since last epoch_stats) tallies
         self._cum = self._zero()
         self._win = self._zero()
         self.epoch = -1
         self.last_step_s = 0.0
-        self.last_mfu: float | None = None
+        _listen(self)
 
     @staticmethod
     def _zero() -> dict:
         return {"steps": 0, "wall_s": 0.0, "data_wait_s": 0.0,
-                "dispatch_s": 0.0, "compiles": 0, "flops": 0.0,
-                "mfu_sum": 0.0, "mfu_n": 0}
+                "dispatch_s": 0.0, "sync_s": 0.0, "build_s": 0.0,
+                "h2d_s": 0.0, "compiles": 0, "cache_hits": 0,
+                "cache_misses": 0}
 
-    # -- feed path (inside train_epoch) -------------------------------------
+    # -- feed path (inside the train loops) ---------------------------------
 
     def observe_step(self, wait_s: float, dispatch_s: float,
-                     shape_key=None, flops: float | None = None) -> None:
+                     sync_s: float = 0.0) -> None:
+        """One step's host times: waiting for the batch, inside the call of
+        the step, and (where the loop reads the loss each step) waiting for
+        the device in that read."""
         wait_s = max(0.0, float(wait_s))
         dispatch_s = max(0.0, float(dispatch_s))
-        mfu = None
-        if (flops and self.roofline_flops_per_s
-                and dispatch_s > 0 and self.roofline_flops_per_s > 0):
-            mfu = float(flops) / dispatch_s / self.roofline_flops_per_s
+        sync_s = max(0.0, float(sync_s))
         with self._lock:
-            compiled = shape_key is not None and shape_key not in self._shapes
-            if compiled:
-                self._shapes.add(shape_key)
             for t in (self._cum, self._win):
                 t["steps"] += 1
-                t["wall_s"] += wait_s + dispatch_s
+                t["wall_s"] += wait_s + dispatch_s + sync_s
                 t["data_wait_s"] += wait_s
                 t["dispatch_s"] += dispatch_s
-                t["compiles"] += int(compiled)
-                if flops:
-                    t["flops"] += float(flops)
-                if mfu is not None:
-                    t["mfu_sum"] += mfu
-                    t["mfu_n"] += 1
-            self.last_step_s = wait_s + dispatch_s
-            if mfu is not None:
-                self.last_mfu = mfu
+                t["sync_s"] += sync_s
+            self.last_step_s = wait_s + dispatch_s + sync_s
+
+    _PRODUCER_TALLY = {"batch.build": "build_s", "batch.h2d": "h2d_s"}
+
+    def observe_producer(self, span) -> None:
+        """A closed ``batch.build`` / ``batch.h2d`` span, from the prefetch
+        producer's thread (``prefetch_to_device(on_span=...)``): the ring
+        forgets, these totals do not."""
+        key = self._PRODUCER_TALLY.get(span.name)
+        if key is not None:
+            with self._lock:
+                self._cum[key] += span.dur_s
+                self._win[key] += span.dur_s
+
+    def observe_jit(self, name: str, duration_s: float,
+                    fun_name: str | None = None) -> None:
+        """One of jax's compile events, just over, on the thread that
+        compiled."""
+        if name == "jit.trace" and duration_s < MIN_TRACE_SPAN_S:
+            return
+        end_s = time.time()
+        within = self.tracer.current_span()
+        attrs = {} if fun_name is None else {"fun_name": fun_name}
+        if within is not None and "step" in within.attrs:
+            attrs["step"] = within.attrs["step"]
+        self.tracer.record(name, end_s - duration_s, end_s,
+                           parent=None if within is None else within.ctx,
+                           **attrs)
+        if name == "jit.backend_compile":
+            self.observe_count("compiles")
+
+    def observe_count(self, name: str) -> None:
+        with self._lock:
+            self._cum[name] += 1
+            self._win[name] += 1
 
     def observe_epoch(self, epoch: int) -> None:
         with self._lock:
@@ -107,14 +216,17 @@ class TrainTelemetry:
             "wall_s": round(t["wall_s"], 6),
             "data_wait_s": round(t["data_wait_s"], 6),
             "dispatch_s": round(t["dispatch_s"], 6),
+            "sync_s": round(t["sync_s"], 6),
+            "build_s": round(t["build_s"], 6),
+            "h2d_s": round(t["h2d_s"], 6),
             "compiles": t["compiles"],
+            "cache_hits": t["cache_hits"],
+            "cache_misses": t["cache_misses"],
         }
         if steps:
             out["mean_step_ms"] = round(t["wall_s"] / steps * 1e3, 4)
             out["data_wait_frac"] = round(
                 t["data_wait_s"] / t["wall_s"], 6) if t["wall_s"] else 0.0
-        if t["mfu_n"]:
-            out["mfu"] = round(t["mfu_sum"] / t["mfu_n"], 6)
         return out
 
     def epoch_stats(self) -> dict:
@@ -138,28 +250,38 @@ class TrainTelemetry:
         reg = MetricsRegistry("deepdfa_train_")
         with self._lock:
             cum = dict(self._cum)
-            epoch, last_step_s, last_mfu = (
-                self.epoch, self.last_step_s, self.last_mfu)
+            epoch, last_step_s = self.epoch, self.last_step_s
             dropped = self.tracer.dropped_total
         reg.counter("steps_total", "Training steps completed").set(
             cum["steps"])
         reg.counter("compiles_total",
-                    "Distinct batch-shape signatures seen (jit compiles)"
+                    "XLA backend compiles jax reported (cache reads included)"
                     ).set(cum["compiles"])
+        reg.counter("compile_cache_hits_total",
+                    "Compiles answered by the persistent compilation cache"
+                    ).set(cum["cache_hits"])
+        reg.counter("compile_cache_misses_total",
+                    "Compiles the persistent compilation cache did not hold"
+                    ).set(cum["cache_misses"])
         reg.counter("data_wait_seconds_total",
                     "Host seconds spent waiting on the input stream").set(
             round(cum["data_wait_s"], 6))
         reg.counter("dispatch_seconds_total",
                     "Host seconds spent in step dispatch").set(
             round(cum["dispatch_s"], 6))
+        reg.counter("loss_sync_seconds_total",
+                    "Host seconds the loop waited for the device in its "
+                    "loss read").set(round(cum["sync_s"], 6))
+        reg.counter("prefetch_build_seconds_total",
+                    "Producer-thread seconds spent building batches").set(
+            round(cum["build_s"], 6))
+        reg.counter("prefetch_h2d_seconds_total",
+                    "Producer-thread seconds spent staging batches on the "
+                    "device").set(round(cum["h2d_s"], 6))
         reg.gauge("epoch", "Current epoch index").set(epoch)
         reg.gauge("last_step_seconds",
                   "Host wall time of the most recent step").set(
             round(last_step_s, 6))
-        if last_mfu is not None:
-            reg.gauge("mfu", "Model FLOP utilization of the last measured "
-                             "step vs the bench roofline").set(
-                round(last_mfu, 6))
         reg.counter("trace_spans_dropped_total",
                     "Spans lost by the trainer tracer (never fatal)").set(
             dropped)
@@ -185,8 +307,7 @@ class TrainTelemetry:
 
             self.slo = SLOEngine((), flight=self.flight)
         snap = self.snapshot()
-        self.slo.observe({"mean_step_ms": snap.get("mean_step_ms"),
-                          "mfu": snap.get("mfu")})
+        self.slo.observe({"mean_step_ms": snap.get("mean_step_ms")})
         return self.slo.render("deepdfa_train_")
 
 

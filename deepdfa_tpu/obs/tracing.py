@@ -30,8 +30,15 @@ fault point (``DEEPDFA_FAULTS`` grammar) injects exactly that loss so
 the chaos battery can prove it — a dropped span bumps
 ``dropped_total`` and nothing else.
 
-All span timestamps are wall-clock (``time.time()``) so spans recorded
-in different processes land on one consistent export timeline.
+Clocks: a span's start is wall-clock (``time.time()``) so spans recorded
+in different processes land on one consistent export timeline; the
+duration of a :meth:`Tracer.span` is taken from ``time.perf_counter()``.
+A tracer built with ``annotation=jax.profiler.TraceAnnotation`` also puts
+every :meth:`Tracer.span` on the profiler's own clock as ``deepdfa:<name>``
+(a flag test while no profiler session runs; :meth:`Tracer.record` tells of
+an interval that has passed, which the profiler cannot take). The class is
+a constructor argument so that this module imports no jax: the router
+records spans without one.
 """
 
 from __future__ import annotations
@@ -52,6 +59,7 @@ __all__ = [
     "SpanContext",
     "Span",
     "Tracer",
+    "no_span",
     "new_trace_id",
     "new_span_id",
     "parse_traceparent",
@@ -130,6 +138,7 @@ class Span:
             "dur_ms": round(self.dur_s * 1e3, 4),
             "root": self.root,
             "attrs": dict(self.attrs),
+            "tid": self.tid,
         }
 
 
@@ -139,8 +148,11 @@ class Tracer:
     def __init__(self, proc: str = "serve", max_spans: int = 4096,
                  slow_ms: float | None = None,
                  exemplar_dir: str | Path | None = None,
-                 max_exemplars: int = 16):
+                 max_exemplars: int = 16, annotation=None):
         self.proc = proc
+        # name, **attrs -> context manager on the profiler's clock
+        # (jax.profiler.TraceAnnotation); None records host spans only
+        self.annotation = annotation
         self.slow_ms = slow_ms
         self.exemplar_dir = Path(exemplar_dir) if exemplar_dir else None
         self.max_exemplars = int(max_exemplars)
@@ -150,22 +162,53 @@ class Tracer:
         self.recorded_total = 0
         self.dropped_total = 0
 
+    @property
+    def capacity(self) -> int:
+        """Spans the ring holds before the oldest falls off."""
+        return self._spans.maxlen
+
     # -- span creation ------------------------------------------------------
 
-    def _stack(self) -> list[SpanContext]:
+    def _stack(self) -> list[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         return stack
 
-    def current(self) -> SpanContext | None:
-        """Context of the innermost open span on THIS thread (what a
-        cross-thread handoff — e.g. a batcher submit — should carry)."""
+    def current_span(self) -> Span | None:
+        """The innermost open span on THIS thread: code that runs inside a
+        span someone else opened sets its counts on ``.attrs``."""
         stack = self._stack()
         return stack[-1] if stack else None
 
+    def current(self) -> SpanContext | None:
+        """Context of the innermost open span on THIS thread (what a
+        cross-thread handoff — e.g. a batcher submit — should carry)."""
+        sp = self.current_span()
+        return sp.ctx if sp is not None else None
+
+    def _annotate(self, name: str, attrs: dict):
+        """The entered profiler annotation of one span, or None. Like
+        ``_record`` it never raises into the code it annotates."""
+        if self.annotation is None:
+            return None
+        try:
+            ann = self.annotation(f"deepdfa:{name}", **attrs)
+            ann.__enter__()
+            return ann
+        except Exception:  # noqa: BLE001 — tracing is strictly best-effort
+            return None
+
+    @staticmethod
+    def _end_annotation(ann) -> None:
+        if ann is not None:
+            try:
+                ann.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001
+                pass
+
     @contextmanager
-    def span(self, name: str, parent: SpanContext | None = None,
+    def span(self, name: str, /, parent: SpanContext | None = None,
              root: bool = False, **attrs):
         """Open one span. ``parent`` wins; otherwise the innermost open
         span on this thread; otherwise a fresh trace is started. The
@@ -182,20 +225,25 @@ class Tracer:
                   root=root, attrs=dict(attrs),
                   tid=threading.get_ident() % 1_000_000)
         stack = self._stack()
-        stack.append(sp.ctx)
+        stack.append(sp)
+        ann = self._annotate(name, attrs)
+        t0 = time.perf_counter()
         try:
             yield sp
         finally:
+            sp.dur_s = time.perf_counter() - t0
+            self._end_annotation(ann)
             stack.pop()
-            sp.dur_s = max(0.0, time.time() - sp.start_s)
             self._record(sp)
 
-    def record(self, name: str, start_s: float, end_s: float | None = None,
+    def record(self, name: str, /, start_s: float, end_s: float | None = None,
                parent: SpanContext | None = None, root: bool = False,
                **attrs) -> Span:
         """Record a span from explicit wall-clock times — the cross-thread
         path (queue wait) and the measured-after-the-fact path (a step
-        already timed by its caller)."""
+        already timed by its caller, a compile jax reports when it is
+        over). Host ring only: the profiler cannot be told of an interval
+        that has passed."""
         end_s = time.time() if end_s is None else end_s
         if parent is None:
             trace_id, parent_id = new_trace_id(), None
@@ -279,6 +327,13 @@ class Tracer:
 
 # ---------------------------------------------------------------------------
 # Chrome / Perfetto trace-event export
+
+
+@contextmanager
+def no_span(*_args, **_attrs):
+    """What code that may run without a tracer opens in the place of
+    ``tracer.span(...)``: yields None, records nothing."""
+    yield None
 
 
 def chrome_trace(spans) -> dict:

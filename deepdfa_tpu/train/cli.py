@@ -406,8 +406,7 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
             capacity=obs.flight_events, proc="train",
             dump_dir=Path(obs.flight_dir) if obs.flight_dir else run_dir)
         slo = SLOEngine(
-            train_specs(step_ms=obs.slo_step_ms,
-                        mfu_floor=obs.slo_mfu_floor),
+            train_specs(step_ms=obs.slo_step_ms),
             fast_window_s=obs.slo_fast_window_s,
             slow_window_s=obs.slo_slow_window_s,
             burn_threshold=obs.slo_burn_threshold,
@@ -417,7 +416,8 @@ def fit(cfg: ExperimentConfig, run_dir: Path, resume: bool = False) -> dict[str,
             slow_ms=0.0,  # journal every epoch root, capped by max_exemplars
             exemplar_dir=(Path(obs.trace_dir) if obs.trace_dir
                           else run_dir / "traces"),
-            max_exemplars=obs.max_exemplars),
+            max_exemplars=obs.max_exemplars,
+            annotation=jax.profiler.TraceAnnotation),
             slo=slo, flight=flight)
         install_sigusr2(flight)  # no-op off the main thread
         if obs.train_port >= 0:

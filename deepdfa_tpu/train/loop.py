@@ -24,8 +24,6 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
-import time
-from contextlib import nullcontext
 from functools import partial
 from typing import Any, Callable, Iterable, NamedTuple
 
@@ -37,6 +35,7 @@ from deepdfa_tpu.config import ExperimentConfig
 from deepdfa_tpu.resilience import faults
 from deepdfa_tpu.data.graphs import BatchedGraphs
 from deepdfa_tpu.models.ggnn import GGNN
+from deepdfa_tpu.obs.tracing import no_span
 from deepdfa_tpu.ops.segment import segment_max
 from deepdfa_tpu.train.metrics import ConfusionState, compute_metrics, update_confusion
 
@@ -392,15 +391,19 @@ class Trainer:
         params = model.init(init_rng, example_batch)["params"]
         return TrainState(params, self.optimizer.init(params), rng, jnp.zeros((), jnp.int32))
 
-    def _stream(self, batches: Iterable[BatchedGraphs]):
+    def _stream(self, batches: Iterable[BatchedGraphs], telemetry=None):
         """Host→device prefetch for every consumer (train/eval/test): the
         background thread stages the next ``data.prefetch`` batches on
         device while the current step runs — the reference's DataLoader
         ``train_workers`` analogue (``datamodule.py:110-129``)."""
         from deepdfa_tpu.data.prefetch import prefetch_to_device
 
+        size = getattr(self.cfg.data, "prefetch", 2)
+        if telemetry is None:
+            return prefetch_to_device(batches, size=size)
         return prefetch_to_device(
-            batches, size=getattr(self.cfg.data, "prefetch", 2)
+            batches, size=size, tracer=telemetry.tracer,
+            on_span=telemetry.observe_producer,
         )
 
     def train_epoch(
@@ -447,24 +450,23 @@ class Trainer:
         hang_armed = watchdog is not None and faults.active("step.hang")
         consumed = 0
         self.twin_routed_steps = 0
-        stream = self._stream(batches)
         # telemetry (obs.TrainTelemetry) is timing-only: it must not touch
         # batches, rng, or step order, so a telemetered epoch stays
         # bit-identical to a bare one (the elasticity invariants depend on
-        # that). Its tracer hangs every step's spans under one epoch root.
+        # that). Its tracer hangs every step's spans, and the prefetch
+        # producer's, under one epoch root.
         tracer = telemetry.tracer if telemetry is not None else None
-        epoch_cm = (tracer.span("train.epoch", root=True)
-                    if tracer is not None else nullcontext())
+        span = tracer.span if tracer is not None else no_span
+        stream = None
         try:
-            with epoch_cm as epoch_sp:
+            with span("train.epoch", root=True):
+                stream = self._stream(batches, telemetry)
                 it = iter(stream)
                 while True:
-                    t_wait = time.time()
-                    try:
-                        batch = next(it)
-                    except StopIteration:
+                    with span("data.wait", step=consumed) as wait:
+                        batch = next(it, None)
+                    if batch is None:
                         break
-                    wait_end = time.time()
                     if consumed < skip_steps:
                         consumed += 1
                         continue
@@ -516,28 +518,16 @@ class Trainer:
                         if nan_fired
                         else (state, batch, metrics)
                     )
-                    t_disp = time.time()
-                    if watchdog is not None:
-                        state, metrics, loss, wsum = watchdog.call(
-                            "train_step", step, *args
-                        )
-                    else:
-                        state, metrics, loss, wsum = step(*args)
-                    disp_end = time.time()
+                    with span("step.dispatch", step=consumed) as dispatch:
+                        if watchdog is not None:
+                            state, metrics, loss, wsum = watchdog.call(
+                                "train_step", step, *args
+                            )
+                        else:
+                            state, metrics, loss, wsum = step(*args)
                     consumed += 1
                     if telemetry is not None:
-                        shape_key = tuple(
-                            tuple(getattr(leaf, "shape", ()))
-                            for leaf in jax.tree.leaves(batch))
-                        telemetry.observe_step(
-                            wait_end - t_wait, disp_end - t_disp,
-                            shape_key=shape_key)
-                        if tracer is not None:
-                            parent = None if epoch_sp is None else epoch_sp.ctx
-                            tracer.record("data.wait", t_wait, wait_end,
-                                          parent=parent, step=consumed - 1)
-                            tracer.record("step.dispatch", t_disp, disp_end,
-                                          parent=parent, step=consumed - 1)
+                        telemetry.observe_step(wait.dur_s, dispatch.dur_s)
                     if sentinel is not None:
                         sentinel.observe(loss)
                     losses.append(loss)
@@ -546,19 +536,13 @@ class Trainer:
                     sentinel.flush()
                 # the host-side reduction below is where the epoch's async
                 # dispatches actually block — the device.sync span
-                t_sync = time.time()
-                out = (state, compute_metrics(metrics, "train_"),
-                       _weighted_mean(losses, wsums))
-                if tracer is not None:
-                    tracer.record(
-                        "device.sync", t_sync,
-                        parent=None if epoch_sp is None else epoch_sp.ctx,
-                        n_steps=consumed)
-                return out
+                with span("device.sync", n_steps=consumed):
+                    return (state, compute_metrics(metrics, "train_"),
+                            _weighted_mean(losses, wsums))
         finally:
             # deterministic producer shutdown even when the sentinel raises
             # mid-epoch (prefetch_to_device joins its thread on close)
-            if hasattr(stream, "close"):
+            if stream is not None:
                 stream.close()
 
     def evaluate(

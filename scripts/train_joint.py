@@ -17,6 +17,7 @@ Usage:
   python scripts/preprocess.py --dataset demo --n 200
   python scripts/train_joint.py --dataset demo --do_train --do_test --epochs 2
   python scripts/train_joint.py --preset bigvul_ft_bigvul --hf-checkpoint /path ...
+  python scripts/report_profiling.py --traces <run_dir>   # the epochs' spans by name
 """
 
 from __future__ import annotations
@@ -376,9 +377,17 @@ def main(argv=None) -> dict:
     run_dir = Path(args.output_dir) if args.output_dir else utils.get_dir(
         utils.storage_dir() / "joint_runs" / utils.get_run_id()
     )
+    # every epoch's spans (steps, producer, `eval`, `checkpoint.save`) are
+    # journaled as one exemplar under <run_dir>/traces, as `deepdfa-tpu fit`
+    # does: `report_profiling.py --traces <run_dir>` reads them by name
+    from deepdfa_tpu.obs import Tracer, TrainTelemetry
+
     trainer = JointTrainer(
         llm=llm, llm_params=llm_params, fusion=fusion, cfg=jcfg,
         join=join, run_dir=run_dir,
+        telemetry=TrainTelemetry(tracer=Tracer(
+            proc="train", slow_ms=0.0, exemplar_dir=run_dir / "traces",
+            annotation=jax.profiler.TraceAnnotation)),
     )
 
     out: dict = {"run_dir": str(run_dir), "n_train": len(train_ex)}

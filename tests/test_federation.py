@@ -904,7 +904,13 @@ def test_idle_replica_burn_decays_not_freezes(demo):
     srv.start()
     try:
         assert _post_score(srv.port, "int f(int x) { return x; }")[0] == 200
+        # the handler counts a response after it has sent it: the client
+        # can be here first, so look until the count is in
+        deadline = time.monotonic() + 5.0
         burn_hot = srv.slo.worst_fast_burn() or srv._observe_fast_burn()
+        while not burn_hot and time.monotonic() < deadline:
+            time.sleep(0.01)
+            burn_hot = srv._observe_fast_burn()
         assert burn_hot is not None and burn_hot > 1.0  # absurd target
         time.sleep(0.5)  # a full fast window with zero traffic
         burn_idle = srv._observe_fast_burn()

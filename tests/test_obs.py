@@ -72,6 +72,71 @@ def test_tracer_nesting_and_bounded_buffer():
     assert tracer.recorded_total == 12
 
 
+def test_tracer_clocks_and_profiler_annotations():
+    """A span keeps its wall-clock start and takes its duration from
+    perf_counter; with an ``annotation`` class every ``span`` is also put on
+    the profiler's clock as ``deepdfa:<name>``."""
+    from deepdfa_tpu.obs import Tracer
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **attrs):
+            self.row = [name, attrs]
+            seen.append(self.row)
+
+        def __enter__(self):
+            self.row.append("in")
+
+        def __exit__(self, *exc):
+            self.row.append("out")
+
+    tracer = Tracer(proc="t", max_spans=2, annotation=Annotation)
+    assert tracer.capacity == 2 and tracer.current_span() is None
+    before = time.time()
+    with tracer.span("step.dispatch", step=7) as sp:
+        assert tracer.current_span() is sp
+        tracer.current_span().attrs["rows"] = 16  # set by whoever runs inside
+        time.sleep(0.02)
+    assert before <= sp.start_s <= time.time()
+    assert 0.02 <= sp.dur_s < 1.0
+    assert sp.attrs == {"step": 7, "rows": 16}
+    assert sp.to_record()["tid"] == sp.tid
+    assert seen == [["deepdfa:step.dispatch", {"step": 7}, "in", "out"]]
+    # a span told after the fact cannot be backdated on the profiler's
+    # clock: it goes to the host ring only
+    tracer.record("jit.lower", 100.0, 100.25, name="train_step")
+    assert len(seen) == 1
+    assert tracer.spans()[-1].attrs == {"name": "train_step"}
+    assert tracer.spans()[-1].dur_s == 0.25
+    # no annotation class (the router, the server): host spans only
+    plain = Tracer(proc="t")
+    with plain.span("x"):
+        pass
+    assert plain.annotation is None and len(plain) == 1
+
+
+def test_process_wide_train_telemetry_is_one_bounded_ring():
+    import jax
+
+    from deepdfa_tpu import obs
+    from deepdfa_tpu.obs.telemetry import RING_SPANS
+
+    t = obs.train_telemetry()
+    assert obs.train_telemetry() is t
+    assert t.tracer.capacity == RING_SPANS == 16_384
+    assert t.tracer.annotation is jax.profiler.TraceAnnotation
+    assert t.tracer.exemplar_dir is None and t.slo is None and t.flight is None
+    # compile events reach every live telemetry, not only the newest
+    mine = obs.TrainTelemetry()
+    before = t.snapshot()["compiles"]
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.01)
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert t.snapshot()["compiles"] == before + 1
+    assert mine.snapshot()["compiles"] == 1 and mine.snapshot()["cache_misses"] == 1
+
+
 # ---------------------------------------------------------------------------
 # exposition conformance — the ONE checker all three endpoints must pass
 
@@ -181,15 +246,26 @@ def test_router_exposition_conformance():
 def test_train_exposition_conformance():
     from deepdfa_tpu.obs import TrainTelemetry
 
-    t = TrainTelemetry(roofline_flops_per_s=1e12)
+    import jax.monitoring
+
+    t = TrainTelemetry()
     t.observe_epoch(0)
-    t.observe_step(0.01, 0.02, shape_key=("a",), flops=1e9)
-    t.observe_step(0.01, 0.02, shape_key=("a",), flops=1e9)
+    t.observe_step(0.01, 0.02, 0.25)
+    t.observe_step(0.01, 0.02, 0.25)
+    # a compile as jax reports it when it is over (no XLA compile here)
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.5, fun_name="step")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    with t.tracer.span("batch.build"):
+        pass
     text = t.render()
     _assert_exposition(text)
     assert "deepdfa_train_steps_total 2" in text
     assert "deepdfa_train_compiles_total 1" in text
-    assert "deepdfa_train_mfu " in text
+    assert "deepdfa_train_compile_cache_hits_total 1" in text
+    assert "deepdfa_train_loss_sync_seconds_total 0.5" in text
+    assert "deepdfa_train_prefetch_build_seconds_total " in text
+    assert "deepdfa_train_prefetch_h2d_seconds_total 0" in text
 
 
 def test_registry_label_escaping_and_histogram_cumulation():
@@ -255,17 +331,34 @@ def test_psi_symmetric_properties():
 def test_train_telemetry_windows_and_server_scrape():
     from deepdfa_tpu.obs import TelemetryServer, TrainTelemetry
 
+    import jax.monitoring
+
     t = TrainTelemetry()
     t.observe_epoch(3)
-    t.observe_step(0.010, 0.030, shape_key=(("8",),))
-    t.observe_step(0.005, 0.015, shape_key=(("8",),))
+    t.observe_step(0.010, 0.030)
+    t.observe_step(0.005, 0.015, 0.060)
+    for event, secs in (("jaxpr_trace_duration", 0.1),
+                        ("jaxpr_to_mlir_module_duration", 0.2),
+                        ("backend_compile_duration", 0.3)):
+        jax.monitoring.record_event_duration_secs(
+            f"/jax/core/compile/{event}", secs, fun_name="train_step")
+    jax.monitoring.record_event_duration_secs("/jax/other/event", 9.0)
+    # the jnp calls inside a traced function: thousands, microseconds, no span
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 2e-5, fun_name="add")
     epoch = t.epoch_stats()                 # drains the window...
     assert epoch["steps"] == 2 and epoch["compiles"] == 1
-    assert epoch["data_wait_frac"] == pytest.approx(0.25, abs=0.01)
-    assert t.epoch_stats()["steps"] == 0    # ...which resets
+    assert epoch["data_wait_frac"] == pytest.approx(0.125, abs=0.01)
+    assert epoch["sync_s"] == pytest.approx(0.060)
+    again = t.epoch_stats()                 # ...which resets
+    assert again["steps"] == 0 and again["compiles"] == 0
     snap = t.snapshot()                     # cumulative view unaffected
-    assert snap["steps"] == 2 and snap["epoch"] == 3
-    assert "mfu" not in snap                # no roofline supplied: no guess
+    assert snap["steps"] == 2 and snap["epoch"] == 3 and snap["compiles"] == 1
+    # each event is a span [now - duration, now] carrying jax's fun_name
+    jit = {s.name: s for s in t.tracer.spans() if s.name.startswith("jit.")}
+    assert sorted(jit) == ["jit.backend_compile", "jit.lower", "jit.trace"]
+    assert jit["jit.lower"].dur_s == pytest.approx(0.2)
+    assert jit["jit.lower"].attrs == {"fun_name": "train_step"}
 
     srv = TelemetryServer(t, port=0).start()
     try:
@@ -732,15 +825,18 @@ def test_slo_engine_gauge_floor_and_never_raises():
     from deepdfa_tpu.obs import SLOEngine, train_specs
 
     t = [0.0]
-    eng = SLOEngine(train_specs(step_ms=100.0, mfu_floor=0.4),
+    from deepdfa_tpu.obs import SLOSpec
+
+    floor = SLOSpec("rate_floor", "min", 0.4, value="steps_per_s")
+    eng = SLOEngine(train_specs(step_ms=100.0) + (floor,),
                     fast_window_s=10.0, slow_window_s=10.0,
                     clock=lambda: t[0])
     for _ in range(3):
         t[0] += 1.0
-        eng.observe({"mean_step_ms": 250.0, "mfu": 0.1})
+        eng.observe({"mean_step_ms": 250.0, "steps_per_s": 0.1})
     by_name = {s["slo"]: s for s in eng.statuses()}
     assert by_name["step_time"]["alert"] is True       # 250/100 = 2.5 > 1
-    assert by_name["mfu_floor"]["alert"] is True       # 0.4/0.1 = 4 > 1
+    assert by_name["rate_floor"]["alert"] is True      # 0.4/0.1 = 4 > 1
     # a hostile snapshot cannot fail the scrape (invariant 14)
     assert eng.observe(None) == []
     assert eng.observe({"mean_step_ms": "not-a-number"}) == []
@@ -797,7 +893,7 @@ def test_slo_endpoint_on_all_three_processes(demo):
     telemetry = TrainTelemetry(
         slo=SLOEngine(train_specs(step_ms=100.0), fast_window_s=10.0,
                       slow_window_s=10.0))
-    telemetry.observe_step(0.01, 0.02, shape_key=("a",))
+    telemetry.observe_step(0.01, 0.02)
     tsrv = TelemetryServer(telemetry, port=0).start()
     try:
         _req(router.port, "POST", "/score",

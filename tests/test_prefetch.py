@@ -72,6 +72,46 @@ def _producer_threads():
     ]
 
 
+@pytest.mark.parametrize("size", [2, 0])
+def test_tracer_gets_the_producers_spans(size):
+    """One ``batch.build`` round each pull (the upstream generator runs
+    inside it and may set its counts) and one ``batch.h2d`` a staged item,
+    under the span that was open where the stream was made; ``on_span`` is
+    handed each once it has closed."""
+    import threading
+
+    from deepdfa_tpu.obs import Tracer
+
+    tracer = Tracer(proc="t")
+    closed = []
+
+    def gen():
+        for i in range(3):
+            tracer.current_span().attrs["rows"] = i
+            yield {"x": np.full((2,), i, np.float32)}
+
+    with tracer.span("train.epoch", root=True) as root:
+        stream = prefetch_to_device(
+            gen(), size=size, tracer=tracer, on_span=closed.append)
+        with tracer.span("data.wait"):  # open on the consumer's thread only
+            first = next(stream)
+        rest = list(stream)
+    assert [float(o["x"][0]) for o in [first, *rest]] == [0.0, 1.0, 2.0]
+    spans = tracer.spans()
+    builds = [s for s in spans if s.name == "batch.build"]
+    assert [s.attrs for s in builds] == [{"rows": 0}, {"rows": 1}, {"rows": 2},
+                                         {"exhausted": True}]
+    h2d = [s for s in spans if s.name == "batch.h2d"]
+    assert len(h2d) == (3 if size else 0)  # pass-through stages nothing
+    assert closed == [s for s in spans if s.name.startswith("batch.")]
+    here = threading.get_ident() % 1_000_000
+    if size:
+        assert {s.parent_id for s in builds + h2d} == {root.span_id}
+        assert here not in {s.tid for s in builds + h2d}
+    else:
+        assert {s.tid for s in builds} == {here}
+
+
 @pytest.mark.faults
 def test_abandoned_iterator_joins_producer_thread():
     """Regression: breaking out of the consumer loop used to leave the
