@@ -6,7 +6,9 @@ collation overlapped with GPU compute). The JAX-native equivalent is a
 background thread that builds the next batches and stages them on device
 (``jax.device_put``) while the current step runs: device dispatch is async,
 so the only way the host stalls the chip is by not having the NEXT batch
-ready — exactly what this removes.
+ready — exactly what this removes. The loop must also not wait for a step
+(read its loss) before launching the next: ``JointTrainer.train`` keeps one
+step in flight, else the staged batch sits through the whole launch.
 
 Usage::
 

@@ -361,6 +361,20 @@ class JointTrainer:
                     tokens_real=int(tb.pad_mask.sum()), tokens=tb.pad_mask.size)
             yield jb
 
+    def _read_loss(self, pending: tuple, alone: bool) -> float:
+        """The loss of the launched step ``pending``, read through
+        ``float(loss)`` — where the loop waits for the device — inside that
+        step's ``loss.sync`` span. ``alone`` says no later step had been
+        launched at the read (an evaluation point, the epoch's end): the
+        device then idles through the next launch."""
+        step, loss, wait_s, dispatch_s = pending
+        with self.telemetry.tracer.span(
+            "loss.sync", step=step, reads=1, alone=int(alone)
+        ) as sync:
+            value = float(loss)
+        self.telemetry.observe_step(wait_s, dispatch_s, sync.dur_s)
+        return value
+
     def _build(
         self, steps_per_epoch: int, example: JoinedBatch, params: Any | None = None
     ) -> JointState | None:
@@ -414,7 +428,10 @@ class JointTrainer:
                 seed=cfg.seed + epoch,
             )
             points = eval_points(n_batches, epoch, cfg)
-            tr_loss, tr_num = 0.0, 0
+            tr_loss = 0.0
+            # the step in flight: (index, loss, its data.wait and
+            # step.dispatch seconds), launched and not yet read
+            pending = None
             with tracer.span("train.epoch", root=True, epoch=epoch):
                 # overlap the host-side graph join + H2D transfer with the
                 # running step (the index-join per batch is real host work —
@@ -442,26 +459,33 @@ class JointTrainer:
                                 state, loss, _probs = train_step(
                                     state, self._llm_arg, jb
                                 )
-                            # the loop reads each loss: where it waits for
-                            # the device
-                            with tracer.span("loss.sync", step=step) as sync:
-                                tr_loss += float(loss)
-                            tr_num += 1
-                            telemetry.observe_step(
-                                wait.dur_s, dispatch.dur_s, sync.dur_s
-                            )
+                            # one step stays in flight: this one is queued on
+                            # the device before the loop waits for the loss of
+                            # the one before it, so the host's launch runs
+                            # beside the device instead of in front of it
+                            if pending is not None:
+                                tr_loss += self._read_loss(pending, alone=False)
+                            pending = (step, loss, wait.dur_s, dispatch.dur_s)
                             if step in points:
+                                tr_loss += self._read_loss(pending, alone=True)
+                                pending = None
                                 with tracer.span("eval", step=step):
                                     report = self.evaluate(state.params, eval_examples)
                                 self.history.append(
                                     {"epoch": epoch, "step": step, **report}
                                 )
+                    # the epoch's last step, before its mean and checkpoint.
+                    # Not in the ``finally``: after an exception the pending
+                    # loss is dropped (a read there could block on, or
+                    # re-raise from, a failed step and mask the first error)
+                    if pending is not None:
+                        tr_loss += self._read_loss(pending, alone=True)
                 finally:
                     # the producer still holds its end-of-stream marker (or,
                     # after an exception, staged batches): stop and join it
                     joined.close()
                 self.history.append(
-                    {"epoch": epoch, "train_loss": tr_loss / max(tr_num, 1),
+                    {"epoch": epoch, "train_loss": tr_loss / max(n_batches, 1),
                      "telemetry": telemetry.epoch_stats()}
                 )
                 if self.run_dir is not None:

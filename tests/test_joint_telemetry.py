@@ -256,3 +256,121 @@ def test_a_profiler_session_holds_the_programs_annotations(tiny, tmp_path):
     assert lines["deepdfa:batch.build"] == lines["deepdfa:batch.h2d"]
     assert lines["deepdfa:batch.build"].isdisjoint(loop)
     assert sorted(steps) == [0, 1, 2]
+
+
+# -- one step in flight: the loop launches step k, then reads step k-1's loss --
+
+class _Probe:
+    """A loss that notes when the loop reads it — through ``float()``, as the
+    benchmark's driver depends on."""
+
+    def __init__(self, value, step, events):
+        self._value, self._step, self._events = value, step, events
+
+    def __float__(self) -> float:
+        self._events.append(("read", self._step))
+        return float(self._value)
+
+
+def _probed(tiny, telemetry, raise_at=None):
+    """One epoch with every call of the step, every loss read and every
+    evaluation noted in order. Returns ``(events, state or the exception)``."""
+    trainer = tiny[0]
+    real_train, real_eval = trainer._steps
+    events: list[tuple] = []
+
+    def train_step(state, llm_arg, jb):
+        k = sum(1 for e in events if e[0] == "dispatch")
+        events.append(("dispatch", k))
+        if k == raise_at:
+            raise RuntimeError(f"step {k} failed")
+        state, loss, probs = real_train(state, llm_arg, jb)
+        return state, _Probe(loss, k, events), probs
+
+    def eval_step(*args):
+        if events[-1] != ("eval",):  # one mark an evaluation, not one a batch
+            events.append(("eval",))
+        return real_eval(*args)
+
+    trainer._steps = (train_step, eval_step)
+    try:
+        return events, _train(tiny, telemetry)
+    except RuntimeError as e:
+        return events, e
+    finally:
+        trainer._steps = (real_train, real_eval)
+
+
+def test_an_epoch_equals_a_loop_that_reads_every_loss_at_once(tiny):
+    """Same program, same order of updates, same sum: reading a loss a step
+    late changes no number."""
+    from deepdfa_tpu.llm.dataset import text_batches
+
+    trainer, examples, state0 = tiny
+    state = _train(tiny, _telemetry())
+    train_step, _ = trainer._steps
+    ref, tr_loss = state0, 0.0
+    for tb in text_batches(examples, 4, shuffle=True, seed=trainer.cfg.seed):
+        ref, loss, _ = train_step(ref, trainer._llm_arg, trainer._joined(tb))
+        tr_loss += float(loss)
+    assert _same(state.params, ref.params) and _same(state.opt_state, ref.opt_state)
+    assert np.array_equal(jax.random.key_data(state.rng), jax.random.key_data(ref.rng))
+    assert int(state.step) == int(ref.step) == 3
+    (epoch,) = [h for h in trainer.history if "train_loss" in h]
+    assert epoch["train_loss"] == tr_loss / 3
+    assert epoch["telemetry"]["steps"] == 3
+
+
+@pytest.mark.parametrize("points,expected", [
+    # the fixture's own eval point, the epoch's last step: its flush is the one lone read
+    (None, [("dispatch", 0), ("dispatch", 1), ("read", 0), ("dispatch", 2), ("read", 1),
+            ("read", 2), ("eval",)]),
+    # mid-epoch: the pending loss is read before the evaluation opens, the next
+    # step starts with nothing in flight, and the epoch's end flushes it
+    ([1], [("dispatch", 0), ("dispatch", 1), ("read", 0), ("read", 1), ("eval",),
+           ("dispatch", 2), ("read", 2)]),
+    # none: the end of the epoch flushes
+    ([], [("dispatch", 0), ("dispatch", 1), ("read", 0), ("dispatch", 2), ("read", 1),
+          ("read", 2)]),
+])
+def test_step_k_is_launched_before_the_loss_of_step_k_minus_1_is_read(
+        tiny, monkeypatch, points, expected):
+    from deepdfa_tpu.llm import joint
+
+    if points is not None:
+        monkeypatch.setattr(joint, "eval_points", lambda *_: set(points))
+    telemetry = _telemetry()
+    events, state = _probed(tiny, telemetry)
+    assert events == expected and int(state.step) == 3
+    # one loss.sync a step, named for the step whose loss it read; `alone`
+    # marks the reads with no later step launched: the flushes
+    spans = telemetry.tracer.spans()
+    sync = [s for s in spans if s.name == "loss.sync"]
+    reads = [k for kind, *k in events if kind == "read"]
+    assert [[s.attrs["step"]] for s in sync] == reads == [[0], [1], [2]]
+    flushes = [e[1] for at, e in enumerate(events)
+               if e[0] == "read" and ("dispatch", e[1] + 1) not in events[:at]]
+    assert [s.attrs["step"] for s in sync if s.attrs["alone"]] == flushes
+    assert {s.attrs["alone"] for s in sync} <= {0, 1} and {s.attrs["reads"] for s in sync} == {1}
+    # the spans closed in that order on the loop's thread (the ring keeps the
+    # order of closing): a dispatch before the read of the step before it
+    kinds = {"step.dispatch": "dispatch", "loss.sync": "read", "eval": "eval"}
+    assert [kinds[s.name] for s in spans if s.name in kinds] == [e[0] for e in events]
+    (ev,) = [s for s in spans if s.name == "eval"] or [None]
+    if ev is not None:
+        read = next(s for s in sync if s.attrs["step"] == ev.attrs["step"])
+        assert read.start_s + read.dur_s <= ev.start_s + 1e-4
+    assert telemetry.epoch_stats()["steps"] == 0  # the epoch's window took all three
+
+
+@pytest.mark.parametrize("k", [0, 1, 2])
+def test_a_step_that_raises_leaves_as_itself_with_the_pending_loss_dropped(tiny, k):
+    telemetry = _telemetry()
+    events, error = _probed(tiny, telemetry, raise_at=k)
+    assert isinstance(error, RuntimeError) and str(error) == f"step {k} failed"
+    # the loss of step k-1 was in flight: dropped, never read
+    assert [e for e in events if e[0] == "read"] == [("read", s) for s in range(k - 1)]
+    assert events[-1] == ("dispatch", k)
+    assert not [t for t in threading.enumerate() if t.name == "prefetch_to_device"]
+    names = [s.name for s in telemetry.tracer.spans()]
+    assert names.count("loss.sync") == max(k - 1, 0) and names.count("train.epoch") == 1
