@@ -5,7 +5,11 @@ Where the reference leans on CUDA-only machinery — bitsandbytes 4-bit NF4
 quantization (``train.py:873-885``), HF accelerate ``device_map`` layer
 placement (``train.py:883``), ``torch.nn.DataParallel`` (``train.py:936``) —
 this package uses bf16 weights GSPMD-sharded over a named mesh (tp/fsdp for
-weights, dp for batch, sp + ring attention for long sequences).
+weights, dp for batch, sp + ring attention for long sequences; a routed
+decoder is told the range of experts its chip holds). Three encoder families
+drive the fusion head:
+``llama`` (causal, dense), ``roberta`` (bidirectional) and ``longcat``
+(causal, latent attention, routed experts).
 """
 
 from deepdfa_tpu.llm.llama import (  # noqa: F401
@@ -27,5 +31,10 @@ __all__ = [
     # fusion   — classification heads over LLM ⊕ GGNN
     # joint    — frozen-LLM joint trainer
     # generate — batch decoding
-    # presets  — the five launch configurations
+    # roberta  — bidirectional encoder (CodeBERT, the LineVul configurations)
+    # longcat  — latent attention + routed experts on a shortcut, zero-compute
+    #            experts; holds a range of experts (frozen decoder of the
+    #            joint classifier)
+    # presets  — the launch configurations: five MSIVD scripts (llama), two
+    #            LineVul (roberta), one routed decoder and its tiny twin (longcat)
 ]

@@ -44,7 +44,6 @@ import optax
 from deepdfa_tpu.data.prefetch import prefetch_to_device
 from deepdfa_tpu.llm.dataset import GraphJoin, JoinedBatch, TextExamples, text_batches
 from deepdfa_tpu.llm.fusion import FusionModel, fusion_loss
-from deepdfa_tpu.llm.llama import LlamaModel
 from deepdfa_tpu.train.metrics import classification_report
 
 __all__ = [
@@ -216,13 +215,17 @@ def eval_points(steps_per_epoch: int, epoch: int, cfg: JointConfig) -> set[int]:
 
 
 def make_joint_steps(
-    llm: LlamaModel,
+    llm: Any,  # any encoder module: (input_ids, pad_mask) -> hidden states
     fusion: FusionModel,
     tx: optax.GradientTransformation,
     train_llm: bool = False,
+    on_stats: Callable[[Any], None] | None = None,
 ) -> tuple[Callable, Callable]:
     """(train_step, eval_step), both jitted. ``llm_params`` is an input, not a
     capture, so sharded placements propagate and the tree is donated-free.
+    The encoder is whatever maps ``(input_ids, pad_mask)`` to hidden states;
+    counts it sows into a ``stats`` collection leave the jitted step as a
+    fourth output and are handed, unread, to ``on_stats`` after each launch.
 
     ``train_llm=False`` (MSIVD): the LLM forward runs on the constant
     ``llm_params`` input with no backward built through the stack.
@@ -249,10 +252,13 @@ def make_joint_steps(
             llm.cfg, "hidden_dropout_prob"
         ):
             kwargs = {"deterministic": False, "rngs": {"dropout": dropout_rng}}
-        return llm.apply(
+        # an encoder may sow per-step counts into a ``stats`` collection
+        # (a routed decoder's routing counts); most sow nothing: {}
+        hidden, sown = llm.apply(
             {"params": llm_params}, ids, jnp.asarray(batch.text.pad_mask),
-            **kwargs,
+            mutable=["stats"], **kwargs,
         )
+        return hidden, sown.get("stats", {})
 
     def loss_fn(params, llm_params, batch: JoinedBatch, rng):
         if train_llm:
@@ -260,7 +266,7 @@ def make_joint_steps(
         else:
             fusion_params = params
         rng, enc_rng = jax.random.split(rng)
-        hidden = hidden_states(
+        hidden, stats = hidden_states(
             llm_params, batch, dropout_rng=enc_rng if train_llm else None
         )
         logits = fusion.apply(
@@ -275,12 +281,11 @@ def make_joint_steps(
         mask = jnp.asarray(batch.mask)
         with jax.named_scope("loss"):
             loss, probs = fusion_loss(logits, labels, mask)
-        return loss, probs
+        return loss, (probs, stats)
 
-    @jax.jit
     def train_step(state: JointState, llm_params, batch: JoinedBatch):
         rng, sub = jax.random.split(state.rng)
-        (loss, probs), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, (probs, stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, llm_params, batch, sub
         )
         # flax scopes the modules; clip + AdamW + apply_updates would lower
@@ -288,7 +293,17 @@ def make_joint_steps(
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
-        return JointState(params, opt_state, rng, state.step + 1), loss, probs
+        return JointState(params, opt_state, rng, state.step + 1), loss, probs, stats
+
+    jitted_train_step = jax.jit(train_step)
+
+    def launch(state: JointState, llm_params, batch: JoinedBatch):
+        """The step as its callers take it, ``(state, loss, probs)``; what the
+        encoder sowed goes to ``on_stats`` as unread device arrays."""
+        state, loss, probs, stats = jitted_train_step(state, llm_params, batch)
+        if stats and on_stats is not None:
+            on_stats(stats)
+        return state, loss, probs
 
     @jax.jit
     def eval_step(params, llm_params, batch: JoinedBatch):
@@ -296,7 +311,7 @@ def make_joint_steps(
             fusion_params, llm_params = params["fusion"], params["llm"]
         else:
             fusion_params = params
-        hidden = hidden_states(llm_params, batch)
+        hidden, _ = hidden_states(llm_params, batch)
         logits = fusion.apply(
             {"params": fusion_params},
             hidden,
@@ -310,14 +325,14 @@ def make_joint_steps(
             loss, probs = fusion_loss(logits, labels, mask)
         return loss, probs
 
-    return train_step, eval_step
+    return launch, eval_step
 
 
 @dataclasses.dataclass
 class JointTrainer:
     """The ``train``/``evaluate``/``test`` driver (``train.py:211-585``)."""
 
-    llm: LlamaModel
+    llm: Any  # the encoder module: (input_ids, pad_mask) -> hidden states
     llm_params: Any
     fusion: FusionModel
     cfg: JointConfig
@@ -329,6 +344,9 @@ class JointTrainer:
 
     def __post_init__(self):
         self._steps: tuple[Callable, Callable] | None = None
+        # what the encoder sowed in the step just launched (a routed
+        # decoder's routing counts), as unread device arrays; most sow nothing
+        self._launched: list = []
         self.num_missing = 0
         self.history: list[dict] = []
         if self.telemetry is None:
@@ -367,11 +385,18 @@ class JointTrainer:
         step's ``loss.sync`` span. ``alone`` says no later step had been
         launched at the read (an evaluation point, the epoch's end): the
         device then idles through the next launch."""
-        step, loss, wait_s, dispatch_s = pending
+        step, loss, wait_s, dispatch_s, stats = pending
         with self.telemetry.tracer.span(
             "loss.sync", step=step, reads=1, alone=int(alone)
         ) as sync:
             value = float(loss)
+            if stats:
+                # outputs of the step whose loss was just read: they are
+                # there already, this is a copy and no second wait
+                flat, _ = jax.tree_util.tree_flatten_with_path(jax.device_get(stats))
+                sync.attrs.update({
+                    "_".join(str(getattr(k, "key", k)) for k in path): leaf.item()
+                    for path, leaf in flat})
         self.telemetry.observe_step(wait_s, dispatch_s, sync.dur_s)
         return value
 
@@ -404,7 +429,8 @@ class JointTrainer:
                 params = {"fusion": params, "llm": self.llm_params}
         self.tx = joint_optimizer(self.cfg, steps_per_epoch, params)
         self._steps = make_joint_steps(
-            self.llm, self.fusion, self.tx, train_llm=self.cfg.train_llm
+            self.llm, self.fusion, self.tx, train_llm=self.cfg.train_llm,
+            on_stats=self._launched.append,
         )
         if not fresh:
             return None
@@ -430,7 +456,8 @@ class JointTrainer:
             points = eval_points(n_batches, epoch, cfg)
             tr_loss = 0.0
             # the step in flight: (index, loss, its data.wait and
-            # step.dispatch seconds), launched and not yet read
+            # step.dispatch seconds, what its encoder sowed), launched and
+            # not yet read
             pending = None
             with tracer.span("train.epoch", root=True, epoch=epoch):
                 # overlap the host-side graph join + H2D transfer with the
@@ -465,7 +492,8 @@ class JointTrainer:
                             # beside the device instead of in front of it
                             if pending is not None:
                                 tr_loss += self._read_loss(pending, alone=False)
-                            pending = (step, loss, wait.dur_s, dispatch.dur_s)
+                            stats = self._launched.pop() if self._launched else None
+                            pending = (step, loss, wait.dur_s, dispatch.dur_s, stats)
                             if step in points:
                                 tr_loss += self._read_loss(pending, alone=True)
                                 pending = None

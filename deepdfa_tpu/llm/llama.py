@@ -56,6 +56,13 @@ LOGICAL_RULES = (
     ("mlp", "tp"),
     ("vocab", "tp"),
     ("norm", None),
+    # routed-expert decoders (llm/longcat.py): a chip holds a *range* of
+    # experts (``experts_held``); the logical name is there for the mesh
+    # axis that the expert exchange will bring, and maps to none until then
+    ("experts", None),
+    ("expert_mlp", None),
+    ("latent", None),
+    ("router", None),
 )
 
 

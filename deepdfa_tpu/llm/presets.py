@@ -1,15 +1,20 @@
 """Joint-training launch presets.
 
 The five MSIVD launch scripts (``MSIVD/msivd/scripts/*.sh``) as structured
-configs, plus the two LineVul configs of BASELINE config #3
-(``scripts/performance_evaluation.sh:7-9``: LineVul alone and
-DeepDFA+LineVul combined, ``encoder_family="roberta"``). ``finetuned`` marks presets that start from a LoRA-finetuned model
+configs (``encoder_family="llama"``), the two LineVul configs of BASELINE
+config #3 (``scripts/performance_evaluation.sh:7-9``: LineVul alone and
+DeepDFA+LineVul combined, ``encoder_family="roberta"``), and the MSIVD job
+with a latent-attention routed-expert decoder frozen in the LLM's place
+(``encoder_family="longcat"``: one chip's share of an expert-parallel
+deployment, and its test-size twin). ``finetuned`` marks presets that start
+from a LoRA-finetuned model
 (the reference's ``--finetuned_path`` / ``PeftInference`` load path,
 ``train.py:863-869`` — here: convert HF weights, apply LoRA adapters, see
 ``deepdfa_tpu/llm/{convert,lora}.py``). Mesh suggestions are TPU-side design
 (no reference equivalent — it used ``device_map="balanced"``): 7B fits one
 v4-8 slice with fsdp; 13B long-block presets shard seq over ``sp`` with ring
-attention.
+attention; the routed decoder holds one chip's range of experts
+(``experts_held``).
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import dataclasses
 from deepdfa_tpu.config import MeshConfig
 from deepdfa_tpu.llm.joint import JointConfig
 from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
+from deepdfa_tpu.llm.longcat import longcat_flash, tiny_longcat
 from deepdfa_tpu.llm.roberta import codebert_base
 
 __all__ = ["JointPreset", "PRESETS"]
@@ -27,13 +33,14 @@ __all__ = ["JointPreset", "PRESETS"]
 @dataclasses.dataclass(frozen=True)
 class JointPreset:
     name: str
-    llm: "LlamaConfig | object"  # RobertaConfig for encoder_family="roberta"
+    llm: "LlamaConfig | object"  # RobertaConfig / LongcatConfig by encoder_family
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
     dataset: str  # reference data family the preset targets
-    # which encoder stack drives the fusion head: "llama" (causal, MSIVD) or
-    # "roberta" (bidirectional CodeBERT — the LineVul configs)
+    # which encoder stack drives the fusion head: "llama" (causal, MSIVD),
+    # "roberta" (bidirectional CodeBERT — the LineVul configs) or "longcat"
+    # (causal, latent attention + routed experts, frozen)
     encoder_family: str = "llama"
 
 
@@ -131,6 +138,37 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="roberta",
+        ),
+        # MSIVD's joint classifier (pb_ft_pb's block 2048 x batch 4, lr 1e-6,
+        # GGNN + head trained) over a frozen latent-attention routed-expert
+        # decoder at its published widths: rank 0 of the 32 chips that share
+        # each layer by expert parallelism (16 of 512 routed experts, all 256
+        # zero-compute experts, attention and dense FFNs whole), four layers
+        # as one pipeline stage, an eighth of the vocabulary
+        JointPreset(
+            name="longcat_flash_msivd",
+            llm=longcat_flash(num_layers=4, vocab_size=16384, experts_held=(0, 16)),
+            joint=JointConfig(
+                block_size=2048, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="longcat",
+        ),
+        # the same code at test size (CPU): 2 of 8 routed experts held
+        JointPreset(
+            name="tiny_longcat_msivd",
+            llm=tiny_longcat(vocab_size=2048, experts_held=(0, 2)),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="longcat",
         ),
     ]
 }

@@ -31,7 +31,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["full_attention", "ring_attention", "ring_attention_sharded"]
+__all__ = [
+    "full_attention",
+    "blocked_causal_attention",
+    "ring_attention",
+    "ring_attention_sharded",
+]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/where() NaN-free
 
@@ -81,6 +86,33 @@ def full_attention(
         probs = jnp.where(row_valid[..., None], probs, 0.0)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
     return out.astype(q.dtype)
+
+
+def blocked_causal_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    *,
+    kv_mask: jnp.ndarray | None = None,
+    block_q: int = 256,
+) -> jnp.ndarray:
+    """Causal self-attention in blocks over the queries: block ``i`` attends
+    to keys ``[0, end of block i)`` only, so the scores are never whole
+    (``[b, h, block_q, keys]`` at a time) and the blocks above the diagonal
+    are never computed. The query/key width may differ from the value width
+    (latent attention: 192 against 128); the scale is the query width's.
+
+    q: [b, s, h, dk]; k: [b, s, h_kv, dk]; v: [b, s, h_kv, dv]; kv_mask:
+    [b, s] (True = attend). Returns [b, s, h, dv]."""
+    s = q.shape[1]
+    outs = []
+    for start in range(0, s, block_q):
+        end = min(start + block_q, s)
+        outs.append(full_attention(
+            q[:, start:end], k[:, :end], v[:, :end], causal=True,
+            kv_mask=None if kv_mask is None else kv_mask[:, :end],
+            q_positions=jnp.arange(start, end)))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
 def ring_attention(

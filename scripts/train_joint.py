@@ -67,9 +67,10 @@ def main(argv=None) -> dict:
     parser.add_argument("--output_dir", default=None)
     parser.add_argument("--sample", action="store_true")
     parser.add_argument(
-        "--encoder", choices=["llama", "roberta"], default=None,
+        "--encoder", choices=["llama", "roberta", "longcat"], default=None,
         help="encoder stack (default: preset's encoder_family, else llama); "
-        "roberta = the CodeBERT/LineVul bidirectional path (config #3)",
+        "roberta = the CodeBERT/LineVul bidirectional path (config #3); "
+        "longcat = a frozen latent-attention routed-expert decoder",
     )
     parser.add_argument(
         "--freeze-graph", default=None, metavar="CKPT_DIR",
@@ -163,6 +164,10 @@ def main(argv=None) -> dict:
             llm_cfg = tiny_roberta(
                 vocab_size=2048, max_position_embeddings=jcfg.block_size + 4
             )
+    if encoder_family == "longcat" and not args.preset:
+        from deepdfa_tpu.llm.longcat import tiny_longcat
+
+        llm_cfg = tiny_longcat(vocab_size=2048)  # hermetic default
     if args.freeze_graph:
         if not jcfg.use_gnn:
             raise SystemExit(
@@ -282,6 +287,23 @@ def main(argv=None) -> dict:
                     np.ones((2, jcfg.block_size), bool),
                 )["params"]
             )
+    elif encoder_family == "longcat":
+        import flax.linen as nn
+
+        from deepdfa_tpu.llm.longcat import LongcatModel
+
+        if args.hf_checkpoint:
+            raise SystemExit("the longcat family has no checkpoint conversion yet: "
+                             "it is built from a seed at the preset's widths")
+        tokenizer = HashTokenizer(vocab_size=llm_cfg.vocab_size)
+        llm = LongcatModel(llm_cfg)
+        # jitted: at published widths the weights (bfloat16) are made on the
+        # device leaf by leaf, never as float32 on the host
+        llm_params = nn.meta.unbox(jax.jit(llm.init)(
+            jax.random.key(0),
+            np.zeros((2, jcfg.block_size), np.int32),
+            np.ones((2, jcfg.block_size), bool),
+        )["params"])
     elif args.hf_checkpoint:
         from transformers import AutoTokenizer
 
