@@ -12,11 +12,22 @@ LineVul/CodeBERT architecture plus the reference's freeze-transfer hook,
 
 TPU design notes (vs a torch translation):
 
-- bidirectional attention is a single masked softmax over the full [s, s]
-  score matrix — no causal structure, no KV cache; XLA fuses the mask add
-  into the softmax. Sequences are short (LineVul block 512), so no ring/sp
-  path is needed; the encoder rides ``dp``/``fsdp``/``tp`` mesh axes via the
-  same logical-axis rules as the Llama stack (``llama.py LOGICAL_RULES``).
+- bidirectional attention, no causal structure, no KV cache. On a TPU it is
+  ``ops/flash_attention``: forward and backward kernels that keep a
+  ``[block_q, s]`` tile of scores in VMEM (softmax in float32, the tile
+  recomputed in the backward), so no ``[b, h, s, s]`` tensor is written to
+  HBM or kept for the backward; the pad mask goes in as per-token segment
+  ids. The kernels are taken when the process has one TPU device (nothing
+  is sharded), ``s`` is a multiple of 128, the heads tile 128 lanes and
+  attention dropout draws nothing (``deterministic`` or rate 0.0: a
+  fused kernel cannot draw flax's mask). Everywhere else — the CPU, short
+  sequences, the published dropout 0.1, meshes — the layer is a single masked
+  softmax over the full [s, s] score matrix in XLA ops, as it always was.
+  ``RobertaEncoder`` sows ``attn_layers`` / ``attn_fused`` into ``stats`` so
+  a step can say which it ran. Sequences are short (LineVul block 512), so
+  no ring/sp path is needed; the encoder rides ``dp``/``fsdp``/``tp`` mesh
+  axes via the same logical-axis rules as the Llama stack (``llama.py
+  LOGICAL_RULES``).
 - learned absolute positions (RoBERTa convention: real tokens get
   consecutive positions starting at ``pad_token_id + 1``) are computed from
   the explicit pad mask, so the framework-wide left-pad convention works
@@ -134,23 +145,63 @@ def _layer_norm(eps: float) -> nn.LayerNorm:
     )
 
 
+def _attention_kernel() -> bool | None:
+    """How this process runs ``ops/flash_attention``: ``False`` compiled for
+    its one TPU device, ``None`` not at all (more devices may shard batch or
+    heads, and a Pallas call is not GSPMD-partitionable). Tests patch this to
+    return ``True``, the Pallas interpreter."""
+    if jax.default_backend() == "tpu" and jax.device_count() == 1:
+        return False
+    return None
+
+
+def _fused_attention(cfg: RobertaConfig, seq_len: int, deterministic: bool) -> bool | None:
+    """The ``interpret`` flag for the attention kernels, or ``None`` where the
+    einsum-softmax path has to run: no kernel here, a shape it does not take,
+    or an attention dropout that draws a mask."""
+    interpret = _attention_kernel()
+    if interpret is None:
+        return None
+    # Pallas costs a second of imports: paid only where a kernel can run
+    from deepdfa_tpu.ops.flash_attention import supports
+
+    if not supports(seq_len, cfg.num_attention_heads, cfg.head_dim):
+        return None
+    if not deterministic and cfg.attention_probs_dropout_prob != 0.0:
+        return None
+    return interpret
+
+
 class _SelfAttention(nn.Module):
-    """``attention.self``: Q/K/V projections + bidirectional masked softmax."""
+    """``attention.self``: Q/K/V projections + bidirectional masked softmax;
+    ``fused`` (from :func:`_fused_attention`) is ``None`` for the XLA ops
+    below, else the ``interpret`` flag of the kernels that take their place."""
 
     cfg: RobertaConfig
 
     @nn.compact
     def __call__(
         self, x: jnp.ndarray, pad_mask: jnp.ndarray | None,
-        deterministic: bool = True,
+        deterministic: bool = True, fused: bool | None = None,
     ) -> jnp.ndarray:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         b, s, _ = x.shape
         h, d = cfg.num_attention_heads, cfg.head_dim
-        q = _dense(h * d, "embed", "heads", dtype, "query")(x).reshape(b, s, h, d)
-        k = _dense(h * d, "embed", "heads", dtype, "key")(x).reshape(b, s, h, d)
-        v = _dense(h * d, "embed", "heads", dtype, "value")(x).reshape(b, s, h, d)
+        proj = lambda name: _dense(h * d, "embed", "heads", dtype, name)(x)
+        if fused is not None:
+            from deepdfa_tpu.ops.flash_attention import flash_attention
+
+            # real queries see exactly the real keys, as below; pad queries
+            # see the pad keys, and nothing reads their rows either way
+            segments = (jnp.ones((b, s), jnp.int32) if pad_mask is None
+                        else pad_mask.astype(jnp.int32))
+            return flash_attention(
+                proj("query"), proj("key"), proj("value"), segments,
+                num_heads=h, interpret=fused)
+        q = proj("query").reshape(b, s, h, d)
+        k = proj("key").reshape(b, s, h, d)
+        v = proj("value").reshape(b, s, h, d)
         # [b, h, s_q, s_k] scores in f32; pads masked on the key axis only —
         # pad *query* rows produce garbage that downstream pooling never reads
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
@@ -173,9 +224,9 @@ class _AttentionBlock(nn.Module):
     @nn.compact
     def __call__(
         self, x: jnp.ndarray, pad_mask: jnp.ndarray | None,
-        deterministic: bool = True,
+        deterministic: bool = True, fused: bool | None = None,
     ) -> jnp.ndarray:
-        attn = _SelfAttention(self.cfg, name="self")(x, pad_mask, deterministic)
+        attn = _SelfAttention(self.cfg, name="self")(x, pad_mask, deterministic, fused)
         # HF nests output.dense + output.LayerNorm under attention.output —
         # the tree shape is attention/{self,output}/...
         return _AttnOutput(self.cfg, name="output")(attn, x, deterministic)
@@ -229,9 +280,9 @@ class RobertaLayer(nn.Module):
     @nn.compact
     def __call__(
         self, x: jnp.ndarray, pad_mask: jnp.ndarray | None,
-        deterministic: bool = True,
+        deterministic: bool = True, fused: bool | None = None,
     ) -> jnp.ndarray:
-        x = _AttentionBlock(self.cfg, name="attention")(x, pad_mask, deterministic)
+        x = _AttentionBlock(self.cfg, name="attention")(x, pad_mask, deterministic, fused)
         ff = _Intermediate(self.cfg, name="intermediate")(x)
         x = _FFNOutput(self.cfg, name="output")(ff, x, deterministic)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
@@ -296,8 +347,16 @@ class RobertaEncoder(nn.Module):
                 positions = roberta_position_ids(pad_mask, cfg.pad_token_id)
         x = _Embeddings(cfg, deterministic, name="embeddings")(input_ids, positions)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        fused = _fused_attention(cfg, input_ids.shape[1], deterministic)
         for i in range(cfg.num_hidden_layers):
-            x = RobertaLayer(cfg, name=f"layer_{i}")(x, pad_mask, deterministic)
+            x = RobertaLayer(cfg, name=f"layer_{i}")(x, pad_mask, deterministic, fused)
+        if not self.is_initializing():
+            # which attention the step ran, for whoever applies the encoder
+            # with ``mutable=["stats"]`` (the joint step: onto ``loss.sync``)
+            layers = jnp.int32(cfg.num_hidden_layers)
+            self.sow("stats", "attn",
+                     {"layers": layers, "fused": layers * (fused is not None)},
+                     reduce_fn=lambda _, new: new, init_fn=dict)
         return x
 
 

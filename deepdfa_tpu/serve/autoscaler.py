@@ -350,6 +350,11 @@ class Autoscaler:
         if handle is None:
             return []
         handle.kill()
+        # SIGKILL is not instant on a busy host: the same tick's _heal polls
+        # this handle and has to find it dead (past 5 s the next tick heals)
+        give_up = time.monotonic() + 5.0
+        while handle.poll() is None and time.monotonic() < give_up:
+            time.sleep(0.005)
         return [self._record("replica_crash_injected", backend=handle.name)]
 
     def _heal(self) -> list[dict]:
