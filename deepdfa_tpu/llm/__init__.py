@@ -9,7 +9,8 @@ weights, dp for batch, sp + ring attention for long sequences; a routed
 decoder is told the range of experts its chip holds). Three encoder families
 drive the fusion head:
 ``llama`` (causal, dense), ``roberta`` (bidirectional) and ``longcat``
-(causal, latent attention, routed experts).
+(causal, latent attention, routed experts): what a family is lives in
+``families.py``, and a fourth is one row there and one model file.
 """
 
 from deepdfa_tpu.llm.llama import (  # noqa: F401
@@ -35,6 +36,8 @@ __all__ = [
     # longcat  — latent attention + routed experts on a shortcut, zero-compute
     #            experts; holds a range of experts (frozen decoder of the
     #            joint classifier)
+    # families — what an encoder family is, and build_encoder over it: classes,
+    #            weights, tokenizer, pooling, trained or frozen
     # presets  — the launch configurations: five MSIVD scripts (llama), two
     #            LineVul (roberta), one routed decoder and its tiny twin (longcat)
 ]

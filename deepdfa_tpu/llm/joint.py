@@ -243,15 +243,11 @@ def make_joint_steps(
         # real-token distances (a uniform shift); the RoBERTa encoder builds
         # mask-aware absolute positions itself.
         #
-        # ``dropout_rng`` (train_llm steps only): enables the encoder's HF
-        # training regularisation — RobertaEncoder reads hidden/attention
-        # dropout rates off its config; the frozen Llama path never uses
-        # dropout, matching the reference's frozen-LLM forward.
-        kwargs = {}
-        if dropout_rng is not None and hasattr(llm, "cfg") and hasattr(
-            llm.cfg, "hidden_dropout_prob"
-        ):
-            kwargs = {"deterministic": False, "rngs": {"dropout": dropout_rng}}
+        # ``dropout_rng`` (train_llm steps only): a trained encoder runs
+        # with its HF training regularisation — RobertaEncoder reads
+        # hidden/attention dropout rates off its config; a frozen encoder is
+        # never handed a key, matching the reference's frozen-LLM forward.
+        kwargs = {} if dropout_rng is None else {"deterministic": False, "rngs": {"dropout": dropout_rng}}
         # an encoder may sow per-step counts into a ``stats`` collection
         # (a routed decoder's routing counts); most sow nothing: {}
         hidden, sown = llm.apply(

@@ -16,12 +16,12 @@ here; ``JointEngine.score`` is the whole contract:
 - static shapes: every chunk pads to ``max_batch`` text rows and a fixed
   ``(max_nodes, max_edges)`` graph budget, so the step compiles once.
 
-Two construction paths, mirroring ``scripts/train_joint.py``:
+Two construction paths, through ``llm/families.py`` like ``train_joint.py``:
 
 - :meth:`from_run_dir` **hermetic** (default): ``tiny_llama`` +
   :class:`HashTokenizer` — no downloaded weights, the tests/smoke path;
 - :meth:`from_run_dir` **sharded**: pass ``hf_checkpoint=`` (+ ``mesh=``) to
-  load CodeLlama through ``llm/llama.py``'s converter and tp/fsdp placement
+  load CodeLlama through ``llm/convert.py`` and tp/fsdp placement
   (``mesh_shardings``); the fusion tree is tiny and stays replicated.
 """
 
@@ -126,41 +126,23 @@ class JointEngine:
         """Restore the newest ``epoch_N`` fusion checkpoint from a
         ``train_joint.py`` run dir.
 
-        Default is the hermetic pairing ``train_joint.py`` trains with when
-        no preset/HF checkpoint is given (``tiny_llama(vocab_size=2048)`` +
-        :class:`HashTokenizer`); ``hf_checkpoint`` switches to the real
-        CodeLlama stack, placed over ``mesh`` when given.
+        The encoder is ``families.build_encoder``'s, as ``train_joint.py``
+        builds it (``llama`` is all tier 2 serves today): by default the
+        hermetic ``tiny_llama`` at ``vocab_size`` + :class:`HashTokenizer`;
+        ``hf_checkpoint`` switches to CodeLlama, placed over ``mesh`` if given.
         """
-        import jax
         import orbax.checkpoint as ocp
 
         from deepdfa_tpu.config import FeatureConfig, GGNNConfig
-        from deepdfa_tpu.llm.dataset import HashTokenizer
+        from deepdfa_tpu.llm.families import FAMILIES, build_encoder
         from deepdfa_tpu.llm.fusion import FusionModel
         from deepdfa_tpu.llm.joint import JointConfig
-        from deepdfa_tpu.llm.llama import LlamaModel, tiny_llama
 
         jcfg = jcfg or JointConfig()
-        if hf_checkpoint is not None:
-            from transformers import AutoTokenizer
-
-            from deepdfa_tpu.llm.convert import load_hf_checkpoint, load_hf_config
-            from deepdfa_tpu.llm.llama import mesh_shardings
-
-            llm_cfg = load_hf_config(hf_checkpoint)
-            tokenizer = AutoTokenizer.from_pretrained(hf_checkpoint)
-            llm = LlamaModel(llm_cfg, mesh=mesh)
-            llm_params = load_hf_checkpoint(hf_checkpoint)["model"]
-            if mesh is not None:
-                shardings = mesh_shardings(llm, llm_params, mesh)
-                llm_params = jax.device_put(llm_params, shardings)
-        else:
-            llm_cfg = tiny_llama(vocab_size=vocab_size)
-            tokenizer = HashTokenizer(vocab_size=llm_cfg.vocab_size)
-            llm = LlamaModel(llm_cfg)
-            llm_params = llm.init(
-                jax.random.key(0), np.zeros((2, jcfg.block_size), np.int32)
-            )["params"]
+        family = FAMILIES["llama"]
+        # a checkpoint brings its own config; else the hermetic one
+        llm_cfg = None if hf_checkpoint else family.hermetic(jcfg.block_size, vocab_size)
+        llm, llm_params, tokenizer, llm_cfg = build_encoder(family, llm_cfg, jcfg.block_size, hf_checkpoint, mesh)
 
         fusion = FusionModel(
             gnn_cfg=gnn_cfg or GGNNConfig(),
@@ -168,7 +150,7 @@ class JointEngine:
             llm_hidden_size=llm_cfg.hidden_size,
             use_gnn=use_gnn,
             dropout_rate=0.1,
-            pool="last",
+            pool=family.pool,
         )
 
         newest = newest_epoch_dir(run_dir)

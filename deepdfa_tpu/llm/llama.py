@@ -10,10 +10,10 @@ Replaces the reference's HF ``AutoModelForSequenceClassification`` /
   all-gather/reduce-scatter over ICI. Params carry *logical* axis names
   (``nn.with_logical_partitioning``); :func:`mesh_shardings` maps them onto a
   mesh via :data:`LOGICAL_RULES`.
-- **ring attention for long sequences**: ``attn_impl="ring"`` shards the
-  sequence over ``sp`` (see ``deepdfa_tpu/ops/ring_attention.py``); the
+- **ring attention for long sequences**: of ``attn_impl``'s two modes,
+  ``"ring"`` shards the sequence over ``sp`` (``ops/ring_attention.py``); the
   reference truncates at ``block_size <= 2048`` (``train.py:199-207``), which
-  remains the parity mode (``attn_impl="full"``).
+  remains the parity mode (``"full"``: XLA's attention, no stock kernel).
 - **no data-dependent control flow**: static shapes, causal mask built from
   ``arange`` comparisons, generation via a fixed-size KV cache — everything
   jits once.
@@ -226,40 +226,6 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
-def _flash_attention(q, k, v, attn_mask):
-    """Pallas flash-attention path (``attn_impl="flash"``): blockwise
-    softmax in VMEM via the stock TPU kernel — the single-chip hot-op
-    companion to the ``sp``-sharded ring path (TPU only; the CPU test mesh
-    uses "full"/"ring"). Layout in: [b, s, h, d]; kernel wants [b, h, s, d].
-    Padding rides segment ids: pads get segment 0, real tokens 1, and the
-    kernel masks cross-segment attention — same effect as ``kv_mask``."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        SegmentIds,
-        flash_attention,
-    )
-
-    s = q.shape[1]
-    if s % 128 != 0:  # kernel block constraint; short/ragged seqs take XLA
-        return full_attention(q, k, v, causal=True, kv_mask=attn_mask)
-    from deepdfa_tpu.ops.ring_attention import _repeat_kv
-
-    h = q.shape[2]
-    k = _repeat_kv(k, h // k.shape[2])
-    v = _repeat_kv(v, h // v.shape[2])
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    segment_ids = None
-    if attn_mask is not None:
-        seg = attn_mask.astype(jnp.int32)
-        segment_ids = SegmentIds(q=seg, kv=seg)
-    out = flash_attention(
-        qt, kt, vt,
-        segment_ids=segment_ids,
-        causal=True,
-        sm_scale=q.shape[-1] ** -0.5,
-    )
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
-
-
 class Attention(nn.Module):
     cfg: LlamaConfig
     mesh: Mesh | None = None
@@ -310,8 +276,6 @@ class Attention(nn.Module):
             out = ring_attention_sharded(
                 q, k, v, self.mesh, causal=True, kv_mask=attn_mask
             )
-        elif cfg.attn_impl == "flash":
-            out = _flash_attention(q, k, v, attn_mask)
         else:
             out = full_attention(q, k, v, causal=True, kv_mask=attn_mask)
         return o_proj(out.reshape(b, s, h * d))

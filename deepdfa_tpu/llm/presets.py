@@ -6,8 +6,10 @@ config #3 (``scripts/performance_evaluation.sh:7-9``: LineVul alone and
 DeepDFA+LineVul combined, ``encoder_family="roberta"``), and the MSIVD job
 with a latent-attention routed-expert decoder frozen in the LLM's place
 (``encoder_family="longcat"``: one chip's share of an expert-parallel
-deployment, and its test-size twin). ``finetuned`` marks presets that start
-from a LoRA-finetuned model
+deployment, and its test-size twin). What a family is lives in
+``llm/families.py`` (a fourth: one row there, one model file); a preset's
+``llm`` must be its family's config class, checked at construction.
+``finetuned`` marks presets that start from a LoRA-finetuned model
 (the reference's ``--finetuned_path`` / ``PeftInference`` load path,
 ``train.py:863-869`` — here: convert HF weights, apply LoRA adapters, see
 ``deepdfa_tpu/llm/{convert,lora}.py``). Mesh suggestions are TPU-side design
@@ -22,10 +24,11 @@ from __future__ import annotations
 import dataclasses
 
 from deepdfa_tpu.config import MeshConfig
+from deepdfa_tpu.llm.families import FAMILIES
 from deepdfa_tpu.llm.joint import JointConfig
 from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
-from deepdfa_tpu.llm.longcat import longcat_flash, tiny_longcat
-from deepdfa_tpu.llm.roberta import codebert_base
+from deepdfa_tpu.llm.longcat import LongcatConfig, longcat_flash, tiny_longcat
+from deepdfa_tpu.llm.roberta import RobertaConfig, codebert_base
 
 __all__ = ["JointPreset", "PRESETS"]
 
@@ -33,7 +36,7 @@ __all__ = ["JointPreset", "PRESETS"]
 @dataclasses.dataclass(frozen=True)
 class JointPreset:
     name: str
-    llm: "LlamaConfig | object"  # RobertaConfig / LongcatConfig by encoder_family
+    llm: LlamaConfig | RobertaConfig | LongcatConfig  # encoder_family's class
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
@@ -42,6 +45,12 @@ class JointPreset:
     # "roberta" (bidirectional CodeBERT — the LineVul configs) or "longcat"
     # (causal, latent attention + routed experts, frozen)
     encoder_family: str = "llama"
+
+    def __post_init__(self):
+        config_cls, _ = FAMILIES[self.encoder_family].classes()
+        if not isinstance(self.llm, config_cls):  # else LlamaModel(RobertaConfig) or vice versa
+            raise TypeError(f"preset {self.name!r}: encoder_family={self.encoder_family!r} builds "
+                            f"from a {config_cls.__name__}, not a {type(self.llm).__name__}")
 
 
 PRESETS: dict[str, JointPreset] = {

@@ -51,6 +51,8 @@ def _restore_newest_epoch(trainer, examples, jcfg, search_dir, what: str):
 
 
 def main(argv=None) -> dict:
+    from deepdfa_tpu.llm.families import FAMILIES, build_encoder
+
     parser = argparse.ArgumentParser()
     parser.add_argument("--dataset", default="demo")
     parser.add_argument("--preset", default=None, help="one of llm.presets.PRESETS")
@@ -67,7 +69,7 @@ def main(argv=None) -> dict:
     parser.add_argument("--output_dir", default=None)
     parser.add_argument("--sample", action="store_true")
     parser.add_argument(
-        "--encoder", choices=["llama", "roberta", "longcat"], default=None,
+        "--encoder", choices=list(FAMILIES), default=None,
         help="encoder stack (default: preset's encoder_family, else llama); "
         "roberta = the CodeBERT/LineVul bidirectional path (config #3); "
         "longcat = a frozen latent-attention routed-expert decoder",
@@ -104,34 +106,24 @@ def main(argv=None) -> dict:
     from deepdfa_tpu import utils
     from deepdfa_tpu.config import GGNNConfig
     from deepdfa_tpu.data.graphs import load_shards
-    from deepdfa_tpu.llm.dataset import (
-        GraphJoin,
-        HashTokenizer,
-        encode_functions,
-        text_batches,
-    )
+    from deepdfa_tpu.llm.dataset import GraphJoin, encode_functions, text_batches
     from deepdfa_tpu.llm.fusion import FusionModel
     from deepdfa_tpu.llm.joint import JointConfig, JointTrainer
-    from deepdfa_tpu.llm.llama import LlamaModel, tiny_llama
 
     # --- joint config: preset base, CLI overrides on top
-    encoder_family = args.encoder
+    preset, encoder_family = None, args.encoder
     if args.preset:
         from deepdfa_tpu.llm.presets import PRESETS
 
         preset = PRESETS[args.preset]
-        jcfg, llm_cfg = preset.joint, preset.llm
         if encoder_family and encoder_family != preset.encoder_family:
-            # the preset's llm config is class-bound to its stack — crossing
-            # them builds LlamaModel(RobertaConfig) or vice versa
+            # the preset's llm config is class-bound to its family
             raise SystemExit(
                 f"--encoder {encoder_family} contradicts preset "
                 f"{args.preset!r} (encoder_family={preset.encoder_family})"
             )
         encoder_family = preset.encoder_family
-    else:
-        jcfg, llm_cfg = JointConfig(), tiny_llama(vocab_size=2048)
-    encoder_family = encoder_family or "llama"
+    family = FAMILIES[encoder_family or "llama"]
     updates = {
         k: v
         for k, v in {
@@ -147,27 +139,13 @@ def main(argv=None) -> dict:
     }
     if args.no_flowgnn:
         updates["use_gnn"] = False
-    jcfg = dataclasses.replace(jcfg, **updates)
-    if encoder_family == "roberta":
-        # LineVul fine-tunes CodeBERT end-to-end in EVERY configuration —
-        # train_llm applies regardless of where the weights came from (the
-        # r04 advisor caught --hf-checkpoint without --preset silently
-        # running the encoder frozen, unlike the hermetic default and the
-        # linevul presets, which also set it)
-        jcfg = dataclasses.replace(jcfg, train_llm=True)
-        if not args.preset and not args.hf_checkpoint:
-            from deepdfa_tpu.llm.roberta import tiny_roberta
-
-            # hermetic default: tiny CodeBERT-architecture encoder, LineVul
-            # mode; built AFTER overrides so the position table covers
-            # --block_size (+2: RoBERTa positions start at pad_token_id + 1)
-            llm_cfg = tiny_roberta(
-                vocab_size=2048, max_position_embeddings=jcfg.block_size + 4
-            )
-    if encoder_family == "longcat" and not args.preset:
-        from deepdfa_tpu.llm.longcat import tiny_longcat
-
-        llm_cfg = tiny_longcat(vocab_size=2048)  # hermetic default
+    # a trained family (LineVul fine-tunes CodeBERT end-to-end) is trained
+    # in EVERY configuration, wherever the weights came from (the r04 advisor
+    # caught --hf-checkpoint without --preset silently running it frozen)
+    jcfg = dataclasses.replace(preset.joint if preset else JointConfig(), **updates, train_llm=family.trained)
+    # no preset: the family's hermetic config, built AFTER the overrides (a
+    # checkpoint replaces its architecture and keeps its TPU-side knobs)
+    llm_cfg = preset.llm if preset else family.hermetic(jcfg.block_size)
     if args.freeze_graph:
         if not jcfg.use_gnn:
             raise SystemExit(
@@ -257,78 +235,7 @@ def main(argv=None) -> dict:
         funcs, labels, ids = df.before.tolist(), df.vul.tolist(), df.id.tolist()
 
     # --- model + tokenizer
-    if encoder_family == "roberta":
-        from deepdfa_tpu.llm.roberta import RobertaEncoder
-
-        if args.hf_checkpoint:
-            from transformers import AutoTokenizer
-
-            from deepdfa_tpu.llm.convert import load_torch_state
-            from deepdfa_tpu.llm.roberta import RobertaConfig, convert_hf_roberta
-
-            with open(Path(args.hf_checkpoint) / "config.json") as f:
-                llm_cfg = RobertaConfig.from_hf_dict(json.load(f))
-            tokenizer = AutoTokenizer.from_pretrained(args.hf_checkpoint)
-            llm = RobertaEncoder(llm_cfg)
-            llm_params = convert_hf_roberta(load_torch_state(args.hf_checkpoint))
-        else:
-            import flax.linen as nn
-
-            tokenizer = HashTokenizer(vocab_size=llm_cfg.vocab_size)
-            llm = RobertaEncoder(llm_cfg)
-            # unbox: in train_llm mode these params join the trained tree,
-            # where boxed leaves would defeat the no-decay mask (its path
-            # check would see the box's 'value' leaf) and diverge from the
-            # unboxed HF-checkpoint tree shape
-            llm_params = nn.meta.unbox(
-                llm.init(
-                    jax.random.key(0),
-                    np.zeros((2, jcfg.block_size), np.int32),
-                    np.ones((2, jcfg.block_size), bool),
-                )["params"]
-            )
-    elif encoder_family == "longcat":
-        import flax.linen as nn
-
-        from deepdfa_tpu.llm.longcat import LongcatModel
-
-        if args.hf_checkpoint:
-            raise SystemExit("the longcat family has no checkpoint conversion yet: "
-                             "it is built from a seed at the preset's widths")
-        tokenizer = HashTokenizer(vocab_size=llm_cfg.vocab_size)
-        llm = LongcatModel(llm_cfg)
-        # jitted: at published widths the weights (bfloat16) are made on the
-        # device leaf by leaf, never as float32 on the host
-        llm_params = nn.meta.unbox(jax.jit(llm.init)(
-            jax.random.key(0),
-            np.zeros((2, jcfg.block_size), np.int32),
-            np.ones((2, jcfg.block_size), bool),
-        )["params"])
-    elif args.hf_checkpoint:
-        from transformers import AutoTokenizer
-
-        from deepdfa_tpu.llm.convert import load_hf_checkpoint, load_hf_config
-
-        # architecture shapes come from the HF config.json; TPU-side knobs
-        # (lora_rank, attn_impl, dtype) stay with the preset/defaults —
-        # from_hf_dict would silently zero them otherwise
-        hf_cfg = load_hf_config(args.hf_checkpoint)
-        llm_cfg = dataclasses.replace(
-            hf_cfg,
-            lora_rank=llm_cfg.lora_rank,
-            lora_alpha=llm_cfg.lora_alpha,
-            attn_impl=llm_cfg.attn_impl,
-            dtype=llm_cfg.dtype,
-        )
-        tokenizer = AutoTokenizer.from_pretrained(args.hf_checkpoint)
-        llm = LlamaModel(llm_cfg)
-        llm_params = load_hf_checkpoint(args.hf_checkpoint)["model"]
-    else:
-        tokenizer = HashTokenizer(vocab_size=llm_cfg.vocab_size)
-        llm = LlamaModel(llm_cfg)
-        llm_params = llm.init(
-            jax.random.key(0), np.zeros((2, jcfg.block_size), np.int32)
-        )["params"]
+    llm, llm_params, tokenizer, llm_cfg = build_encoder(family, llm_cfg, jcfg.block_size, args.hf_checkpoint)
 
     examples = encode_functions(funcs, labels, tokenizer, jcfg.block_size, indices=ids)
     if scan_meta is not None:
@@ -392,9 +299,7 @@ def main(argv=None) -> dict:
         llm_hidden_size=llm_cfg.hidden_size,
         use_gnn=jcfg.use_gnn,
         dropout_rate=0.1,
-        # bidirectional encoders summarise into the CLS (first real) token;
-        # causal decoders into the last
-        pool="cls" if encoder_family == "roberta" else "last",
+        pool=family.pool,
     )
     run_dir = Path(args.output_dir) if args.output_dir else utils.get_dir(
         utils.storage_dir() / "joint_runs" / utils.get_run_id()
