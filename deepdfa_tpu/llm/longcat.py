@@ -36,12 +36,15 @@ chip the layer runs without its exchange and nothing stands in for it.
 
 Everything but the router runs at ``dtype`` (bfloat16 weights and
 activations, float32 accumulation, norms and softmax in float32); the router's
-product and softmax are float32 as published. Attention is computed in blocks
-over the queries (``ops/ring_attention.blocked_causal_attention``), the held
-experts as grouped products with no capacity limit (``ops/grouped.py``).
+product and softmax are float32 as published. Attention is one kernel where it
+can run (``ops/latent_attention.py``: one TPU device, published widths, whole
+128-row tiles) and computed in blocks over the queries elsewhere
+(``ops/ring_attention.blocked_causal_attention``); the held experts are
+grouped products with no capacity limit (``ops/grouped.py``).
 
 Two flax collections leave the forward when asked for (``mutable=``):
-``stats`` — the step's routing counts summed over layers, which the joint
+``stats`` — the step's routing counts summed over layers (``moe``) and how
+many of its attention blocks ran the kernel (``attn``), which the joint
 trainer reads where it reads the loss — and ``routing`` — every layer's
 choices, for a comparison with a reference.
 """
@@ -56,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.llama import RMSNorm, rope_cos_sin
 from deepdfa_tpu.ops.grouped import held_expert_ffn
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention
@@ -175,6 +179,23 @@ def route(x: jnp.ndarray, w_r: jnp.ndarray, bias: jnp.ndarray, cfg: LongcatConfi
     return choice.astype(jnp.int32), gates
 
 
+def _fused_attention(cfg: LongcatConfig, seq_len: int) -> bool | None:
+    """The ``interpret`` flag for the latent-attention kernel, or ``None``
+    where ``blocked_causal_attention`` has to run: no kernel here (the rule is
+    ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
+    take."""
+    interpret = roberta._attention_kernel()
+    if interpret is None:
+        return None
+    # Pallas costs a second of imports: paid only where a kernel can run
+    from deepdfa_tpu.ops.latent_attention import supports
+
+    if not supports(seq_len, cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                    cfg.qk_rope_head_dim, cfg.v_head_dim):
+        return None
+    return interpret
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention: queries and keys/values through low-rank
     latents, a rope part of the key shared by all heads."""
@@ -201,19 +222,28 @@ class LatentAttention(nn.Module):
         c_kv = (norm("kv_a_norm")(c_kv)
                 * scale(cfg.mla_scale_kv_lora, cfg.kv_lora_rank)).astype(dtype)
         kv = _proj(h * (dn + dv), ("latent", "heads"), cfg, "kv_b_proj")(c_kv)
-        kv = kv.reshape(b, s, h, dn + dv)
 
         cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta)  # [b, s, dr/2]
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-        q = jnp.concatenate(
-            [q[..., :dn], rope_interleaved(q[..., dn:], cos, sin)], axis=-1)
+        q_n, q_r = q[..., :dn], rope_interleaved(q[..., dn:], cos, sin)
         k_r = rope_interleaved(k_r[:, :, None, :], cos, sin)
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, h, dr))], axis=-1)
-        out = blocked_causal_attention(
-            q, k, kv[..., dn:], kv_mask=attn_mask, block_q=cfg.attn_block_q)
-        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(
-            out.reshape(b, s, h * dv))
+        fused = _fused_attention(cfg, s)
+        with jax.named_scope("scores"):
+            if fused is not None:
+                from deepdfa_tpu.ops.latent_attention import latent_attention
+
+                # heads side by side, as the projections give and take them
+                out = latent_attention(
+                    q_n.reshape(b, s, h * dn), q_r.reshape(b, s, h * dr), k_r[:, :, 0], kv,
+                    attn_mask, num_heads=h, interpret=fused)
+            else:
+                kv = kv.reshape(b, s, h, dn + dv)
+                k = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, h, dr))], axis=-1)
+                out = blocked_causal_attention(
+                    jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., dn:],
+                    kv_mask=attn_mask, block_q=cfg.attn_block_q).reshape(b, s, h * dv)
+        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out)
 
 
 class DenseFFN(nn.Module):
@@ -340,4 +370,9 @@ class LongcatModel(nn.Module):
             totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
         # per step, summed over layers; replaced, not appended, on each apply
         self.sow("stats", "moe", totals, reduce_fn=lambda _, new: new, init_fn=dict)
+        # which attention the step ran (``RobertaEncoder``'s names): two blocks a layer
+        blocks = jnp.int32(2 * cfg.num_layers)
+        fused = _fused_attention(cfg, input_ids.shape[1]) is not None
+        self.sow("stats", "attn", {"layers": blocks, "fused": blocks * fused},
+                 reduce_fn=lambda _, new: new, init_fn=dict)
         return RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="norm")(x)

@@ -109,8 +109,12 @@ def test_joint_engine_restores_into_the_pairing_build_encoder_makes(tmp_path):
         gnn_cfg=None, input_dim=52, llm_hidden_size=cfg.hidden_size,
         use_gnn=False, dropout_rate=0.1, pool=fam.pool)
     saved = JointEngine._template_params(llm, params, fusion, jcfg, 64, 128)
-    ocp.StandardCheckpointer().save((tmp_path / "epoch_0").absolute(), saved)
-    ocp.StandardCheckpointer().wait_until_finished()
+    # one checkpointer: the save is asynchronous, and a second instance's
+    # ``wait_until_finished`` waits for nothing (a loaded machine then restores
+    # before ``epoch_0`` is renamed into place)
+    with ocp.StandardCheckpointer() as checkpointer:
+        checkpointer.save((tmp_path / "epoch_0").absolute(), saved)
+        checkpointer.wait_until_finished()
 
     eng = JointEngine.from_run_dir(tmp_path, jcfg=jcfg, use_gnn=False)
     assert type(eng.llm) is type(llm) and eng.llm.cfg == cfg

@@ -2,6 +2,8 @@
 standing in for the chip) against the einsum-softmax path it replaces on a
 TPU, when each of the two is taken, and the counter that says which ran."""
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -315,3 +317,20 @@ def test_the_kernels_compile_for_the_v5e_at_codeberts_size(one_v5e):
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_the_latent_attention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
+    """Here because this file's fixture is the one place that loads the TPU
+    compiler: ``ops/latent_attention`` at [4, 2048, 64 x (192 | 128)]
+    bfloat16 (tiling, VMEM, the loop with bounds from SMEM), and nothing near
+    a query block's 268 MB of float32 scores among the temporaries."""
+    from deepdfa_tpu.ops.latent_attention import latent_attention
+
+    b, s, h = 4, 2048, 64
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    compiled = jax.jit(functools.partial(latent_attention, num_heads=h)).trace(
+        shape(b, s, h * 128), shape(b, s, h * 64), shape(b, s, 64), shape(b, s, h * 256),
+        shape(b, s, dtype=jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert "latent_attention_fwd" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
