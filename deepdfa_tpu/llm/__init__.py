@@ -6,11 +6,11 @@ quantization (``train.py:873-885``), HF accelerate ``device_map`` layer
 placement (``train.py:883``), ``torch.nn.DataParallel`` (``train.py:936``) —
 this package uses bf16 weights GSPMD-sharded over a named mesh (tp/fsdp for
 weights, dp for batch, sp + ring attention for long sequences; a routed
-decoder is told the range of experts its chip holds). Three encoder families
+decoder is told the range of experts its chip holds). Four encoder families
 drive the fusion head:
-``llama`` (causal, dense), ``roberta`` (bidirectional) and ``longcat``
-(causal, latent attention, routed experts): what a family is lives in
-``families.py``, and a fourth is one row there and one model file.
+``llama`` (causal, dense), ``roberta`` (bidirectional), ``longcat`` and
+``pangu_moe`` (causal, latent attention, routed experts): what a family is
+lives in ``families.py``, and a further one is one row there and one model file.
 """
 
 from deepdfa_tpu.llm.llama import (  # noqa: F401
@@ -35,9 +35,12 @@ __all__ = [
     # roberta  — bidirectional encoder (CodeBERT, the LineVul configurations)
     # longcat  — latent attention + routed experts on a shortcut, zero-compute
     #            experts; holds a range of experts (frozen decoder of the
-    #            joint classifier)
+    #            joint classifier); and what both sparse decoders share
+    # pangu_moe — sandwich norms, latent attention, leading dense layers then a
+    #            shared expert beside sigmoid-routed experts (frozen decoder too)
     # families — what an encoder family is, and build_encoder over it: classes,
     #            weights, tokenizer, pooling, trained or frozen
     # presets  — the launch configurations: five MSIVD scripts (llama), two
-    #            LineVul (roberta), one routed decoder and its tiny twin (longcat)
+    #            LineVul (roberta), a routed decoder and its tiny twin each for
+    #            longcat and pangu_moe
 ]

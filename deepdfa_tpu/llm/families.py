@@ -4,7 +4,7 @@ A family fixes what differs between the stacks that can drive the fusion head: c
 classes, weights from a seed and from a local HF checkpoint, the test-size (hermetic) config, whether
 the encoder is trained, where the head pools. ``scripts/train_joint.py``, ``JointEngine.from_run_dir``
 and ``presets.py`` read it here; ``make_joint_steps`` needs none of it (``train_llm`` is ``trained``).
-A fourth family is one row in :data:`FAMILIES` and one model file, imported on use, never with this module.
+A further family is one row in :data:`FAMILIES` and one model file, imported on use, never with this module.
 """
 
 from __future__ import annotations
@@ -51,8 +51,8 @@ def _roberta_from_checkpoint(ckpt_dir, llm_cfg):
     return RobertaConfig.from_hf_dict(hf_cfg), convert_hf_roberta(load_torch_state(ckpt_dir))
 
 
-def _longcat_from_seed(llm, key, ids, pad_mask):
-    # jitted: at published widths the weights (bfloat16) are made on the device, never as float32 on the host
+def _sparse_from_seed(llm, key, ids, pad_mask):
+    # the routed decoders'. jitted: at published widths the weights (bfloat16) are made on the device, never as float32 on the host
     return nn.meta.unbox(jax.jit(llm.init)(key, ids, pad_mask)["params"])
 
 
@@ -93,7 +93,10 @@ FAMILIES: dict[str, EncoderFamily] = {
                       tiny_kw=lambda block_size: {"max_position_embeddings": block_size + 4}),
         # causal, latent attention + routed experts, frozen; no converter yet
         EncoderFamily("longcat", "LongcatConfig", "LongcatModel", "tiny_longcat", pool="last", trained=False,
-                      from_seed=_longcat_from_seed),
+                      from_seed=_sparse_from_seed),
+        # causal, sandwich norms, latent attention, dense then shared + sigmoid-routed experts, frozen; no converter
+        EncoderFamily("pangu_moe", "PanguMoeConfig", "PanguMoeModel", "tiny_pangu_moe", pool="last", trained=False,
+                      from_seed=_sparse_from_seed),
     ]
 }
 

@@ -56,7 +56,7 @@ LOGICAL_RULES = (
     ("mlp", "tp"),
     ("vocab", "tp"),
     ("norm", None),
-    # routed-expert decoders (llm/longcat.py): a chip holds a *range* of
+    # routed-expert decoders (llm/longcat.py, llm/pangu_moe.py): a chip holds a *range* of
     # experts (``experts_held``); the logical name is there for the mesh
     # axis that the expert exchange will bring, and maps to none until then
     ("experts", None),

@@ -1,7 +1,8 @@
 """A latent-attention, routed-expert decoder (the LongCat-Flash layer) in Flax.
 
-The third encoder stack beside ``llama.py`` (causal, dense) and
-``roberta.py`` (bidirectional): a causal decoder whose layer holds **two**
+One of the four encoder stacks (``llama.py``: causal, dense; ``roberta.py``:
+bidirectional; this file and ``pangu_moe.py``: causal, latent attention and
+routed experts): a causal decoder whose layer holds **two**
 latent-attention blocks and two dense gated FFNs, with one routed-expert
 layer on a *shortcut* — it reads the first block's output and joins after the
 second dense FFN — and whose router may send a token to *zero-compute*
@@ -47,12 +48,23 @@ Two flax collections leave the forward when asked for (``mutable=``):
 many of its attention blocks ran the kernel (``attn``), which the joint
 trainer reads where it reads the loss — and ``routing`` — every layer's
 choices, for a comparison with a reference.
+
+**What ``pangu_moe.py`` shares** lives here once: :class:`LatentAttention`
+(with its kernel choice and its ``scores`` scope), :func:`rope_interleaved`,
+:class:`DenseFFN`, and what an expert layer does once its router has chosen —
+:func:`mask_pads`, :func:`held_experts` (the held experts' weights and
+``held_expert_ffn``) and :func:`sow_and_count` (the ``routing`` choices and
+the ``stats`` counts); :func:`embed_tokens` and :func:`sow_attention` at a
+model's two ends; :class:`HeldRange` in the configs. They read a config by the
+published names the two families have in common; each model keeps its own
+``route`` and layer class.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -71,11 +83,45 @@ __all__ = [
     "tiny_longcat",
     "rope_interleaved",
     "route",
+    "LatentAttention",
+    "DenseFFN",
+    "mask_pads",
+    "held_experts",
+    "sow_and_count",
+    "HeldRange",
+    "embed_tokens",
+    "sow_attention",
 ]
 
 
+class HeldRange:
+    """What both sparse decoders' configs do alike (a mixin of their frozen
+    dataclasses): ``experts_held`` checked and as a range, and a config read
+    from a published ``config.json``'s keys."""
+
+    def _check_held(self):
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.n_routed_experts:
+                raise ValueError(f"experts_held {self.experts_held} is no range of the "
+                                 f"{self.n_routed_experts} routed experts")
+            object.__setattr__(self, "experts_held", (int(lo), int(hi)))
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.n_routed_experts)
+
+    @classmethod
+    def from_hf_dict(cls, d: dict):
+        names = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in d.items() if k in names}
+        if kw.get("experts_held") is not None:
+            kw["experts_held"] = tuple(kw["experts_held"])
+        return cls(**kw)
+
+
 @dataclasses.dataclass(frozen=True)
-class LongcatConfig:
+class LongcatConfig(HeldRange):
     """Published ``config.json`` keys (defaults: LongCat-Flash-Omni's language
     model) plus the TPU-side knobs at the end."""
 
@@ -106,28 +152,11 @@ class LongcatConfig:
     moe_chunk_rows: int = 4096  # assignments per grouped product (ops/grouped.py)
 
     def __post_init__(self):
-        if self.experts_held is not None:
-            lo, hi = self.experts_held
-            if not 0 <= lo < hi <= self.n_routed_experts:
-                raise ValueError(f"experts_held {self.experts_held} is no range of the "
-                                 f"{self.n_routed_experts} routed experts")
-            object.__setattr__(self, "experts_held", (int(lo), int(hi)))
-
-    @property
-    def held(self) -> tuple[int, int]:
-        return self.experts_held or (0, self.n_routed_experts)
+        self._check_held()
 
     @property
     def router_width(self) -> int:
         return self.n_routed_experts + self.zero_expert_num
-
-    @classmethod
-    def from_hf_dict(cls, d: dict) -> "LongcatConfig":
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in d.items() if k in names}
-        if kw.get("experts_held") is not None:
-            kw["experts_held"] = tuple(kw["experts_held"])
-        return cls(**kw)
 
 
 def longcat_flash(**kw) -> LongcatConfig:
@@ -149,7 +178,7 @@ def tiny_longcat(**kw) -> LongcatConfig:
     return LongcatConfig(**defaults)
 
 
-def _proj(features: int, axes: tuple, cfg: LongcatConfig, name: str) -> nn.Dense:
+def _proj(features: int, axes: tuple, cfg, name: str) -> nn.Dense:
     dtype = jnp.dtype(cfg.dtype)
     return nn.Dense(
         features, use_bias=False, dtype=dtype, param_dtype=dtype,
@@ -179,7 +208,7 @@ def route(x: jnp.ndarray, w_r: jnp.ndarray, bias: jnp.ndarray, cfg: LongcatConfi
     return choice.astype(jnp.int32), gates
 
 
-def _fused_attention(cfg: LongcatConfig, seq_len: int) -> bool | None:
+def _fused_attention(cfg, seq_len: int) -> bool | None:
     """The ``interpret`` flag for the latent-attention kernel, or ``None``
     where ``blocked_causal_attention`` has to run: no kernel here (the rule is
     ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
@@ -198,9 +227,12 @@ def _fused_attention(cfg: LongcatConfig, seq_len: int) -> bool | None:
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention: queries and keys/values through low-rank
-    latents, a rope part of the key shared by all heads."""
+    latents, a rope part of the key shared by all heads. ``cfg`` is either
+    decoder's: the sizes by their published names, ``mla_scale_q_lora`` /
+    ``mla_scale_kv_lora`` (whether a normed latent is scaled), ``dtype``,
+    ``attn_block_q``."""
 
-    cfg: LongcatConfig
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, attn_mask, positions):
@@ -247,14 +279,72 @@ class LatentAttention(nn.Module):
 
 
 class DenseFFN(nn.Module):
-    cfg: LongcatConfig
+    """``W_down(silu(W_gate x) * (W_up x))``, ``width`` wide: a dense FFN, or
+    an expert every token passes through."""
+
+    cfg: Any
+    width: int
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = _proj(cfg.ffn_hidden_size, ("embed", "mlp"), cfg, "gate_proj")(x)
-        up = _proj(cfg.ffn_hidden_size, ("embed", "mlp"), cfg, "up_proj")(x)
+        gate = _proj(self.width, ("embed", "mlp"), cfg, "gate_proj")(x)
+        up = _proj(self.width, ("embed", "mlp"), cfg, "up_proj")(x)
         return _proj(cfg.hidden_size, ("mlp", "embed"), cfg, "down_proj")(nn.silu(gate) * up)
+
+
+def mask_pads(choice, gates, token_mask):
+    """A pad token is routed nowhere: its choices -1, its gates 0."""
+    if token_mask is None:
+        return choice, gates
+    real = token_mask.reshape(-1, 1)
+    return jnp.where(real, choice, -1), jnp.where(real, gates, 0.0)
+
+
+def held_experts(layer: nn.Module, x, choice, gates, width: int):
+    """``(out [t, d] float32, computed)``: the part of an expert layer's
+    result that the experts held here (``layer.cfg.held``) give for the
+    tokens ``x``. The held experts' weights, ``width`` wide, are ``layer``'s
+    own parameters (called from its compact ``__call__``)."""
+    cfg = layer.cfg
+    dtype = jnp.dtype(cfg.dtype)
+    lo, hi = cfg.held
+    n, d = hi - lo, x.shape[-1]
+    fan_in = nn.initializers.variance_scaling(
+        1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
+    expert = lambda name, shape, axes: layer.param(
+        name, nn.with_logical_partitioning(fan_in, axes), shape, dtype)
+    w_gate = expert("experts_gate", (n, d, width), ("experts", "embed", "expert_mlp"))
+    w_up = expert("experts_up", (n, d, width), ("experts", "embed", "expert_mlp"))
+    w_down = expert("experts_down", (n, width, d), ("experts", "expert_mlp", "embed"))
+    with jax.named_scope("held_experts"):
+        return held_expert_ffn(
+            x, choice, gates, w_gate, w_up, w_down, lo=lo, rows=cfg.moe_chunk_rows)
+
+
+def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=None) -> dict:
+    """Sow this layer's choices ([b, s, k]; -1: a pad) into ``routing`` and
+    return its assignments by where they went: ``load_max`` the fullest held
+    expert's; ``slots`` and ``layers`` make means of sums. ``zero`` marks the
+    choices that went to zero-compute experts (none where a router has none)."""
+    lo, hi = layer.cfg.held
+    n = hi - lo
+    layer.sow("routing", "choice", choice.reshape(*batch_shape, choice.shape[-1]))
+    if zero is None:
+        zero = jnp.zeros(choice.shape, bool)
+    held = (choice >= lo) & (choice < hi)
+    n_held = jnp.sum(held, dtype=jnp.int32)
+    load = jnp.sum((choice[..., None] - lo) == jnp.arange(n), axis=(0, 1), dtype=jnp.int32)
+    return {
+        "assigned": jnp.sum(choice >= 0, dtype=jnp.int32),
+        "held": n_held,
+        "zero": jnp.sum(zero, dtype=jnp.int32),
+        "absent": jnp.sum((choice >= 0) & ~held & ~zero, dtype=jnp.int32),
+        "load_max": jnp.max(load),
+        "dropped": n_held - computed,
+        "slots": jnp.int32(n),
+        "layers": jnp.int32(1),
+    }
 
 
 class ExpertLayer(nn.Module):
@@ -266,10 +356,7 @@ class ExpertLayer(nn.Module):
     @nn.compact
     def __call__(self, u, token_mask):
         cfg = self.cfg
-        dtype = jnp.dtype(cfg.dtype)
         b, s, d = u.shape
-        lo, hi = cfg.held
-        n, f = hi - lo, cfg.expert_ffn_hidden_size
         w_r = self.param(
             "router_kernel",
             nn.with_logical_partitioning(nn.initializers.lecun_normal(), ("embed", "router")),
@@ -278,43 +365,13 @@ class ExpertLayer(nn.Module):
             "router_bias",
             nn.with_logical_partitioning(nn.initializers.zeros_init(), ("router",)),
             (cfg.router_width,), jnp.float32)
-        fan_in = nn.initializers.variance_scaling(
-            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
-        expert = lambda name, shape, axes: self.param(
-            name, nn.with_logical_partitioning(fan_in, axes), shape, dtype)
-        w_gate = expert("experts_gate", (n, d, f), ("experts", "embed", "expert_mlp"))
-        w_up = expert("experts_up", (n, d, f), ("experts", "embed", "expert_mlp"))
-        w_down = expert("experts_down", (n, f, d), ("experts", "expert_mlp", "embed"))
-
         x = u.reshape(b * s, d)
-        choice, gates = route(x, w_r, bias, cfg)
-        if token_mask is not None:  # a pad token is routed nowhere
-            real = token_mask.reshape(b * s, 1)
-            choice = jnp.where(real, choice, -1)
-            gates = jnp.where(real, gates, 0.0)
-        with jax.named_scope("held_experts"):
-            out, computed = held_expert_ffn(
-                x, choice, gates, w_gate, w_up, w_down, lo=lo, rows=cfg.moe_chunk_rows)
+        choice, gates = mask_pads(*route(x, w_r, bias, cfg), token_mask)
+        out, computed = held_experts(self, x, choice, gates, cfg.expert_ffn_hidden_size)
         zero = choice >= cfg.n_routed_experts
         out = out + _zero_experts(x, gates, zero)
-        self.sow("routing", "choice", choice.reshape(b, s, cfg.moe_topk))
-
-        held = (choice >= lo) & (choice < hi)
-        n_held = jnp.sum(held, dtype=jnp.int32)
-        load = jnp.sum((choice[..., None] - lo) == jnp.arange(n), axis=(0, 1), dtype=jnp.int32)
-        # this layer's assignments by where they went; ``load_max`` the
-        # fullest held expert's; ``slots`` and ``layers`` make means of sums
-        counts = {
-            "assigned": jnp.sum(choice >= 0, dtype=jnp.int32),
-            "held": n_held,
-            "zero": jnp.sum(zero, dtype=jnp.int32),
-            "absent": jnp.sum((choice >= 0) & ~held & ~zero, dtype=jnp.int32),
-            "load_max": jnp.max(load),
-            "dropped": n_held - computed,
-            "slots": jnp.int32(n),
-            "layers": jnp.int32(1),
-        }
-        return out.astype(dtype).reshape(b, s, d), counts
+        counts = sow_and_count(self, choice, computed, (b, s), zero)
+        return out.astype(jnp.dtype(cfg.dtype)).reshape(b, s, d), counts
 
 
 def _zero_experts(x, gates, zero):
@@ -339,10 +396,33 @@ class LongcatLayer(nn.Module):
             u = norm(f"ffn_norm_{i}")(a)
             if i == 0:
                 shortcut, counts = ExpertLayer(cfg, name="moe")(u, attn_mask)
-            h = a + DenseFFN(cfg, name=f"ffn_{i}")(u)
+            h = a + DenseFFN(cfg, cfg.ffn_hidden_size, name=f"ffn_{i}")(u)
             if i == 1:
                 h = h + shortcut
         return nn.with_logical_constraint(h, ("batch", "seq", "embed")), counts
+
+
+def embed_tokens(cfg, input_ids):
+    """The decoder's embedding of ``input_ids`` (the calling model's
+    ``embed_tokens`` submodule), [b, s, hidden]."""
+    dtype = jnp.dtype(cfg.dtype)
+    x = nn.Embed(
+        cfg.vocab_size, cfg.hidden_size, dtype=dtype, param_dtype=dtype,
+        embedding_init=nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), ("vocab", "embed")),
+        name="embed_tokens",
+    )(input_ids)
+    return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+
+
+def sow_attention(model: nn.Module, blocks: int, seq_len: int) -> None:
+    """Which attention the step ran, into ``stats`` (``RobertaEncoder``'s
+    names): the model's latent-attention ``blocks`` and how many of them ran
+    the kernel — all or none, by ``_fused_attention``."""
+    blocks = jnp.int32(blocks)
+    fused = _fused_attention(model.cfg, seq_len) is not None
+    model.sow("stats", "attn", {"layers": blocks, "fused": blocks * fused},
+              reduce_fn=lambda _, new: new, init_fn=dict)
 
 
 class LongcatModel(nn.Module):
@@ -357,22 +437,12 @@ class LongcatModel(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(input_ids.shape[1]), input_ids.shape)
-        x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, dtype=dtype, param_dtype=dtype,
-            embedding_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("vocab", "embed")),
-            name="embed_tokens",
-        )(input_ids)
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = embed_tokens(cfg, input_ids)
         totals = None
         for i in range(cfg.num_layers):
             x, counts = LongcatLayer(cfg, name=f"layers_{i}")(x, attn_mask, positions)
             totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
         # per step, summed over layers; replaced, not appended, on each apply
         self.sow("stats", "moe", totals, reduce_fn=lambda _, new: new, init_fn=dict)
-        # which attention the step ran (``RobertaEncoder``'s names): two blocks a layer
-        blocks = jnp.int32(2 * cfg.num_layers)
-        fused = _fused_attention(cfg, input_ids.shape[1]) is not None
-        self.sow("stats", "attn", {"layers": blocks, "fused": blocks * fused},
-                 reduce_fn=lambda _, new: new, init_fn=dict)
+        sow_attention(self, 2 * cfg.num_layers, input_ids.shape[1])  # two blocks a layer
         return RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="norm")(x)

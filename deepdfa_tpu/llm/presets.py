@@ -4,10 +4,11 @@ The five MSIVD launch scripts (``MSIVD/msivd/scripts/*.sh``) as structured
 configs (``encoder_family="llama"``), the two LineVul configs of BASELINE
 config #3 (``scripts/performance_evaluation.sh:7-9``: LineVul alone and
 DeepDFA+LineVul combined, ``encoder_family="roberta"``), and the MSIVD job
-with a latent-attention routed-expert decoder frozen in the LLM's place
-(``encoder_family="longcat"``: one chip's share of an expert-parallel
-deployment, and its test-size twin). What a family is lives in
-``llm/families.py`` (a fourth: one row there, one model file); a preset's
+with a latent-attention routed-expert decoder frozen in the LLM's place, of
+either sparse family (``encoder_family="longcat"`` and ``"pangu_moe"``: one
+chip's share of an expert-parallel deployment each, and their test-size
+twins). What a family is lives in ``llm/families.py`` (four today; a further
+one is one row there and one model file); a preset's
 ``llm`` must be its family's config class, checked at construction.
 ``finetuned`` marks presets that start from a LoRA-finetuned model
 (the reference's ``--finetuned_path`` / ``PeftInference`` load path,
@@ -15,7 +16,7 @@ deployment, and its test-size twin). What a family is lives in
 ``deepdfa_tpu/llm/{convert,lora}.py``). Mesh suggestions are TPU-side design
 (no reference equivalent — it used ``device_map="balanced"``): 7B fits one
 v4-8 slice with fsdp; 13B long-block presets shard seq over ``sp`` with ring
-attention; the routed decoder holds one chip's range of experts
+attention; a routed decoder holds one chip's range of experts
 (``experts_held``).
 """
 
@@ -28,6 +29,7 @@ from deepdfa_tpu.llm.families import FAMILIES
 from deepdfa_tpu.llm.joint import JointConfig
 from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
 from deepdfa_tpu.llm.longcat import LongcatConfig, longcat_flash, tiny_longcat
+from deepdfa_tpu.llm.pangu_moe import PanguMoeConfig, openpangu_ultra_moe, tiny_pangu_moe
 from deepdfa_tpu.llm.roberta import RobertaConfig, codebert_base
 
 __all__ = ["JointPreset", "PRESETS"]
@@ -36,14 +38,14 @@ __all__ = ["JointPreset", "PRESETS"]
 @dataclasses.dataclass(frozen=True)
 class JointPreset:
     name: str
-    llm: LlamaConfig | RobertaConfig | LongcatConfig  # encoder_family's class
+    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig  # encoder_family's class
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
     dataset: str  # reference data family the preset targets
     # which encoder stack drives the fusion head: "llama" (causal, MSIVD),
-    # "roberta" (bidirectional CodeBERT — the LineVul configs) or "longcat"
-    # (causal, latent attention + routed experts, frozen)
+    # "roberta" (bidirectional CodeBERT — the LineVul configs), "longcat" or
+    # "pangu_moe" (causal, latent attention + routed experts, frozen)
     encoder_family: str = "llama"
 
     def __post_init__(self):
@@ -178,6 +180,38 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="longcat",
+        ),
+        # the same job over the other sparse decoder, openPangu-Ultra-MoE at
+        # its published widths: rank 0 of the 16 chips that share each layer
+        # by expert parallelism (16 of 256 routed experts; attention, the
+        # shared expert, the dense FFN and the router whole), one leading
+        # dense layer + four expert layers as one pipeline stage, an eighth
+        # of the vocabulary
+        JointPreset(
+            name="openpangu_ultra_msivd",
+            llm=openpangu_ultra_moe(num_hidden_layers=5, first_k_dense_replace=1,
+                                    vocab_size=19200, experts_held=(0, 16)),
+            joint=JointConfig(
+                block_size=2048, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="pangu_moe",
+        ),
+        # the same code at test size (CPU): 2 of 8 routed experts held
+        JointPreset(
+            name="tiny_pangu_moe_msivd",
+            llm=tiny_pangu_moe(vocab_size=2048, experts_held=(0, 2)),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="pangu_moe",
         ),
     ]
 }

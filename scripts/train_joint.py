@@ -72,7 +72,7 @@ def main(argv=None) -> dict:
         "--encoder", choices=list(FAMILIES), default=None,
         help="encoder stack (default: preset's encoder_family, else llama); "
         "roberta = the CodeBERT/LineVul bidirectional path (config #3); "
-        "longcat = a frozen latent-attention routed-expert decoder",
+        "longcat, pangu_moe = a frozen latent-attention routed-expert decoder",
     )
     parser.add_argument(
         "--freeze-graph", default=None, metavar="CKPT_DIR",
