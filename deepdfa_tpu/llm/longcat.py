@@ -73,7 +73,7 @@ from jax import lax
 
 from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.llama import RMSNorm, rope_cos_sin
-from deepdfa_tpu.ops.grouped import held_expert_ffn
+from deepdfa_tpu.ops.grouped import combined_positions, held_expert_ffn
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention
 
 __all__ = [
@@ -149,7 +149,10 @@ class LongcatConfig(HeldRange):
     # [lo, hi) of the routed experts held here; None = all of them
     experts_held: tuple[int, int] | None = None
     attn_block_q: int = 256  # queries per attention block
-    moe_chunk_rows: int = 4096  # assignments per grouped product (ops/grouped.py)
+    # sorted assignments a trip of the expert loop takes (ops/grouped.py): the rows
+    # of each grouped product and of the gather, so the loop's memory; the combine
+    # walks a trip in blocks and only as far as it holds assignments
+    moe_chunk_rows: int = 4096
 
     def __post_init__(self):
         self._check_held()
@@ -325,8 +328,11 @@ def held_experts(layer: nn.Module, x, choice, gates, width: int):
 def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=None) -> dict:
     """Sow this layer's choices ([b, s, k]; -1: a pad) into ``routing`` and
     return its assignments by where they went: ``load_max`` the fullest held
-    expert's; ``slots`` and ``layers`` make means of sums. ``zero`` marks the
-    choices that went to zero-compute experts (none where a router has none)."""
+    expert's; ``combined`` the sorted positions the combine of
+    ``held_expert_ffn`` visited for them (``ops/grouped.py``; ``held`` over it
+    is how full its blocks were); ``slots`` and ``layers`` make means of sums.
+    ``zero`` marks the choices that went to zero-compute experts (none where a
+    router has none)."""
     lo, hi = layer.cfg.held
     n = hi - lo
     layer.sow("routing", "choice", choice.reshape(*batch_shape, choice.shape[-1]))
@@ -342,6 +348,7 @@ def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=N
         "absent": jnp.sum((choice >= 0) & ~held & ~zero, dtype=jnp.int32),
         "load_max": jnp.max(load),
         "dropped": n_held - computed,
+        "combined": combined_positions(n_held, layer.cfg.moe_chunk_rows),
         "slots": jnp.int32(n),
         "layers": jnp.int32(1),
     }

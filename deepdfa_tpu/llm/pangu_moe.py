@@ -94,7 +94,10 @@ class PanguMoeConfig(HeldRange):
     # [lo, hi) of the routed experts held here; None = all of them
     experts_held: tuple[int, int] | None = None
     attn_block_q: int = 256  # queries per attention block
-    moe_chunk_rows: int = 4096  # assignments per grouped product (ops/grouped.py)
+    # sorted assignments a trip of the expert loop takes (ops/grouped.py): the rows
+    # of each grouped product and of the gather, so the loop's memory; the combine
+    # walks a trip in blocks and only as far as it holds assignments
+    moe_chunk_rows: int = 4096
 
     # ``LatentAttention``'s switches: this family scales no latent
     mla_scale_q_lora = False

@@ -18,8 +18,19 @@ Mechanism — ``ops/segment.py``'s problem on the MXU:
 3. each chunk is one grouped matrix product per projection
    (:func:`grouped_matmul`: jax's stock megablox kernel on the TPU, which
    visits only the row tiles that hold assignments; ``lax.ragged_dot``
-   elsewhere), gated SiLU in float32, and a scatter-add of the gate-weighted
-   rows back onto their tokens.
+   elsewhere) and gated SiLU in float32;
+4. the chunk's gate-weighted rows go back onto their tokens as a product the
+   MXU runs, not as a scatter-add (on the TPU a fixed cost of the target's
+   shape, whatever the rows hold: 24 ms onto ``[8192, 7680]`` float32, PERF.md
+   section 6, PR 34): for a block of sorted positions,
+   ``onehot[token, position] @ rows`` *is* the scatter-add. The one-hot matrix
+   is exact in bfloat16; the float32 rows go in as three bfloat16 addends
+   (``hi + mid + lo``: 24 bits of significand, so their sum is the row itself)
+   and the product accumulates in float32 — a token's result is still a
+   float32 sum of float32 expert rows. A trip walks its chunk in blocks and
+   stops after the last block that holds an assignment
+   (:func:`combine_blocks`), so the work follows the assignments held, not
+   ``rows``.
 
 The count of rows handed to the grouped products (the sum of the group sizes
 each call was given: rows outside a group are not computed) is returned
@@ -33,12 +44,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["grouped_matmul", "held_expert_ffn"]
+__all__ = ["combine_blocks", "combined_positions", "grouped_matmul", "held_expert_ffn"]
 
 # megablox tiles (m, k, n) for one v5e core: [512, 1024] and [1024, 1024]
 # bf16 operand tiles double-buffered plus a [512, 1024] f32 accumulator stay
 # well inside the 16 MiB of scoped VMEM
 _GMM_TILING = (512, 1024, 1024)
+
+# sorted positions a block of the combine takes: [t, 3 x 512] one-hot columns
+# against [3 x 512, d] addends, one pass over ``out`` a block
+_COMBINE_BLOCK = 512
 
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> jnp.ndarray:
@@ -56,6 +71,36 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> 
         return gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
                    tiling=(tm, min(tk, k), min(tn, n)))
     return lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
+
+
+def combine_blocks(left, rows: int):
+    """``(blocks, width)``: the combine of one trip walks ``blocks`` blocks of
+    ``width`` sorted positions when ``left`` positions from the trip's start
+    on still hold an assignment — up to the last block that holds one."""
+    width = _COMBINE_BLOCK if rows % _COMBINE_BLOCK == 0 else rows
+    return -(-jnp.clip(left, 0, rows) // width), width
+
+
+def combined_positions(n_held, rows: int):
+    """Sorted positions the combine visits (its blocks times their width,
+    summed over the loop's trips) when ``n_held`` assignments are consumed
+    ``rows`` at a time: every trip but the last is full and walked whole."""
+    last, width = combine_blocks(n_held % rows, rows)
+    return n_held // rows * rows + last * width
+
+
+def _add_rows(out: jnp.ndarray, tok: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``out.at[tok].add(y)`` as one product: ``onehot[t, 3w] @ [hi; mid; lo]``.
+
+    out: [t, d] float32; tok: [w] token of each row; y: [w, d] float32 (finite:
+    a row that holds no assignment is zero). ``reduce_precision`` and not a
+    cast to bfloat16 and back, which XLA may drop as excess precision."""
+    hi = lax.reduce_precision(y, exponent_bits=8, mantissa_bits=7)
+    mid = lax.reduce_precision(y - hi, exponent_bits=8, mantissa_bits=7)
+    addends = jnp.concatenate([hi, mid, y - hi - mid]).astype(jnp.bfloat16)
+    onehot = jnp.arange(out.shape[0], dtype=jnp.int32)[:, None] == jnp.tile(tok, 3)[None, :]
+    return out + jnp.dot(onehot.astype(jnp.bfloat16), addends,
+                         preferred_element_type=jnp.float32)
 
 
 def held_expert_ffn(
@@ -108,7 +153,15 @@ def held_expert_ffn(
         h = jax.nn.silu(grouped_matmul(x, w_gate, sz)) * grouped_matmul(x, w_up, sz)
         y = grouped_matmul(h.astype(u.dtype), w_down, sz)
         y = jnp.where(valid[:, None], y * gate[pos][:, None], 0.0)
-        out = out.at[jnp.where(valid, tok, t)].add(y, mode="drop")
+        # the rows back onto their tokens, block by block as far as the chunk
+        # holds assignments: float32 sums by a product the MXU runs, where a
+        # scatter-add costs the TPU the same whatever the chunk holds (module
+        # docstring, step 4)
+        blocks, width = combine_blocks(n_held - start, rows)
+        with jax.named_scope("combine"):
+            out = lax.fori_loop(0, blocks, lambda j, acc: _add_rows(
+                acc, lax.dynamic_slice_in_dim(tok, j * width, width),
+                lax.dynamic_slice_in_dim(y, j * width, width)), out)
         return start + rows, out, computed + jnp.sum(sz, dtype=jnp.int32)
 
     _, out, computed = lax.while_loop(

@@ -1,0 +1,195 @@
+"""The combine of ``ops/grouped.py:held_expert_ffn`` — the held experts' rows
+back onto their tokens as a one-hot product — against the scatter-add it
+replaced (written here), in float32; the blocks it walks against
+``combined_positions``, the count the ``moe`` stats carry."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from deepdfa_tpu.ops import grouped
+from deepdfa_tpu.ops.grouped import (
+    combine_blocks,
+    combined_positions,
+    grouped_matmul,
+    held_expert_ffn,
+)
+
+
+def scatter_add_ffn(u, choice, gates, w_gate, w_up, w_down, *, lo, rows):
+    """The same sort, loop and grouped products; each chunk's rows added onto
+    their tokens one index after the other."""
+    t, d = u.shape
+    k, n = choice.shape[1], w_gate.shape[0]
+    local = choice.reshape(-1) - lo
+    key = jnp.where((local >= 0) & (local < n), local, n).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    n_held = int(ends[-1])
+    token, gate = (order // k).astype(jnp.int32), gates.reshape(-1)[order]
+    out = jnp.zeros((t, d), jnp.float32)
+    for start in range(0, n_held, rows):
+        pos = jnp.minimum(start + jnp.arange(rows), t * k - 1)
+        valid = start + jnp.arange(rows) < n_held
+        tok = token[pos]
+        sz = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - sizes - start, 0, rows)
+        x = u[tok]
+        h = jax.nn.silu(grouped_matmul(x, w_gate, sz)) * grouped_matmul(x, w_up, sz)
+        y = grouped_matmul(h.astype(u.dtype), w_down, sz)
+        y = jnp.where(valid[:, None], y * gate[pos][:, None], 0.0)
+        out = out.at[jnp.where(valid, tok, t)].add(y, mode="drop")
+    return out, n_held
+
+
+def _weights(rng, n, d, f, dtype=jnp.float32):
+    wg, wu = (jnp.asarray(rng.normal(size=(n, d, f)), dtype) for _ in range(2))
+    return wg, wu, jnp.asarray(rng.normal(size=(n, f, d)), dtype)
+
+
+def _positions(choice, lo, n):
+    """Sorted position of every held (token, slot), -1 elsewhere: numpy's
+    stable sort by local expert, as the function sorts."""
+    local = np.asarray(choice).reshape(-1) - lo
+    key = np.where((local >= 0) & (local < n), local, n)
+    order = np.argsort(key, kind="stable")
+    at = np.full(local.shape, -1)
+    at[order[: int((key < n).sum())]] = np.arange(int((key < n).sum()))
+    return at.reshape(np.asarray(choice).shape)
+
+
+def _same(got, want):
+    scale = float(jnp.abs(want).max()) or 1.0
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6 * scale)
+
+
+# what a choice is drawn from: held experts are [3, 7); -1 a pad; 0-2 and 7-11 absent
+DRAWS = {
+    "pads and absent experts mixed in": lambda rng, t, k: rng.integers(-1, 12, size=(t, k)),
+    "every assignment held": lambda rng, t, k: rng.integers(3, 7, size=(t, k)),
+    "no assignment held": lambda rng, t, k: rng.choice([-1, 0, 2, 7, 11], size=(t, k)),
+}
+
+
+@pytest.mark.parametrize("rows", [8, 32, 4096])
+@pytest.mark.parametrize("draw", list(DRAWS))
+def test_combine_equals_the_scatter_add(draw, rows):
+    rng = np.random.default_rng(rows)
+    t, k, d, f = 40, 3, 16, 8
+    u = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    choice = jnp.asarray(DRAWS[draw](rng, t, k), jnp.int32)
+    gates = jnp.asarray(rng.random((t, k)), jnp.float32)
+    w = _weights(rng, 4, d, f)
+    out, computed = jax.jit(lambda *a: held_expert_ffn(*a, lo=3, rows=rows))(
+        u, choice, gates, *w)
+    want, n_held = scatter_add_ffn(u, choice, gates, *w, lo=3, rows=rows)
+    assert int(computed) == n_held == int(((choice >= 3) & (choice < 7)).sum())
+    assert out.dtype == jnp.float32
+    _same(out, want)
+    if draw == "no assignment held":  # zero trips
+        assert n_held == 0 and not np.asarray(out).any()
+    elif draw == "every assignment held":
+        assert n_held == t * k
+    else:
+        assert rows >= 4096 or n_held > rows  # 8, 32: several trips; 4096: one block
+
+
+@pytest.mark.parametrize("rows,where", [
+    (1024, "one block"), (2048, "two blocks"), (512, "two trips")])
+def test_a_token_with_several_held_assignments(rows, where):
+    """Token 0 goes to the first and the last held expert: its two rows sit at
+    the head of the sorted assignments and in the last expert's run — inside
+    one block of 512 (300 held), or in two blocks of one trip of 2048, or in
+    two trips of 512 (900 held)."""
+    rng = np.random.default_rng(3)
+    held = 300 if where == "one block" else 900
+    t, k, d, f, n = 320, 3, 8, 8, 4
+    choice = np.full((t, k), 20, np.int32)  # absent
+    flat = 3 + rng.choice(t * k - 3, held - 2, replace=False)  # not token 0's slots
+    choice.reshape(-1)[flat] = rng.integers(5, 5 + n, size=held - 2)
+    choice[0] = [5, -1, 5 + n - 1]
+    at = _positions(choice, 5, n)
+    first, last = at[0, 0], at[0, 2]
+    assert first == 0 and last >= held // 2
+    _, width = combine_blocks(0, rows)
+    assert width == 512
+    if where == "one block":
+        assert first // width == last // width
+    elif where == "two blocks":
+        assert first // rows == last // rows and first // width != last // width
+    else:
+        assert first // rows != last // rows
+    u = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    gates = jnp.asarray(rng.random((t, k)), jnp.float32)
+    w = _weights(rng, n, d, f)
+    out, computed = held_expert_ffn(u, jnp.asarray(choice), gates, *w, lo=5, rows=rows)
+    want, n_held = scatter_add_ffn(u, jnp.asarray(choice), gates, *w, lo=5, rows=rows)
+    assert int(computed) == n_held == held
+    _same(out, want)
+    x = u[0]
+    both = sum(g * ((jax.nn.silu(x @ w[0][e]) * (x @ w[1][e])) @ w[2][e])
+               for g, e in ((gates[0, 0], 0), (gates[0, 2], n - 1)))
+    np.testing.assert_allclose(out[0], both, rtol=1e-4, atol=1e-4)
+
+
+def test_rows_that_differ_below_bfloat16_are_summed_in_float32():
+    """bfloat16 tokens and weights. Token 0 goes to two experts whose rows
+    ``c0 + c1 * 2**-8 + c2 * 2**-17`` and ``c0 + c1 * 2**-8 + c2 * 2**-18``
+    agree in their leading 16 bits (and round to the same bfloat16), with
+    gates +1 and -1: the float32 sum is ``c2 * 2**-18``; a combine that
+    rounded ``y`` to bfloat16's 8 bits, or to 16, gives 0."""
+    dt = jnp.bfloat16
+    t, d, f = 8, 4, 3
+    u = jnp.zeros((t, d), dt).at[:, 0].set(1.0)
+    w_gate = jnp.zeros((2, d, f), dt).at[:, 0, :].set(3.0)
+    w_up = jnp.zeros((2, d, f), dt).at[:, 0, :].set(jnp.array([1.5, 1.0, 1.75], dt))
+    w_down = jnp.ones((2, f, d), dt).at[:, 1].set(2.0 ** -8)
+    w_down = w_down.at[0, 2].set(2.0 ** -17).at[1, 2].set(2.0 ** -18)
+    one = lambda e: np.asarray(held_expert_ffn(
+        u, jnp.full((t, 1), e, jnp.int32), jnp.ones((t, 1), jnp.float32),
+        w_gate, w_up, w_down, lo=0, rows=8)[0][0])
+    y_a, y_b = one(0), one(1)
+    to16 = lambda y: np.asarray(lax.reduce_precision(y, exponent_bits=8, mantissa_bits=15))
+    assert (y_a != y_b).all() and (to16(y_a) == to16(y_b)).all()
+    choice = jnp.full((t, 2), -1, jnp.int32).at[0].set(jnp.array([0, 1]))
+    gates = jnp.zeros((t, 2), jnp.float32).at[0].set(jnp.array([1.0, -1.0]))
+    out, computed = held_expert_ffn(u, choice, gates, w_gate, w_up, w_down, lo=0, rows=8)
+    assert int(computed) == 2
+    want, _ = scatter_add_ffn(u, choice, gates, w_gate, w_up, w_down, lo=0, rows=8)
+    assert (np.asarray(want[0]) == y_a - y_b).all() and (y_a - y_b != 0).all()
+    np.testing.assert_allclose(out[0], y_a - y_b, rtol=1e-6, atol=0)
+    assert not np.asarray(out[1:]).any()
+
+
+@pytest.mark.parametrize("rows,n_held", [
+    (1024, 0), (1024, 1), (1024, 512), (1024, 513), (1024, 1024 + 513), (1024, 2500),
+    (8, 20), (4096, 600)])
+def test_the_count_is_the_positions_the_loop_visits(monkeypatch, rows, n_held):
+    """``combined_positions`` (what ``sow_and_count`` reports) against a run
+    of the loop in which every block the combine takes is counted."""
+    visited = []
+    real = grouped._add_rows
+
+    def counted(out, tok, y):
+        jax.debug.callback(lambda: visited.append(tok.shape[0]))
+        return real(out, tok, y)
+    monkeypatch.setattr(grouped, "_add_rows", counted)
+    rng = np.random.default_rng(n_held)
+    t, k, d, f, n = 700, 4, 8, 4, 3
+    choice = np.full(t * k, 50, np.int32)
+    choice[rng.choice(t * k, n_held, replace=False)] = rng.integers(2, 2 + n, size=n_held)
+    u = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    out, computed = held_expert_ffn(
+        u, jnp.asarray(choice.reshape(t, k)), jnp.ones((t, k), jnp.float32),
+        *_weights(rng, n, d, f), lo=2, rows=rows)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    blocks, width = combine_blocks(n_held % rows, rows)
+    assert int(computed) == n_held
+    assert sum(visited) == int(combined_positions(jnp.int32(n_held), rows))
+    assert set(visited) <= {width}
+    # whole trips are walked whole; the last one up to its last held position
+    assert sum(visited) == n_held // rows * rows + int(blocks) * width
+    assert n_held <= sum(visited) < n_held + width

@@ -29,7 +29,7 @@ from deepdfa_tpu.llm.longcat import (
     route,
     tiny_longcat,
 )
-from deepdfa_tpu.ops.grouped import held_expert_ffn
+from deepdfa_tpu.ops.grouped import combine_blocks, held_expert_ffn
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, full_attention
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -138,12 +138,15 @@ def test_routing_counts_are_on_the_loss_sync_spans(followed):
     spans = [s for s in followed["driver"].trainer.telemetry.tracer.spans()
              if s.name == "loss.sync" and "moe_held" in s.attrs]
     assert len(spans) >= followed["driver"].setup_steps - 1  # the step in flight is not read
+    _, width = combine_blocks(0, followed["driver"].trainer.llm.cfg.moe_chunk_rows)
     for s in spans:
         a = s.attrs
         assert a["moe_dropped"] == 0 and a["reads"] == 1
         assert a["moe_held"] + a["moe_zero"] + a["moe_absent"] == a["moe_assigned"] > 0
         assert a["moe_load_max"] * a["moe_slots"] >= a["moe_held"] * a["moe_layers"]
         assert all(isinstance(a[k], int) for k in a if k.startswith("moe_"))
+        # the combine's blocks: only the last one of a layer's loop is not full
+        assert a["moe_held"] <= a["moe_combined"] < a["moe_held"] + width * a["moe_layers"]
 
 
 def test_make_joint_steps_names_no_family():

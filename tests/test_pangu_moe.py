@@ -30,6 +30,7 @@ from deepdfa_tpu.llm.pangu_moe import (
     route,
     tiny_pangu_moe,
 )
+from deepdfa_tpu.ops.grouped import combine_blocks
 
 ROOT = Path(__file__).resolve().parent.parent
 BENCH = ROOT / "benchmark"
@@ -142,10 +143,13 @@ def test_routing_counts_are_on_the_loss_sync_spans(followed):
     spans = [s for s in followed["driver"].trainer.telemetry.tracer.spans()
              if s.name == "loss.sync" and "moe_held" in s.attrs and t0 <= s.start_s <= t1]
     assert len(spans) >= followed["driver"].setup_steps - 1  # the step in flight is not read
+    _, width = combine_blocks(0, followed["driver"].trainer.llm.cfg.moe_chunk_rows)
     for s in spans:
         a = s.attrs
         assert a["moe_dropped"] == 0 and a["moe_zero"] == 0 and a["moe_layers"] == 2
         assert a["moe_held"] + a["moe_absent"] == a["moe_assigned"] > 0
+        # the combine's blocks: only the last one of a layer's loop is not full
+        assert a["moe_held"] <= a["moe_combined"] < a["moe_held"] + width * a["moe_layers"]
         assert a["attn_layers"] == 3 and a["attn_fused"] == 0
     # the checked steps' routing has one entry an expert layer
     assert followed["run"]["readings"]["routing"][0].shape[0] == 2
@@ -461,10 +465,11 @@ def test_encoder_flag_alone_builds_the_hermetic_decoder():
 # -- what the other sparse decoder keeps ---------------------------------------
 
 # sha256 of ``jit(train_step).lower(...).as_text()`` of ``tiny_longcat`` behind the frozen
-# joint step on the commit before this file (PR 32, jax 0.9.0), made by this very function
-# there: ``LatentAttention``, the rope and the held-experts path now serve two decoders, and
-# LongCat's program is the parent's byte for byte. A PR that means to change it replaces this.
-LONGCAT_STEP = "0683cd6eda80dea4a346916bb9523a0d0e32304dc05c9b130d769bd505e64107"
+# joint step (jax 0.9.0), made by this very function: ``LatentAttention``, the rope and the
+# held-experts path serve two decoders, so a change to ``longcat.py`` or ``ops/grouped.py``
+# that is meant for one of them must keep LongCat's program byte for byte. A PR that means
+# to change it replaces this.
+LONGCAT_STEP = "fa1ad5486a43022df734fa5f0f32d2f29d20a246ca50ef68c1983c903ec83e1f"
 
 
 def _lowered_longcat_step() -> str:
@@ -499,5 +504,9 @@ def _lowered_longcat_step() -> str:
 
 
 def test_the_longcat_step_is_lowered_as_before():
+    """Moved on purpose by PR 34 (from 0683cd6e..., PR 32's program, which PR 33 kept):
+    ``held_expert_ffn`` puts a chunk's rows back onto their tokens by a one-hot product
+    in place of the scatter-add, and the ``moe`` stats carry one count more
+    (``combined``) — both decoders' programs change with it."""
     text = _lowered_longcat_step()
     assert hashlib.sha256(text.encode()).hexdigest() == LONGCAT_STEP
