@@ -6,10 +6,11 @@ quantization (``train.py:873-885``), HF accelerate ``device_map`` layer
 placement (``train.py:883``), ``torch.nn.DataParallel`` (``train.py:936``) —
 this package uses bf16 weights GSPMD-sharded over a named mesh (tp/fsdp for
 weights, dp for batch, sp + ring attention for long sequences; a routed
-decoder is told the range of experts its chip holds). Four encoder families
+decoder is told the range of experts its chip holds). Five encoder families
 drive the fusion head:
 ``llama`` (causal, dense), ``roberta`` (bidirectional), ``longcat`` and
-``pangu_moe`` (causal, latent attention, routed experts): what a family is
+``pangu_moe`` (causal, latent attention, routed experts), ``jamba`` (causal,
+selective-scan layers with multi-query attention every few): what a family is
 lives in ``families.py``, and a further one is one row there and one model file.
 """
 
@@ -38,9 +39,12 @@ __all__ = [
     #            joint classifier); and what both sparse decoders share
     # pangu_moe — sandwich norms, latent attention, leading dense layers then a
     #            shared expert beside sigmoid-routed experts (frozen decoder too)
+    # jamba    — Mamba-1 selective-scan layers (ops/selective_scan.py) with
+    #            multi-query attention every few, an MLP in every layer (frozen
+    #            decoder too, whole)
     # families — what an encoder family is, and build_encoder over it: classes,
     #            weights, tokenizer, pooling, trained or frozen
     # presets  — the launch configurations: five MSIVD scripts (llama), two
-    #            LineVul (roberta), a routed decoder and its tiny twin each for
-    #            longcat and pangu_moe
+    #            LineVul (roberta), a frozen decoder and its tiny twin each for
+    #            longcat, pangu_moe and jamba
 ]

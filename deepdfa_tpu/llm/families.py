@@ -52,7 +52,7 @@ def _roberta_from_checkpoint(ckpt_dir, llm_cfg):
 
 
 def _sparse_from_seed(llm, key, ids, pad_mask):
-    # the routed decoders'. jitted: at published widths the weights (bfloat16) are made on the device, never as float32 on the host
+    # the frozen decoders' (routed, state-space). jitted: at published widths the weights (bfloat16) are made on the device, never as float32 on the host
     return nn.meta.unbox(jax.jit(llm.init)(key, ids, pad_mask)["params"])
 
 
@@ -96,6 +96,10 @@ FAMILIES: dict[str, EncoderFamily] = {
                       from_seed=_sparse_from_seed),
         # causal, sandwich norms, latent attention, dense then shared + sigmoid-routed experts, frozen; no converter
         EncoderFamily("pangu_moe", "PanguMoeConfig", "PanguMoeModel", "tiny_pangu_moe", pool="last", trained=False,
+                      from_seed=_sparse_from_seed),
+        # causal, Mamba-1 selective-scan layers with multi-query attention every few, an MLP in every layer,
+        # frozen; no converter
+        EncoderFamily("jamba", "JambaConfig", "JambaModel", "tiny_jamba", pool="last", trained=False,
                       from_seed=_sparse_from_seed),
     ]
 }

@@ -7,7 +7,8 @@ DeepDFA+LineVul combined, ``encoder_family="roberta"``), and the MSIVD job
 with a latent-attention routed-expert decoder frozen in the LLM's place, of
 either sparse family (``encoder_family="longcat"`` and ``"pangu_moe"``: one
 chip's share of an expert-parallel deployment each, and their test-size
-twins). What a family is lives in ``llm/families.py`` (four today; a further
+twins) or a hybrid state-space decoder whole (``"jamba"``, and its twin).
+What a family is lives in ``llm/families.py`` (five today; a further
 one is one row there and one model file); a preset's
 ``llm`` must be its family's config class, checked at construction.
 ``finetuned`` marks presets that start from a LoRA-finetuned model
@@ -26,6 +27,7 @@ import dataclasses
 
 from deepdfa_tpu.config import MeshConfig
 from deepdfa_tpu.llm.families import FAMILIES
+from deepdfa_tpu.llm.jamba import JambaConfig, jamba2_3b, tiny_jamba
 from deepdfa_tpu.llm.joint import JointConfig
 from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
 from deepdfa_tpu.llm.longcat import LongcatConfig, longcat_flash, tiny_longcat
@@ -38,14 +40,15 @@ __all__ = ["JointPreset", "PRESETS"]
 @dataclasses.dataclass(frozen=True)
 class JointPreset:
     name: str
-    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig  # encoder_family's class
+    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig  # encoder_family's class
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
     dataset: str  # reference data family the preset targets
     # which encoder stack drives the fusion head: "llama" (causal, MSIVD),
     # "roberta" (bidirectional CodeBERT — the LineVul configs), "longcat" or
-    # "pangu_moe" (causal, latent attention + routed experts, frozen)
+    # "pangu_moe" (causal, latent attention + routed experts, frozen), "jamba"
+    # (causal, selective-scan layers + multi-query attention, frozen)
     encoder_family: str = "llama"
 
     def __post_init__(self):
@@ -212,6 +215,35 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="pangu_moe",
+        ),
+        # the same job over a hybrid state-space decoder, AI21-Jamba2-3B whole:
+        # all 28 layers (26 Mamba-1 selective scans, multi-query attention at
+        # layers 7 and 21), the whole vocabulary, every width as published —
+        # 6.06 GB of bfloat16 weights, nothing cut and no deployment share
+        JointPreset(
+            name="jamba2_3b_msivd",
+            llm=jamba2_3b(),
+            joint=JointConfig(
+                block_size=2048, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="jamba",
+        ),
+        # the same code at test size (CPU): 8 layers, attention at 2 and 6
+        JointPreset(
+            name="tiny_jamba_msivd",
+            llm=tiny_jamba(vocab_size=2048),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="jamba",
         ),
     ]
 }
