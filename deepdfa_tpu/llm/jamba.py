@@ -33,9 +33,11 @@ Precision as the other decoders': weights and activations ``dtype``, products
 accumulate in float32; RMSNorm, softplus, ``exp(delta A)``, the state and its
 whole recurrence, the ``C`` reduction and softmax in float32; ``A_log``, ``D``
 and ``b_dt`` are float32 leaves (Mamba's convention); each sub-layer's output
-is rounded once. ``tie_word_embeddings``: the embedding is the only
-vocabulary-sized leaf, and an encoder that hands out final-norm states builds
-no output head.
+is rounded once. On one TPU device the ``scan`` scope (softplus, recurrence,
+gate) is one kernel with the same precision (``ops/selective_scan.gated_scan``,
+at shapes its ``supports`` takes; ``stats/ssm`` counts the layers that ran
+it). ``tie_word_embeddings``: the embedding is the only vocabulary-sized
+leaf, and an encoder that hands out final-norm states builds no output head.
 
 The scopes ``layers_i/mamba/{in_proj,conv,scan,out_proj}``,
 ``layers_i/attn/scores`` and ``layers_i/mlp`` are what
@@ -50,10 +52,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.llama import RMSNorm
 from deepdfa_tpu.llm.longcat import DenseFFN, _proj, embed_tokens
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention
-from deepdfa_tpu.ops.selective_scan import causal_conv1d, selective_scan
+from deepdfa_tpu.ops.selective_scan import causal_conv1d, gated_scan, selective_scan, supports
 
 __all__ = ["JambaConfig", "JambaModel", "jamba2_3b", "tiny_jamba", "dt_bias_init"]
 
@@ -156,6 +159,17 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=jnp.float32)), shape).astype(dtype)
 
 
+def _fused_scan(cfg: JambaConfig, seq_len: int) -> bool | None:
+    """The ``interpret`` flag for the selective-scan kernel, or ``None`` where
+    the plain form has to run: no kernel here (the rule is
+    ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
+    take."""
+    interpret = roberta._attention_kernel()
+    if interpret is None or not supports(seq_len, cfg.d_inner, cfg.mamba_d_state):
+        return None
+    return interpret
+
+
 class MambaMixer(nn.Module):
     """The Mamba-1 mixer with Jamba's three inner norms (module docstring)."""
 
@@ -185,10 +199,15 @@ class MambaMixer(nn.Module):
         a_log = self.param(
             "A_log", nn.with_logical_partitioning(_a_log_init, ("mlp", None)), (di, n), jnp.float32)
         d_skip = channels("D", nn.initializers.ones_init(), (di,), jnp.float32)
+        interpret = _fused_scan(cfg, x.shape[1])
         with jax.named_scope("scan"):
-            delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            y = selective_scan(c, delta, -jnp.exp(a_log), b_in, c_in, d_skip, mask)
-            y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+            if interpret is None:  # the plain form, under the names ``benchmark/tools`` plant faults in
+                delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+                y = selective_scan(c, delta, -jnp.exp(a_log), b_in, c_in, d_skip, mask)
+                y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+            else:  # the same three steps as one kernel, ``z`` read where it lies in ``uz``
+                y = gated_scan(c, dt, dt_bias, -jnp.exp(a_log), b_in, c_in, d_skip, uz, mask,
+                               interpret=interpret)
         return _proj(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(y)
 
 
@@ -245,9 +264,12 @@ class JambaModel(nn.Module):
         for i in range(cfg.num_hidden_layers):
             x = JambaLayer(cfg, cfg.is_attention(i), name=f"layers_{i}")(x, attn_mask)
         # which mixers the step ran, into ``stats`` (the other encoders' names and channel):
-        # ``fused`` counts the layers whose mixer ran a kernel — none yet, for either kind
+        # ``fused`` counts the layers whose mixer ran a kernel — the Mamba layers all or none,
+        # by ``_fused_scan``; the attention layers none (``blocked_causal_attention``)
         n_attn = len(cfg.attention_layers)
-        for name, layers in (("ssm", cfg.num_hidden_layers - n_attn), ("attn", n_attn)):
-            self.sow("stats", name, {"layers": jnp.int32(layers), "fused": jnp.int32(0)},
+        n_ssm = cfg.num_hidden_layers - n_attn
+        ssm_fused = n_ssm * (_fused_scan(cfg, input_ids.shape[1]) is not None)
+        for name, layers, fused in (("ssm", n_ssm, ssm_fused), ("attn", n_attn, 0)):
+            self.sow("stats", name, {"layers": jnp.int32(layers), "fused": jnp.int32(fused)},
                      reduce_fn=lambda _, new: new, init_fn=dict)
         return RMSNorm(cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype), name="norm")(x)

@@ -17,9 +17,20 @@ them, and a row's result would depend on how much padding its batch gave it.
 0 there and a state of 0 decays to 0, so the state is *exactly* 0 at the first
 real token and a left-padded row's real tokens read what the row alone would.
 
-Plain ``jax`` (no kernel yet: ``fused`` in the model's ``ssm`` counts is 0):
-``lax.scan`` over the positions carrying the ``[b, n, d]`` float32 state
-(channels on the lanes), :data:`UNROLL` positions a trip. The state and its
+**Two forms of one algorithm.** A mixer's ``scan`` scope is the softplus, the
+scan and the gate. On one TPU device, at a shape :func:`supports` takes, that
+is one Pallas kernel behind :func:`gated_scan` (``ops/selective_scan_kernel.py``,
+imported where it first runs: Pallas costs a second of imports) whose float32
+state stays on the chip over the positions and which reads and writes the
+step's own arrays; the model's ``ssm`` counts say so (``fused``). Everywhere
+else — the CPU, several devices, other shapes — and for every gradient it is
+the plain form below, which the kernel is held to. Chunks of 128 positions
+were chosen on the chip at ``[4, 2048, 5120]`` x 16 states under the cell's
+padding (PERF.md section 6): 0.94 / 0.90 / 0.95 / 1.05 ms a layer at 64 / 128 /
+256 / 512 (1.94 with no padding), where the plain form takes 5.55.
+
+The plain form is ``lax.scan`` over the positions carrying the ``[b, n, d]``
+float32 state (channels on the lanes), :data:`UNROLL` positions a trip. The state and its
 whole recurrence are float32 whatever the inputs are, and no ``[b, s, d, n]``
 array of the whole sequence is ever built. The form and the trip were chosen
 on the chip at ``[4, 2048, 5120]`` x 16 states (PERF.md section 6): 17.4 /
@@ -33,13 +44,18 @@ kept.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["causal_conv1d", "selective_scan", "UNROLL"]
+__all__ = ["causal_conv1d", "selective_scan", "gated_scan", "supports", "UNROLL"]
 
 UNROLL = 32  # positions a trip of the loop
+# what the kernel takes: states, channels a group (one register a state index), positions a
+# grid step (the largest that tiles the sequence)
+D_STATE, GROUP, CHUNKS = 16, 1024, (128, 64, 32, 16)
 
 
 def causal_conv1d(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
@@ -87,3 +103,74 @@ def selective_scan(c: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray, B: jnp.nd
     _, ys = lax.scan(lambda s, inp: _step(a_t, s, *inp), state,
                      tuple(map(time_major, (delta, x, B, C))), unroll=UNROLL)
     return (time_major(ys) + f32(D) * cf).astype(c.dtype)
+
+
+def supports(seq_len: int, d_inner: int, d_state: int) -> bool:
+    """Whether the kernel takes this shape: 16 states, channels in whole
+    groups of 1,024 (a state index of a group is one register), whole chunks
+    of positions."""
+    return d_state == D_STATE and d_inner % GROUP == 0 and seq_len % CHUNKS[-1] == 0
+
+
+def _plain(c, dt, dt_bias, A, B, C, D, z, mask):
+    delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    y = selective_scan(c, delta, A, B, C, D, mask)
+    z = z[..., z.shape[-1] - c.shape[-1]:]
+    return (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(c.dtype)
+
+
+def _forward(*operands_mask, chunk, interpret):
+    from deepdfa_tpu.ops.selective_scan_kernel import scan_forward
+
+    return scan_forward(*operands_mask, chunk=chunk, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _fused(c, dt, dt_bias, A, B, C, D, z, mask, chunk, interpret):
+    return _forward(c, dt, dt_bias, A, B, C, D, z, mask, chunk=chunk, interpret=interpret)
+
+
+def _fused_fwd(*args):
+    return _forward(*args[:9], chunk=args[9], interpret=args[10]), args[:9]
+
+
+def _fused_bwd(chunk, interpret, residuals, do):
+    *operands, mask = residuals
+    _, vjp = jax.vjp(lambda *xs: _plain(*xs, mask), *operands)
+    return (*vjp(do), None)
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+def gated_scan(c: jnp.ndarray, dt: jnp.ndarray, dt_bias: jnp.ndarray, A: jnp.ndarray,
+               B: jnp.ndarray, C: jnp.ndarray, D: jnp.ndarray, z: jnp.ndarray,
+               mask: jnp.ndarray | None = None, *, interpret: bool | None = None,
+               chunk: int | None = None) -> jnp.ndarray:
+    """``selective_scan(c, softplus(dt + dt_bias), ..) * silu(z)``, [b, s, d]
+    in ``c``'s dtype: a Mamba mixer between ``dt_proj`` and ``out_proj``. dt:
+    [b, s, d]; dt_bias: [d]; z: [b, s, d], or a wider array whose last ``d``
+    columns it is (``in_proj``'s whole output: a slice handed to a kernel is a
+    copy, so the kernel reads those columns where they lie); the rest as
+    :func:`selective_scan`'s.
+
+    ``interpret=None`` is the plain form. ``False`` / ``True`` is the kernel,
+    compiled / under the Pallas interpreter, ``chunk`` positions a grid step
+    (the largest of :data:`CHUNKS` that tiles ``s`` if not given); the shape
+    must pass :func:`supports`. Its real tokens are the plain form's to
+    float32 rounding under any mask; at a pad position it returns zeros where
+    the whole chunk lies before the row's first real token and the plain
+    form's value elsewhere (nothing reads either). Differentiable: the
+    backward is the plain form's, recomputed."""
+    if interpret is None:
+        return _plain(c, dt, dt_bias, A, B, C, D, z, mask)
+    (b, s, d), n = c.shape, A.shape[1]
+    if not supports(s, d, n) or (z.shape[-1] - d) % GROUP:
+        raise ValueError(f"the selective-scan kernel takes no [s={s}, d_inner={d}, d_state={n}, "
+                         f"z={z.shape[-1]}]")
+    chunk = chunk or next(ch for ch in CHUNKS if s % ch == 0)
+    if s % chunk or chunk % CHUNKS[-1]:
+        raise ValueError(f"chunks of {chunk} positions do not tile s={s}")
+    if mask is None:
+        mask = jnp.ones((b, s), bool)
+    return _fused(c, dt, dt_bias, A, B, C, D, z, mask, chunk, interpret)

@@ -334,3 +334,23 @@ def test_the_latent_attention_kernel_compiles_for_the_v5e_at_the_decoders_size(o
     ).lower(lowering_platforms=("tpu",)).compile()
     assert "latent_attention_fwd" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_the_selective_scan_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
+    """Here for the fixture's sake too: ``ops/selective_scan.gated_scan`` at
+    [4, 2048, 5120] x 16 states, bfloat16, ``z`` as the second half of
+    ``in_proj``'s output (the strided stores and loads of the relayout, the
+    SMEM windows, VMEM), and no float32 array of the sequence's size
+    (168 MB) among the temporaries."""
+    from deepdfa_tpu.ops.selective_scan import gated_scan
+
+    b, s, d, n = 4, 2048, 5120, 16
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    f32 = functools.partial(shape, dtype=jnp.float32)
+    compiled = jax.jit(functools.partial(gated_scan, interpret=False)).trace(
+        shape(b, s, d), shape(b, s, d), f32(d), f32(d, n), shape(b, s, n), shape(b, s, n),
+        f32(d), shape(b, s, 2 * d), shape(b, s, dtype=jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "selective_scan_fwd" in text and " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
