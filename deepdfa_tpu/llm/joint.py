@@ -380,12 +380,15 @@ class JointTrainer:
         ``float(loss)`` — where the loop waits for the device — inside that
         step's ``loss.sync`` span. ``alone`` says no later step had been
         launched at the read (an evaluation point, the epoch's end): the
-        device then idles through the next launch."""
+        device then idles through the next launch. The read's return is the
+        moment a step is known complete: the telemetry puts the interval
+        since the last one on the span."""
         step, loss, wait_s, dispatch_s, stats = pending
         with self.telemetry.tracer.span(
             "loss.sync", step=step, reads=1, alone=int(alone)
         ) as sync:
             value = float(loss)
+            self.telemetry.observe_read(sync, alone)
             if stats:
                 # outputs of the step whose loss was just read: they are
                 # there already, this is a copy and no second wait

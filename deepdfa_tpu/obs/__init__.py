@@ -1,9 +1,11 @@
 """One telemetry plane: request/step tracing (W3C ``traceparent``,
 Chrome trace-event export), the shared Prometheus-exposition metrics
-registry, training-step timelines, the score-drift sentinel — and the
+registry, training-step timelines and the cadence of completed steps, the
+score-drift sentinel — and the
 verdict layer on top of it: the perf-regression ledger, the SLO
 burn-rate engine, and the crash flight recorder."""
 
+from deepdfa_tpu.obs.cadence import interval_stats, step_cadence
 from deepdfa_tpu.obs.drift import ScoreDriftSentinel, psi
 from deepdfa_tpu.obs.flightrec import FlightRecorder, install_sigusr2
 from deepdfa_tpu.obs.ledger import Ledger, LedgerEntry, LedgerStore
@@ -52,6 +54,7 @@ __all__ = [
     "escape_label_value",
     "federation_specs",
     "install_sigusr2",
+    "interval_stats",
     "load_trace_records",
     "new_span_id",
     "new_trace_id",
@@ -59,6 +62,7 @@ __all__ = [
     "psi",
     "router_specs",
     "serve_specs",
+    "step_cadence",
     "train_specs",
     "train_telemetry",
     "write_alerts_artifact",

@@ -32,7 +32,10 @@ the chaos battery can prove it — a dropped span bumps
 
 Clocks: a span's start is wall-clock (``time.time()``) so spans recorded
 in different processes land on one consistent export timeline; the
-duration of a :meth:`Tracer.span` is taken from ``time.perf_counter()``.
+duration of a :meth:`Tracer.span` is taken from ``time.perf_counter()``,
+and beside it ``cpu_s`` from ``time.thread_time()``: the seconds the thread
+that opened the span was running. ``dur_s - cpu_s`` is the time it was not:
+blocked in a runtime, on a lock, or waiting for the interpreter lock.
 A tracer built with ``annotation=jax.profiler.TraceAnnotation`` also puts
 every :meth:`Tracer.span` on the profiler's own clock as ``deepdfa:<name>``
 (a flag test while no profiler session runs; :meth:`Tracer.record` tells of
@@ -110,7 +113,9 @@ def parse_traceparent(header: str | None) -> SpanContext | None:
 @dataclass
 class Span:
     """One finished stage. ``start_s``/``dur_s`` are wall-clock seconds;
-    export converts to the µs the trace-event format wants."""
+    export converts to the µs the trace-event format wants. ``cpu_s`` is the
+    opening thread's on-CPU seconds inside the span (None where the interval
+    was told after the fact)."""
 
     name: str
     trace_id: str
@@ -122,6 +127,7 @@ class Span:
     root: bool = False
     attrs: dict = field(default_factory=dict)
     tid: int = 0
+    cpu_s: float | None = None
 
     @property
     def ctx(self) -> SpanContext:
@@ -136,6 +142,7 @@ class Span:
             "proc": self.proc,
             "start_s": self.start_s,
             "dur_ms": round(self.dur_s * 1e3, 4),
+            "cpu_ms": None if self.cpu_s is None else round(self.cpu_s * 1e3, 4),
             "root": self.root,
             "attrs": dict(self.attrs),
             "tid": self.tid,
@@ -157,7 +164,9 @@ class Tracer:
         self.exemplar_dir = Path(exemplar_dir) if exemplar_dir else None
         self.max_exemplars = int(max_exemplars)
         self._spans: deque[Span] = deque(maxlen=max(1, int(max_spans)))
-        self._lock = threading.Lock()
+        # re-entrant: a collection that starts while this thread holds the
+        # lock records its gc.pause span (obs.telemetry) on the same thread
+        self._lock = threading.RLock()
         self._local = threading.local()
         self.recorded_total = 0
         self.dropped_total = 0
@@ -187,9 +196,10 @@ class Tracer:
         sp = self.current_span()
         return sp.ctx if sp is not None else None
 
-    def _annotate(self, name: str, attrs: dict):
-        """The entered profiler annotation of one span, or None. Like
-        ``_record`` it never raises into the code it annotates."""
+    def annotate(self, name: str, /, **attrs):
+        """The entered profiler annotation ``deepdfa:<name>``, or None; whoever
+        enters one hands it to :meth:`end_annotation`. Like ``_record`` it
+        never raises into the code it annotates."""
         if self.annotation is None:
             return None
         try:
@@ -200,7 +210,7 @@ class Tracer:
             return None
 
     @staticmethod
-    def _end_annotation(ann) -> None:
+    def end_annotation(ann) -> None:
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
@@ -226,24 +236,30 @@ class Tracer:
                   tid=threading.get_ident() % 1_000_000)
         stack = self._stack()
         stack.append(sp)
-        ann = self._annotate(name, attrs)
-        t0 = time.perf_counter()
+        ann = self.annotate(name, **attrs)
+        t0, c0 = time.perf_counter(), time.thread_time()
         try:
             yield sp
         finally:
             sp.dur_s = time.perf_counter() - t0
-            self._end_annotation(ann)
+            sp.cpu_s = time.thread_time() - c0
+            self.end_annotation(ann)
             stack.pop()
             self._record(sp)
 
     def record(self, name: str, /, start_s: float, end_s: float | None = None,
                parent: SpanContext | None = None, root: bool = False,
+               cpu_s: float | None = None, unprompted: bool = False,
                **attrs) -> Span:
         """Record a span from explicit wall-clock times — the cross-thread
         path (queue wait) and the measured-after-the-fact path (a step
         already timed by its caller, a compile jax reports when it is
         over). Host ring only: the profiler cannot be told of an interval
-        that has passed."""
+        that has passed. ``cpu_s`` where the caller took the thread's CPU
+        clock round the interval itself. ``unprompted``: an event that no step
+        of the program's control flow caused (a collection). It takes no hit
+        of an ``obs.trace_drop`` schedule, whose hits count the program's own
+        spans: a chaos run stays the same run whenever the collector comes."""
         end_s = time.time() if end_s is None else end_s
         if parent is None:
             trace_id, parent_id = new_trace_id(), None
@@ -252,16 +268,17 @@ class Tracer:
         sp = Span(name=name, trace_id=trace_id, span_id=new_span_id(),
                   parent_id=parent_id, proc=self.proc, start_s=start_s,
                   dur_s=max(0.0, end_s - start_s), root=root,
-                  attrs=dict(attrs), tid=threading.get_ident() % 1_000_000)
-        self._record(sp)
+                  attrs=dict(attrs), tid=threading.get_ident() % 1_000_000,
+                  cpu_s=cpu_s)
+        self._record(sp, unprompted)
         return sp
 
-    def _record(self, sp: Span) -> None:
+    def _record(self, sp: Span, unprompted: bool = False) -> None:
         # a lost span export must never fail the request it annotates:
         # the injected obs.trace_drop loss and any real export failure
         # both end here, counted and swallowed
         try:
-            if faults.fire("obs.trace_drop"):
+            if not unprompted and faults.fire("obs.trace_drop"):
                 with self._lock:
                     self.dropped_total += 1
                 return
@@ -362,6 +379,8 @@ def chrome_trace(spans) -> dict:
             "args": {"trace_id": rec.get("trace_id"),
                      "span_id": rec.get("span_id"),
                      "parent_id": rec.get("parent_id"),
+                     **({} if rec.get("cpu_ms") is None
+                        else {"cpu_ms": rec["cpu_ms"]}),
                      **(rec.get("attrs") or {})},
         })
     return {"traceEvents": events, "displayTimeUnit": "ms"}

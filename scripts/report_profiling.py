@@ -6,6 +6,7 @@ gmacs / avg ms per example over ``profiledata.jsonl`` + ``timedata.jsonl``);
 the aggregation itself lives in ``deepdfa_tpu.train.profiling.report``.
 
 ``--traces`` switches to the tracing view: per-span-name duration stats
+(and on-CPU share) and the cadence of each epoch's completed steps
 over a run dir's ``event=trace`` exemplars (``deepdfa_tpu.obs``) — where
 a slow request actually spent its time (queue wait vs batch assembly vs
 engine dispatch), straight from the journaled traces. Use
@@ -25,27 +26,44 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
 
+def _by_name_row(rows: list[tuple]) -> dict:
+    """``rows`` = one span name's ``(dur_ms, cpu_ms or None)``."""
+    durs = [d for d, _ in rows]
+    out = {"count": len(rows), "mean_ms": round(sum(durs) / len(rows), 4),
+           "max_ms": round(max(durs), 4)}
+    timed = [(d, c) for d, c in rows if c is not None]
+    if timed:
+        # on-CPU share of the spans that carry their thread's CPU clock
+        out["cpu_share"] = round(
+            sum(c for _, c in timed) / max(sum(d for d, _ in timed), 1e-9), 4)
+    return out
+
+
 def trace_report(run_dir) -> dict:
-    """Per-span-name {count, mean_ms, max_ms} over the run's exemplars."""
-    from deepdfa_tpu.obs import load_trace_records
+    """Per-span-name {count, mean_ms, max_ms, cpu_share} over the run's
+    exemplars, and for each one that holds a training epoch the cadence of
+    its completed steps (``obs.step_cadence``: interval quantiles, the share
+    lost to stalls, each stall's cause)."""
+    from deepdfa_tpu.obs import load_trace_records, step_cadence
 
     records = load_trace_records(run_dir)
-    by_name: dict[str, list[float]] = {}
+    by_name: dict[str, list[tuple]] = {}
+    cadence = []
     for rec in records:
-        for span in rec.get("spans", []):
+        spans = rec.get("spans", [])
+        for span in spans:
             by_name.setdefault(span["name"], []).append(
-                float(span.get("dur_ms", 0.0)))
-    return {
-        "trace_records": len(records),
-        "spans": {
-            name: {
-                "count": len(durs),
-                "mean_ms": round(sum(durs) / len(durs), 4),
-                "max_ms": round(max(durs), 4),
-            }
-            for name, durs in sorted(by_name.items())
-        },
-    }
+                (float(span.get("dur_ms", 0.0)), span.get("cpu_ms")))
+        summary = step_cadence(spans)
+        if summary["steps"]:
+            epoch = next((s["attrs"].get("epoch") for s in spans
+                          if s["name"] == rec.get("root")), None)
+            cadence.append({"epoch": epoch, **summary})
+    report = {"trace_records": len(records),
+              "spans": {name: _by_name_row(rows) for name, rows in sorted(by_name.items())}}
+    if cadence:
+        report["cadence"] = cadence
+    return report
 
 
 def main(argv=None) -> None:

@@ -2,9 +2,12 @@
 producer's spans, compile events with the step they fell in, the profiler's
 annotations — and that none of it can touch the training itself."""
 
+import contextlib
+import gc
 import subprocess
 import sys
 import threading
+import time
 from pathlib import Path
 
 import jax
@@ -144,20 +147,62 @@ def test_an_exemplar_dir_exports_the_epoch_with_eval_and_checkpoint(tiny, tmp_pa
     report = trace_report(tmp_path)
     assert report["trace_records"] == 1
     counts = {name: row["count"] for name, row in report["spans"].items()}
+    counts.pop("gc.pause", None)  # the collector's long pauses, where it had any
     assert counts == {
         "train.epoch": 1, "data.wait": 3, "step.dispatch": 3, "loss.sync": 3,
         "batch.build": 4, "batch.h2d": 3, "eval": 1, "checkpoint.save": 1}
     assert report["spans"]["checkpoint.save"]["mean_ms"] > 0
+    # every span the loop timed carries its thread's CPU clock
+    assert all(row["cpu_share"] >= 0 for row in report["spans"].values())
+    # and the epoch's cadence: two intervals between its three reads
+    (cadence,) = report["cadence"]
+    assert cadence["epoch"] == 0 and cadence["steps"] == 2
+    assert 0 < cadence["interval_p50_ms"] <= cadence["interval_max_ms"]
+
+
+@contextlib.contextmanager
+def _a_collection_in_step(tiny, k):
+    """The collector held off, but for one full collection forced inside the
+    call of step ``k`` (none for ``None``): the run's pauses are known."""
+    trainer = tiny[0]
+    real_train, real_eval = trainer._steps
+    calls = []
+
+    def train_step(state, llm_arg, jb):
+        calls.append(1)
+        if k is not None and len(calls) == k + 1:
+            gc.collect()
+        return real_train(state, llm_arg, jb)
+
+    trainer._steps = (train_step, real_eval)
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+        trainer._steps = (real_train, real_eval)
 
 
 def test_params_equal_a_run_with_every_span_dropped(tiny):
     on = _telemetry()
-    with_spans = _train(tiny, on)
+    with _a_collection_in_step(tiny, 1):
+        with_spans = _train(tiny, on)
     off = _telemetry()
-    with faults.installed("obs.trace_drop:p=1"):
+    with faults.installed("obs.trace_drop:p=1"), _a_collection_in_step(tiny, None):
         without = _train(tiny, off)
+    # every recording was on in the first run: the CPU clock, the pause, the intervals
+    spans = on.tracer.spans()
+    (pause,) = [s for s in spans if s.name == "gc.pause"]
+    (call,) = [s for s in spans if s.name == "step.dispatch" and s.attrs["step"] == 1]
+    assert pause.attrs["generation"] == 2 and pause.attrs["step"] == 1
+    assert pause.parent_id == call.span_id and pause.tid == call.tid
+    assert all(s.cpu_s is not None for s in spans)
+    assert sum("interval_s" in s.attrs for s in spans if s.name == "loss.sync") == 2
+    # (a pause takes no hit of the drop schedule: it is no span of the program's making)
     assert len(on.tracer) > 15 and len(off.tracer) == 0
-    assert off.tracer.dropped_total == on.tracer.recorded_total
+    assert off.tracer.dropped_total == on.tracer.recorded_total - 1
     assert _same(with_spans.params, without.params)
     assert _same(with_spans.opt_state, without.opt_state)
     assert int(with_spans.step) == int(without.step) == 3
@@ -256,6 +301,90 @@ def test_a_profiler_session_holds_the_programs_annotations(tiny, tmp_path):
     assert lines["deepdfa:batch.build"] == lines["deepdfa:batch.h2d"]
     assert lines["deepdfa:batch.build"].isdisjoint(loop)
     assert sorted(steps) == [0, 1, 2]
+
+
+def test_a_profiler_session_holds_a_full_collection_on_the_thread_it_ran_on(tiny, tmp_path):
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)), _a_collection_in_step(tiny, 1):
+        _train(tiny, _telemetry())
+        gc.collect(1)  # a young collection is no annotation
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    lines: dict[str, list] = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        for at, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("deepdfa:"):
+                    lines.setdefault(ev.name, []).append(
+                        ((plane.name, at), ev.start_ns, ev.start_ns + ev.duration_ns))
+    (pause,) = lines["deepdfa:gc.pause"]
+    calls = sorted(lines["deepdfa:step.dispatch"], key=lambda e: e[1])
+    assert len(calls) == 3
+    # on the loop's line, on the profiler's clock, inside the call it interrupted
+    where, a, b = calls[1]
+    assert pause[0] == where and a <= pause[1] <= pause[2] <= b
+
+
+# -- the interval between completed steps ---------------------------------------
+
+class _Timed:
+    """A loss that notes when its read returns, on the clock the telemetry reads."""
+
+    def __init__(self, value, returned):
+        self._value, self._returned = value, returned
+
+    def __float__(self) -> float:
+        value = float(self._value)
+        self._returned.append(time.perf_counter())
+        return value
+
+
+@pytest.mark.parametrize("points,present", [
+    (None, [False, True, True]),  # the fixture's eval point is the last step: its lone read ends an interval
+    ([1], [False, True, False]),  # an evaluation lies before step 2's read: no interval
+    ([], [False, True, True]),
+])
+def test_interval_s_spans_consecutive_reads_and_nothing_else(tiny, monkeypatch, points, present):
+    from deepdfa_tpu.llm import joint
+
+    if points is not None:
+        monkeypatch.setattr(joint, "eval_points", lambda *_: set(points))
+    trainer = tiny[0]
+    real_train, real_eval = trainer._steps
+    returned: list[float] = []
+
+    def train_step(state, llm_arg, jb):
+        state, loss, probs = real_train(state, llm_arg, jb)
+        return state, _Timed(loss, returned), probs
+
+    trainer._steps = (train_step, real_eval)
+    telemetry = _telemetry()
+    try:
+        _train(tiny, telemetry)
+    finally:
+        trainer._steps = (real_train, real_eval)
+    sync = [s for s in telemetry.tracer.spans() if s.name == "loss.sync"]
+    assert ["interval_s" in s.attrs for s in sync] == present
+    for s in sync:
+        assert ("gc_s" in s.attrs) == ("gc_n" in s.attrs) == ("interval_s" in s.attrs)
+    intervals = [s.attrs.get("interval_s") for s in sync]
+    # each is the time from the read before it to this one; together, where no
+    # evaluation lies between, they are the time from the first read to the last
+    for k in (1, 2):
+        if present[k]:
+            assert intervals[k] == pytest.approx(returned[k] - returned[k - 1], abs=2e-4)
+    if all(present[1:]):
+        assert sum(intervals[1:]) == pytest.approx(returned[-1] - returned[0], abs=4e-4)
+    assert all(s.attrs["gc_n"] >= 0 and s.attrs["gc_s"] >= 0 for s in sync if "gc_n" in s.attrs)
+    # the epoch's entry and the scrape carry them; the gauge is the last interval
+    stats = next(h["telemetry"] for h in trainer.history if "telemetry" in h)
+    known = [v for v in intervals if v is not None]
+    assert stats["interval_max_ms"] == round(1e3 * max(known), 4)
+    assert 0 < stats["interval_p50_ms"] <= stats["interval_max_ms"] and stats["stalls"] >= 0
+    assert stats["gc_n"] >= 0 and stats["gc_s"] >= 0
+    text = telemetry.render().splitlines()
+    assert f"deepdfa_train_last_step_seconds {round(known[-1], 6)}" in text
+    assert any(line.startswith("deepdfa_train_gc_collections_total ") for line in text)
 
 
 # -- one step in flight: the loop launches step k, then reads step k-1's loss --

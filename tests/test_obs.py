@@ -5,6 +5,8 @@ sentinel, and training-step telemetry. Everything here is device-free —
 stub engines, no XLA compiles — so ``pytest -m obs`` runs in seconds and
 is wired into scripts/lint_gate.py."""
 
+import contextlib
+import gc
 import json
 import re
 import sys
@@ -378,6 +380,303 @@ def test_train_telemetry_windows_and_server_scrape():
         conn.close()
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# what a slow run is made of: on-CPU time, the collector's pauses, intervals
+
+
+@contextlib.contextmanager
+def _no_automatic_collections():
+    """Only the collections a test forces run (and call the callbacks)."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
+@pytest.mark.parametrize("kind", ["sleeping", "spinning"])
+def test_a_span_keeps_its_threads_cpu_time_beside_its_wall_time(kind):
+    from deepdfa_tpu.obs import Tracer
+
+    tracer = Tracer(proc="t")
+    with tracer.span("step.dispatch", step=1) as sp:
+        if kind == "sleeping":
+            time.sleep(0.1)
+        else:
+            c0 = time.thread_time()
+            while time.thread_time() - c0 < 0.03:
+                pass
+    assert sp.attrs == {"step": 1}  # a field of the span, not an attribute
+    # (room for a CPU clock that ticks in 10 ms, as the chip's host has)
+    assert 0.0 <= sp.cpu_s <= sp.dur_s + 0.011
+    if kind == "sleeping":
+        assert sp.dur_s >= 0.1 and sp.cpu_s < 0.5 * sp.dur_s
+    else:
+        assert sp.cpu_s >= 0.03
+
+
+def test_a_told_interval_has_no_cpu_time_and_an_exemplar_round_trips_cpu_ms(tmp_path):
+    from deepdfa_tpu.obs import Tracer, chrome_trace, load_trace_records
+
+    tracer = Tracer(proc="t", slow_ms=0.0, exemplar_dir=tmp_path)
+    told = tracer.record("jit.lower", 100.0, 100.25)
+    assert told.cpu_s is None and told.to_record()["cpu_ms"] is None
+    timed = tracer.record("gc.pause", 100.0, 100.004, cpu_s=0.0035, generation=2)
+    assert timed.cpu_s == 0.0035 and timed.attrs == {"generation": 2}
+    assert timed.to_record()["cpu_ms"] == 3.5
+    with tracer.span("train.epoch", root=True) as root:
+        with tracer.span("step.dispatch", step=0):
+            time.sleep(0.002)
+        tracer.record("jit.trace", time.time() - 0.001, parent=root.ctx)
+    (rec,) = load_trace_records(tmp_path)
+    by_name = {s["name"]: s for s in rec["spans"]}
+    assert set(by_name) == {"train.epoch", "step.dispatch", "jit.trace"}
+    assert by_name["jit.trace"]["cpu_ms"] is None
+    for name in ("train.epoch", "step.dispatch"):
+        live = next(s for s in tracer.spans() if s.name == name)
+        assert by_name[name]["cpu_ms"] == round(live.cpu_s * 1e3, 4) <= by_name[name]["dur_ms"] + 11
+    # the timeline shows it where the span has it
+    args = {e["name"]: e["args"] for e in chrome_trace(rec["spans"])["traceEvents"]
+            if e["ph"] == "X"}
+    assert args["step.dispatch"]["cpu_ms"] == by_name["step.dispatch"]["cpu_ms"]
+    assert "cpu_ms" not in args["jit.trace"] and args["step.dispatch"]["step"] == 0
+
+
+def test_a_forced_collection_inside_an_open_span_is_one_gc_pause():
+    from deepdfa_tpu.obs import Tracer, TrainTelemetry
+
+    t = TrainTelemetry(tracer=Tracer(proc="train", max_spans=64))
+    with _no_automatic_collections():
+        before = t.snapshot()
+        with t.tracer.span("step.dispatch", step=5) as call:
+            cycle = []
+            cycle.append(cycle)
+            del cycle
+            gc.collect()
+        after = t.snapshot()
+    (pause,) = [s for s in t.tracer.spans() if s.name == "gc.pause"]
+    assert pause.attrs["generation"] == 2 and pause.attrs["step"] == 5
+    assert isinstance(pause.attrs["collected"], int) and pause.attrs["collected"] >= 1
+    assert set(pause.attrs) == {"generation", "collected", "step"}
+    assert pause.parent_id == call.span_id and pause.trace_id == call.trace_id
+    assert pause.tid == call.tid and 0 <= pause.cpu_s <= pause.dur_s + 0.011
+    assert call.start_s - 1e-3 <= pause.start_s
+    assert pause.start_s + pause.dur_s <= call.start_s + call.dur_s + 1e-3
+    assert after["gc_n"] == before["gc_n"] + 1
+    assert after["gc_s"] == pytest.approx(before["gc_s"] + pause.dur_s, abs=2e-6)
+    # outside any span: a pause all the same, with no parent and no step; and it
+    # takes no hit of a chaos schedule, which counts the program's own spans
+    from deepdfa_tpu.resilience import faults
+
+    with _no_automatic_collections(), faults.installed("obs.trace_drop:p=1") as armed:
+        gc.collect()
+        assert armed.counters()["hits"] == {}
+    lone = t.tracer.spans()[-1]
+    assert lone.name == "gc.pause" and lone.parent_id is None and "step" not in lone.attrs
+    assert t.tracer.dropped_total == 0
+
+
+def test_short_collections_are_tallied_and_leave_no_span():
+    from deepdfa_tpu.obs import Tracer, TrainTelemetry
+    from deepdfa_tpu.obs.telemetry import MIN_GC_SPAN_S
+
+    t = TrainTelemetry(tracer=Tracer(proc="train", max_spans=64))
+    with _no_automatic_collections():
+        for _ in range(5):
+            gc.collect(0)
+    stats = t.epoch_stats()
+    assert stats["gc_n"] == 5 and 0 < stats["gc_s"] < 5.0
+    assert t.epoch_stats()["gc_n"] == 0 and t.snapshot()["gc_n"] == 5  # window and lifetime
+    # (a young collection on a starved machine may take a millisecond: then it is a span)
+    assert all(s.dur_s >= MIN_GC_SPAN_S for s in t.tracer.spans())
+    n = len(t.tracer)
+    t.observe_gc(100.0, MIN_GC_SPAN_S * 0.99, 1e-4, generation=1, collected=7)
+    assert len(t.tracer) == n and t.snapshot()["gc_n"] == 6
+    t.observe_gc(100.0, MIN_GC_SPAN_S, 1e-3, generation=0, collected=7)   # long
+    t.observe_gc(101.0, 1e-5, 1e-5, generation=2, collected=0)            # full
+    assert [s.attrs["generation"] for s in t.tracer.spans()[n:]] == [0, 2]
+    text = t.render()
+    _assert_exposition(text)
+    assert "deepdfa_train_gc_collections_total 8" in text
+    assert "deepdfa_train_gc_pause_seconds_total " in text
+
+
+def test_one_gc_callback_a_process_whatever_its_telemetries_do(monkeypatch):
+    import weakref
+
+    from deepdfa_tpu.obs import Tracer, TrainTelemetry, telemetry
+
+    mine = [TrainTelemetry(tracer=Tracer(proc="train", max_spans=8)) for _ in range(3)]
+    assert gc.callbacks.count(telemetry._on_gc) == 1
+    assert all(t in telemetry._live() for t in mine)
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("telemetry")
+
+    # a raising telemetry fails neither the collection nor the others' account of it
+    monkeypatch.setattr(mine[0], "observe_gc", boom)
+    with _no_automatic_collections():
+        assert isinstance(gc.collect(), int)
+    assert [t.snapshot()["gc_n"] for t in mine] == [0, 1, 1]
+    # a dead one is dropped: nothing holds it but the listeners' weak set
+    dead = weakref.ref(mine.pop())
+    gc.collect()
+    assert dead() is None and len([t for t in telemetry._live() if t in mine]) == 2
+    # a stop with no start (registered between a collection's two calls) is nothing
+    telemetry._on_gc("stop", {"generation": 2, "collected": 0, "uncollectable": 0})
+    telemetry._on_gc("start", {})  # nor is a malformed call
+    telemetry._collecting = None
+
+
+def _loop(t, n, sleep_s=0.004, alone=()):
+    """What ``JointTrainer`` does at each loss read, ``n`` times."""
+    for k in range(n):
+        with t.tracer.span("loss.sync", step=k, alone=int(k in alone)) as sp:
+            time.sleep(sleep_s)
+            t.observe_read(sp, alone=k in alone)
+        t.observe_step(0.001, 0.002, sp.dur_s)
+
+
+def test_intervals_between_reads_reach_the_span_the_epoch_and_the_gauge():
+    from deepdfa_tpu.obs import Tracer, TrainTelemetry
+
+    t = TrainTelemetry(tracer=Tracer(proc="train", max_spans=64))
+    t.observe_epoch(0)
+    with _no_automatic_collections():
+        _loop(t, 5, alone={2})
+        gc.collect(0)
+        _loop(t, 2)
+    syncs = [s for s in t.tracer.spans() if s.name == "loss.sync"]
+    # absent on the first read and on the one after the lone read; the mark
+    # outlives whatever else the host does (here a collection) until a lone
+    # read or a new epoch
+    assert ["interval_s" in s.attrs for s in syncs] == [False, True, True, False, True, True, True]
+    for s in syncs:
+        assert ("gc_s" in s.attrs) == ("gc_n" in s.attrs) == ("interval_s" in s.attrs)
+    assert [s.attrs["gc_n"] for s in syncs if "gc_n" in s.attrs] == [0, 0, 0, 1, 0]
+    assert syncs[5].attrs["gc_s"] > 0 == syncs[6].attrs["gc_s"]
+    assert all(s.attrs["interval_s"] >= 0.004 for s in syncs if "interval_s" in s.attrs)
+    last = syncs[-1].attrs["interval_s"]
+    assert f"deepdfa_train_last_step_seconds {round(last, 6)}" in t.render().splitlines()
+    stats = t.epoch_stats()
+    assert stats["steps"] == 7 and stats["stalls"] == 0 and stats["gc_n"] == 1
+    assert 4.0 <= stats["interval_p50_ms"] <= stats["interval_max_ms"]
+    assert stats["interval_max_ms"] == round(1e3 * max(
+        s.attrs["interval_s"] for s in syncs if "interval_s" in s.attrs), 4)
+    assert "interval_p50_ms" not in t.epoch_stats()  # the window was taken
+    # a new epoch's first read ends no interval
+    t.observe_epoch(1)
+    _loop(t, 1)
+    assert "interval_s" not in t.tracer.spans()[-1].attrs
+    # a loop that reads no loss: the gauge is the host's time of its last step
+    t.observe_step(0.25, 0.5)
+    assert "deepdfa_train_last_step_seconds 0.75" in t.render().splitlines()
+
+
+def _ring(step_s, n=40, stalls=()):
+    """A hand-made ring of ``n`` steps of ``step_s``: ``data.wait`` 1 ms, a
+    ``step.dispatch`` of 10 ms (8 on the CPU), then ``loss.sync`` to the end
+    of the step. ``stalls`` = ``{step: (extra seconds, span name or None)}``:
+    the extra time sits in the read, under a span of that name if given."""
+    from deepdfa_tpu.obs import Tracer
+
+    tracer = Tracer(proc="train", max_spans=4096)
+    root = tracer.record("train.epoch", 1000.0, 1000.0, root=True, epoch=0)
+    at = 1000.0
+    for k in range(n):
+        extra, name = dict(stalls).get(k, (0.0, None))
+        tracer.record("data.wait", at, at + 0.001, parent=root.ctx, step=k)
+        tracer.record("step.dispatch", at + 0.001, at + 0.011, parent=root.ctx,
+                      cpu_s=0.008, step=k)
+        end = at + step_s + extra
+        attrs = {"interval_s": end - at, "gc_s": 0.0, "gc_n": 0} if k else {}
+        sync = tracer.record("loss.sync", at + 0.011, end, parent=root.ctx,
+                             cpu_s=0.0002, step=k, reads=1, alone=0, **attrs)
+        if name == "gc.pause":
+            tracer.record(name, at + 0.02, at + 0.02 + extra, parent=sync.ctx,
+                          cpu_s=extra, generation=2, collected=9, step=k)
+        elif name is not None:
+            tracer.record(name, at + 0.02, at + 0.02 + extra, parent=sync.ctx, step=k)
+        at = end
+    return tracer
+
+
+def test_the_summariser_tells_a_uniformly_slow_run_from_stalls_and_names_their_causes():
+    from deepdfa_tpu.obs import interval_stats, step_cadence
+
+    assert interval_stats([]) == {"steps": 0} == step_cadence([])
+    healthy = step_cadence(_ring(0.100).spans())
+    slow = step_cadence(_ring(0.112).spans())
+    for run in (healthy, slow):
+        assert run["steps"] == 39 and run["stalls"] == 0 and run["stall_share"] == 0.0
+        assert run["causes"] == {} and run["worst"] == []
+    # every step longer: the median is what differs
+    assert healthy["interval_p50_ms"] == pytest.approx(100.0, abs=0.01)
+    assert slow["interval_p50_ms"] == pytest.approx(112.0, abs=0.01)
+    stalled = step_cadence(_ring(0.100, stalls={
+        7: (0.120, "gc.pause"), 19: (0.300, "jit.backend_compile"), 31: (0.080, None)}).spans())
+    assert stalled["interval_p50_ms"] == pytest.approx(100.0, abs=0.01)
+    assert stalled["interval_max_ms"] == pytest.approx(400.0, abs=0.01)
+    assert stalled["stalls"] == 3
+    assert stalled["stall_share"] == pytest.approx(100 * 0.5 / (39 * 0.1 + 0.5), abs=0.01)
+    assert [(s["step"], s["cause"]) for s in stalled["worst"]] == [
+        (19, "jit"), (7, "gc.pause"), (31, "device")]
+    assert [s["excess_ms"] for s in stalled["worst"]] == pytest.approx([300, 120, 80], abs=0.01)
+    assert stalled["causes"] == {
+        "jit": {"n": 1, "excess_ms": pytest.approx(300, abs=0.01)},
+        "gc.pause": {"n": 1, "excess_ms": pytest.approx(120, abs=0.01)},
+        "device": {"n": 1, "excess_ms": pytest.approx(80, abs=0.01)}}
+    # the median, less what the stalls took, is the rate
+    mean_ms = (39 * 100 + 500) / 39
+    assert stalled["interval_p50_ms"] / (1 - stalled["stall_share"] / 100) == pytest.approx(
+        mean_ms, rel=1e-3)
+
+
+def test_the_summariser_splits_a_long_call_by_its_cpu_clock_and_reads_an_exemplar():
+    from deepdfa_tpu.obs import step_cadence
+
+    def ring(cpu_s):
+        tracer = _ring(0.050, n=20)
+        spans = tracer.spans()
+        # step 12's call took 90 ms more, with the producer's H2D beside it
+        call = next(s for s in spans if s.name == "step.dispatch" and s.attrs["step"] == 12)
+        for s in spans:
+            if s.start_s > call.start_s:
+                s.start_s += 0.090
+        call.dur_s += 0.090
+        call.cpu_s = cpu_s
+        sync = next(s for s in spans if s.name == "loss.sync" and s.attrs["step"] == 12)
+        sync.attrs["interval_s"] += 0.090
+        tracer.record("batch.h2d", call.start_s + 0.01, call.start_s + 0.08, cpu_s=0.002)
+        return tracer.spans()
+
+    (blocked,) = step_cadence(ring(0.008))["worst"]
+    assert blocked["step"] == 12 and blocked["cause"] == "step.dispatch blocked"
+    assert blocked["excess_ms"] == pytest.approx(90, abs=0.01)
+    assert blocked["producer"] == [
+        {"name": "batch.h2d", "overlap_ms": pytest.approx(70, abs=0.01), "cpu_ms": 2.0}]
+    (working,) = step_cadence(ring(0.098))["worst"]
+    assert working["cause"] == "step.dispatch on-CPU" and "producer" not in working
+    # the loop's thread held up between two of its spans: no span's seconds grew
+    spans = _ring(0.050, n=20).spans()
+    for s in spans:
+        if s.attrs.get("step", 0) > 12 or (s.attrs.get("step") == 12 and s.name != "data.wait"):
+            s.start_s += 0.090  # after step 12's data.wait, before its call
+    next(s for s in spans if s.name == "loss.sync"
+         and s.attrs["step"] == 12).attrs["interval_s"] += 0.090
+    (held,) = step_cadence(spans)["worst"]
+    assert held["step"] == 12 and held["cause"] == "no span"
+    assert held["cause_ms"] == pytest.approx(90, abs=0.01)
+    # spans without the clock (an older run's exemplar): the call, undivided
+    (older,) = step_cadence(ring(None))["worst"]
+    assert older["cause"] == "step.dispatch"
+    # a journaled exemplar's records read the same as the ring they were made from
+    spans = ring(0.008)
+    assert step_cadence([s.to_record() for s in spans]) == step_cadence(spans)
 
 
 # ---------------------------------------------------------------------------
