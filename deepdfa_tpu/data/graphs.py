@@ -32,6 +32,8 @@ import numpy as np
 __all__ = [
     "Graph",
     "BatchedGraphs",
+    "compact_view",
+    "view_fits",
     "batch_np",
     "GraphBatcher",
     "BucketSpec",
@@ -110,6 +112,34 @@ class BatchedGraphs(NamedTuple):
     @property
     def max_graphs(self) -> int:
         return self.graph_mask.shape[0]
+
+
+def view_fits(batch: BatchedGraphs, n: int, e: int):
+    """Whether the first ``n`` nodes and ``e`` edges of ``batch`` hold all its
+    real ones with node ``n - 1`` left a pad (the view's sink). Numpy or traced
+    masks alike: a scalar bool of the masks' own kind."""
+    return (batch.node_mask.sum() <= n - 1) & (batch.edge_mask.sum() <= e)
+
+
+def compact_view(batch: BatchedGraphs, n: int, e: int) -> BatchedGraphs:
+    """The first ``n`` nodes and ``e`` edges of a ``batch_np`` batch as a batch
+    of their own, valid wherever :func:`view_fits` holds: ``batch_np`` lays
+    real nodes and real edges first, so the prefix keeps them all, and the pad
+    edges it keeps, which pointed at the sink node ``max_nodes - 1``, are
+    clamped onto the view's sink ``n - 1`` (a pad, above every real receiver,
+    so the edges stay sorted by receiver). ``graph_mask`` is whole: the view
+    pools into the same ``max_graphs`` slots. Slices and one clamp, on numpy
+    arrays and inside a jitted function alike."""
+    sink = n - 1
+    return BatchedGraphs(
+        node_feats={k: v[:n] for k, v in batch.node_feats.items()},
+        senders=batch.senders[:e].clip(max=sink),
+        receivers=batch.receivers[:e].clip(max=sink),
+        node_gidx=batch.node_gidx[:n],
+        node_mask=batch.node_mask[:n],
+        edge_mask=batch.edge_mask[:e],
+        graph_mask=batch.graph_mask,
+    )
 
 
 def batch_np(

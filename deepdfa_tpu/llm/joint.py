@@ -220,18 +220,25 @@ def make_joint_steps(
     tx: optax.GradientTransformation,
     train_llm: bool = False,
     on_stats: Callable[[Any], None] | None = None,
+    freeze_gnn: bool = False,
 ) -> tuple[Callable, Callable]:
     """(train_step, eval_step), both jitted. ``llm_params`` is an input, not a
     capture, so sharded placements propagate and the tree is donated-free.
     The encoder is whatever maps ``(input_ids, pad_mask)`` to hidden states;
-    counts it sows into a ``stats`` collection leave the jitted step as a
-    fourth output and are handed, unread, to ``on_stats`` after each launch.
+    counts it or the fusion model sow into a ``stats`` collection leave the
+    jitted step as a fourth output and are handed, unread, to ``on_stats``
+    after each launch.
 
     ``train_llm=False`` (MSIVD): the LLM forward runs on the constant
     ``llm_params`` input with no backward built through the stack.
     ``train_llm=True`` (LineVul-combined): the trained tree is
     ``{"fusion": ..., "llm": ...}`` and gradients flow through the encoder;
-    the ``llm_params`` step argument is ignored (pass ``None``)."""
+    the ``llm_params`` step argument is ignored (pass ``None``).
+
+    ``freeze_gnn`` (``JointConfig.freeze_gnn``: ``tx`` zeroes the GGNN's
+    updates): the ``flowgnn_encoder`` leaves are held out of the backward, so
+    none is traced through the GGNN; their gradients are the zeros that
+    ``tx`` would have made of them."""
 
     def hidden_states(llm_params, batch: JoinedBatch, dropout_rng=None):
         ids = jnp.asarray(batch.text.input_ids)
@@ -261,23 +268,29 @@ def make_joint_steps(
             fusion_params, llm_params = params["fusion"], params["llm"]
         else:
             fusion_params = params
+        if freeze_gnn and fusion.use_gnn:
+            fusion_params = {**fusion_params, "flowgnn_encoder": jax.lax.stop_gradient(
+                fusion_params["flowgnn_encoder"])}
         rng, enc_rng = jax.random.split(rng)
         hidden, stats = hidden_states(
             llm_params, batch, dropout_rng=enc_rng if train_llm else None
         )
-        logits = fusion.apply(
+        # the fusion model sows its own counts (the GGNN's view of the graph
+        # budget) into the same collection; without a GGNN, nothing: {}
+        logits, sown = fusion.apply(
             {"params": fusion_params},
             hidden,
             batch.graphs if fusion.use_gnn else None,
             deterministic=False,
             token_mask=jnp.asarray(batch.text.pad_mask),
             rngs={"dropout": rng},
+            mutable=["stats"],
         )
         labels = jnp.asarray(batch.text.labels)
         mask = jnp.asarray(batch.mask)
         with jax.named_scope("loss"):
             loss, probs = fusion_loss(logits, labels, mask)
-        return loss, (probs, stats)
+        return loss, (probs, {**stats, **sown.get("stats", {})})
 
     def train_step(state: JointState, llm_params, batch: JoinedBatch):
         rng, sub = jax.random.split(state.rng)
@@ -429,7 +442,7 @@ class JointTrainer:
         self.tx = joint_optimizer(self.cfg, steps_per_epoch, params)
         self._steps = make_joint_steps(
             self.llm, self.fusion, self.tx, train_llm=self.cfg.train_llm,
-            on_stats=self._launched.append,
+            on_stats=self._launched.append, freeze_gnn=self.cfg.freeze_gnn,
         )
         if not fresh:
             return None

@@ -217,9 +217,13 @@ def test_the_step_says_on_loss_sync_which_attention_it_ran(decoder, monkeypatch,
 # sha256 of ``jit(train_step).lower(...).as_text()`` on the commit before this
 # kernel (PR 31, jax 0.9.0), made by this very function there. A PR that means
 # to change the CodeBERT step replaces them; one that does not must not.
+# With the GGNN, moved on purpose by PR 38 (from 79460ed0...): the step hands
+# out the counts of the GGNN's view of the graph budget (this budget, 512
+# nodes, is under the size that gets views: the GGNN itself runs as it did).
+# Without the GGNN the step is still PR 31's.
 CODEBERT_STEPS = {
     False: "94d3cfb911c01bfc9929d4e361f729bda8844c18e772a057805410f8d36eb517",
-    True: "79460ed026f98f17e3999a4e8b2324a758fca97f702c1ce8bd631a441c6ba0e5",
+    True: "696b9d8ee21ed10c5ab96b693d469221ce16c26c2772a3196b8005f7b6d89468",
 }
 
 

@@ -469,7 +469,7 @@ def test_encoder_flag_alone_builds_the_hermetic_decoder():
 # held-experts path serve two decoders, so a change to ``longcat.py`` or ``ops/grouped.py``
 # that is meant for one of them must keep LongCat's program byte for byte. A PR that means
 # to change it replaces this.
-LONGCAT_STEP = "fa1ad5486a43022df734fa5f0f32d2f29d20a246ca50ef68c1983c903ec83e1f"
+LONGCAT_STEP = "e4f8555bd8577c19ff42123803abe6da0aa60db0aca1197588fb7a67754f5a48"
 
 
 def _lowered_longcat_step() -> str:
@@ -507,6 +507,8 @@ def test_the_longcat_step_is_lowered_as_before():
     """Moved on purpose by PR 34 (from 0683cd6e..., PR 32's program, which PR 33 kept):
     ``held_expert_ffn`` puts a chunk's rows back onto their tokens by a one-hot product
     in place of the scatter-add, and the ``moe`` stats carry one count more
-    (``combined``) — both decoders' programs change with it."""
+    (``combined``) — both decoders' programs change with it. And by PR 38 (from
+    fa1ad548...), which touches no decoder: the counts of the GGNN's view of the graph
+    budget leave the step beside the ``moe`` stats."""
     text = _lowered_longcat_step()
     assert hashlib.sha256(text.encode()).hexdigest() == LONGCAT_STEP
