@@ -101,6 +101,10 @@ FAMILIES: dict[str, EncoderFamily] = {
         # frozen; no converter
         EncoderFamily("jamba", "JambaConfig", "JambaModel", "tiny_jamba", pool="last", trained=False,
                       from_seed=_sparse_from_seed),
+        # causal, grouped-query attention global without RoPE or windowed with it by layer, the router read
+        # before attention, ReLU-gated routed experts and nothing beside them, frozen; no converter
+        EncoderFamily("smallthinker", "SmallThinkerConfig", "SmallThinkerModel", "tiny_smallthinker", pool="last",
+                      trained=False, from_seed=_sparse_from_seed),
     ]
 }
 

@@ -111,6 +111,13 @@ class HeldRange:
     def held(self) -> tuple[int, int]:
         return self.experts_held or (0, self.n_routed_experts)
 
+    @property
+    def holds_every_expert(self) -> bool:
+        """No choice of the router's is absent or zero-compute: the held range
+        is its whole width, so a real token's k assignments are all here (the
+        case ``held_expert_ffn`` combines by a gather)."""
+        return self.held == (0, getattr(self, "router_width", self.n_routed_experts))
+
     @classmethod
     def from_hf_dict(cls, d: dict):
         names = {f.name for f in dataclasses.fields(cls)}
@@ -304,11 +311,12 @@ def mask_pads(choice, gates, token_mask):
     return jnp.where(real, choice, -1), jnp.where(real, gates, 0.0)
 
 
-def held_experts(layer: nn.Module, x, choice, gates, width: int):
+def held_experts(layer: nn.Module, x, choice, gates, width: int, activation=jax.nn.silu):
     """``(out [t, d] float32, computed)``: the part of an expert layer's
     result that the experts held here (``layer.cfg.held``) give for the
     tokens ``x``. The held experts' weights, ``width`` wide, are ``layer``'s
-    own parameters (called from its compact ``__call__``)."""
+    own parameters (called from its compact ``__call__``); ``activation`` is
+    their gate's."""
     cfg = layer.cfg
     dtype = jnp.dtype(cfg.dtype)
     lo, hi = cfg.held
@@ -320,17 +328,23 @@ def held_experts(layer: nn.Module, x, choice, gates, width: int):
     w_gate = expert("experts_gate", (n, d, width), ("experts", "embed", "expert_mlp"))
     w_up = expert("experts_up", (n, d, width), ("experts", "embed", "expert_mlp"))
     w_down = expert("experts_down", (n, width, d), ("experts", "expert_mlp", "embed"))
+    # only what departs from ``held_expert_ffn``'s defaults is named: the plantings of
+    # ``benchmark/tools/prove_frozen*.py`` wrap it by the two keywords it has always had
+    other = {"activation": activation} if activation is not jax.nn.silu else {}
+    if cfg.holds_every_expert:
+        other["whole"] = True
     with jax.named_scope("held_experts"):
         return held_expert_ffn(
-            x, choice, gates, w_gate, w_up, w_down, lo=lo, rows=cfg.moe_chunk_rows)
+            x, choice, gates, w_gate, w_up, w_down, lo=lo, rows=cfg.moe_chunk_rows, **other)
 
 
 def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=None) -> dict:
     """Sow this layer's choices ([b, s, k]; -1: a pad) into ``routing`` and
     return its assignments by where they went: ``load_max`` the fullest held
-    expert's; ``combined`` the sorted positions the combine of
+    expert's; ``combined`` the sorted positions the one-hot combine of
     ``held_expert_ffn`` visited for them (``ops/grouped.py``; ``held`` over it
-    is how full its blocks were); ``slots`` and ``layers`` make means of sums.
+    is how full its blocks were; 0 where the gather combines them);
+    ``slots`` and ``layers`` make means of sums.
     ``zero`` marks the choices that went to zero-compute experts (none where a
     router has none)."""
     lo, hi = layer.cfg.held
@@ -348,7 +362,8 @@ def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=N
         "absent": jnp.sum((choice >= 0) & ~held & ~zero, dtype=jnp.int32),
         "load_max": jnp.max(load),
         "dropped": n_held - computed,
-        "combined": combined_positions(n_held, layer.cfg.moe_chunk_rows),
+        "combined": (jnp.int32(0) if layer.cfg.holds_every_expert
+                     else combined_positions(n_held, layer.cfg.moe_chunk_rows)),
         "slots": jnp.int32(n),
         "layers": jnp.int32(1),
     }

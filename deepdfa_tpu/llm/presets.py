@@ -7,8 +7,11 @@ DeepDFA+LineVul combined, ``encoder_family="roberta"``), and the MSIVD job
 with a latent-attention routed-expert decoder frozen in the LLM's place, of
 either sparse family (``encoder_family="longcat"`` and ``"pangu_moe"``: one
 chip's share of an expert-parallel deployment each, and their test-size
-twins) or a hybrid state-space decoder whole (``"jamba"``, and its twin).
-What a family is lives in ``llm/families.py`` (five today; a further
+twins), a hybrid state-space decoder whole (``"jamba"``, and its twin), or
+a grouped-query decoder of global and windowed layers that holds every one of
+its routed experts (``"smallthinker"``: SmallThinker-21BA3B, 12 of its 52
+layers, on 8k-token blocks; and its twin).
+What a family is lives in ``llm/families.py`` (six today; a further
 one is one row there and one model file); a preset's
 ``llm`` must be its family's config class, checked at construction.
 ``finetuned`` marks presets that start from a LoRA-finetuned model
@@ -33,6 +36,7 @@ from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
 from deepdfa_tpu.llm.longcat import LongcatConfig, longcat_flash, tiny_longcat
 from deepdfa_tpu.llm.pangu_moe import PanguMoeConfig, openpangu_ultra_moe, tiny_pangu_moe
 from deepdfa_tpu.llm.roberta import RobertaConfig, codebert_base
+from deepdfa_tpu.llm.smallthinker import SmallThinkerConfig, smallthinker_21b, tiny_smallthinker
 
 __all__ = ["JointPreset", "PRESETS"]
 
@@ -40,7 +44,8 @@ __all__ = ["JointPreset", "PRESETS"]
 @dataclasses.dataclass(frozen=True)
 class JointPreset:
     name: str
-    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig  # encoder_family's class
+    # encoder_family's class
+    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig | SmallThinkerConfig
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
@@ -48,7 +53,9 @@ class JointPreset:
     # which encoder stack drives the fusion head: "llama" (causal, MSIVD),
     # "roberta" (bidirectional CodeBERT — the LineVul configs), "longcat" or
     # "pangu_moe" (causal, latent attention + routed experts, frozen), "jamba"
-    # (causal, selective-scan layers + multi-query attention, frozen)
+    # (causal, selective-scan layers + multi-query attention, frozen),
+    # "smallthinker" (causal, global / windowed grouped-query attention +
+    # routed experts all held, frozen)
     encoder_family: str = "llama"
 
     def __post_init__(self):
@@ -244,6 +251,38 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="jamba",
+        ),
+        # the same job on 8k-token inputs (a function with its callers' and
+        # callees' bodies and the explanation) over SmallThinker-21BA3B-Instruct
+        # at its published widths: three periods of its layer pattern (a global
+        # layer without RoPE, then three with the 4096-token window and RoPE)
+        # as one pipeline stage, ALL 64 routed experts of each layer on this
+        # chip (no layer is divided), the whole vocabulary
+        JointPreset(
+            name="smallthinker_21b_msivd",
+            llm=smallthinker_21b(num_hidden_layers=12, experts_held=(0, 64)),
+            joint=JointConfig(
+                block_size=8192, epochs=1, train_batch_size=2, eval_batch_size=2,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="smallthinker",
+        ),
+        # the same code at test size (CPU): 8 layers, global at 0 and 4, a
+        # window of 24 in a block of 64, all 8 experts held
+        JointPreset(
+            name="tiny_smallthinker_msivd",
+            llm=tiny_smallthinker(vocab_size=2048),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="smallthinker",
         ),
     ]
 }

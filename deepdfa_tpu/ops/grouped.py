@@ -30,7 +30,15 @@ Mechanism — ``ops/segment.py``'s problem on the MXU:
    float32 sum of float32 expert rows. A trip walks its chunk in blocks and
    stops after the last block that holds an assignment
    (:func:`combine_blocks`), so the work follows the assignments held, not
-   ``rows``.
+   ``rows``. **Where the held range is the router's whole width** (``whole``:
+   no expert is absent, so every real token has exactly its ``k`` rows among
+   the held) that product would be ``[t, 3 x 512] x [3 x 512, d]`` a block of
+   512 of ``t x k`` positions — quadratic in the tokens (10.7 TFLOP a layer at
+   16,384 tokens of width 2560, against the 1.4 the experts need; PERF.md
+   section 7) — and the sort's inverse says where each token's rows lie: the
+   trips write their rows into one ``[t x k, d]`` float32 buffer by sorted
+   position and a token's result is the sum of the ``k`` rows gathered from
+   it, float32 sums of float32 rows still, no product and no scatter.
 
 The count of rows handed to the grouped products (the sum of the group sizes
 each call was given: rows outside a group are not computed) is returned
@@ -48,12 +56,28 @@ __all__ = ["combine_blocks", "combined_positions", "grouped_matmul", "held_exper
 
 # megablox tiles (m, k, n) for one v5e core: [512, 1024] and [1024, 1024]
 # bf16 operand tiles double-buffered plus a [512, 1024] f32 accumulator stay
-# well inside the 16 MiB of scoped VMEM
+# well inside the 16 MiB of scoped VMEM. The k and n of a call are cut by
+# :func:`_tile`, at most this wide
 _GMM_TILING = (512, 1024, 1024)
 
 # sorted positions a block of the combine takes: [t, 3 x 512] one-hot columns
 # against [3 x 512, d] addends, one pass over ``out`` a block
 _COMBINE_BLOCK = 512
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The tile a dimension of ``dim`` is cut into, at most ``cap`` wide:
+    ``cap`` itself while its partly filled last tile leaves no more than a
+    tenth of the tiles' width empty (6144, 2048: none; 7680: 7.5 tiles, a
+    sixteenth), else the widest multiple of 128 that divides ``dim`` (2560:
+    2.5 tiles of 1024 would leave a sixth empty and mask every last tile of
+    the reduction: 640, four whole tiles)."""
+    if dim <= cap:
+        return dim
+    tiles = -(-dim // cap)
+    if 10 * (tiles * cap - dim) <= tiles * cap:
+        return cap
+    return max((t for t in range(128, cap + 1, 128) if dim % t == 0), default=cap)
 
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> jnp.ndarray:
@@ -69,7 +93,7 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray) -> 
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         return gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
-                   tiling=(tm, min(tk, k), min(tn, n)))
+                   tiling=(tm, _tile(k, tk), _tile(n, tn)))
     return lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
 
 
@@ -113,13 +137,19 @@ def held_expert_ffn(
     *,
     lo: int,
     rows: int,
+    activation=jax.nn.silu,
+    whole: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``sum over (choice in held) of gate * W_down(silu(W_gate u) * (W_up u))``.
+    """``sum over (choice in held) of gate * W_down(act(W_gate u) * (W_up u))``.
 
     u: [t, d] tokens; choice: [t, k] global expert ids (anything outside
     ``[lo, lo + n)`` is not this chip's: absent, zero-compute, or -1 for a pad
     token); gates: [t, k] float32; w_gate / w_up: [n, d, f]; w_down:
-    [n, f, d]; ``rows``: assignments consumed per loop iteration.
+    [n, f, d]; ``rows``: assignments consumed per loop iteration;
+    ``activation``: the gate's, float32 in and out; ``whole``: the caller's
+    word that ``[lo, lo + n)`` is everything the router can choose (every
+    ``choice >= 0`` is held), which picks the gather for the combine (module
+    docstring, step 4).
 
     Returns ``(out [t, d] float32, computed)``: ``computed`` sums the group
     sizes the grouped products were given, trip by trip — the rows they
@@ -141,8 +171,10 @@ def held_expert_ffn(
     gate = gates.reshape(a)[order]
     offs = jnp.arange(rows, dtype=jnp.int32)
 
-    def chunk(carry):
-        start, out, computed = carry
+    def trip(start):
+        """The sorted positions ``[start, start + rows)``: their tokens, their
+        gate-weighted rows (zero where a position holds no assignment) and the
+        group sizes the products were given."""
         pos = start + offs
         valid = pos < n_held
         pos = jnp.minimum(pos, a - 1)
@@ -150,9 +182,13 @@ def held_expert_ffn(
         x = u[tok]
         # the part of each expert's run of rows that falls inside this chunk
         sz = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - sizes - start, 0, rows)
-        h = jax.nn.silu(grouped_matmul(x, w_gate, sz)) * grouped_matmul(x, w_up, sz)
+        h = activation(grouped_matmul(x, w_gate, sz)) * grouped_matmul(x, w_up, sz)
         y = grouped_matmul(h.astype(u.dtype), w_down, sz)
-        y = jnp.where(valid[:, None], y * gate[pos][:, None], 0.0)
+        return tok, jnp.where(valid[:, None], y * gate[pos][:, None], 0.0), sz
+
+    def chunk(carry):
+        start, out, computed = carry
+        tok, y, sz = trip(start)
         # the rows back onto their tokens, block by block as far as the chunk
         # holds assignments: float32 sums by a product the MXU runs, where a
         # scatter-add costs the TPU the same whatever the chunk holds (module
@@ -164,7 +200,27 @@ def held_expert_ffn(
                 lax.dynamic_slice_in_dim(y, j * width, width)), out)
         return start + rows, out, computed + jnp.sum(sz, dtype=jnp.int32)
 
-    _, out, computed = lax.while_loop(
-        lambda c: c[0] < n_held, chunk,
-        (jnp.zeros((), jnp.int32), jnp.zeros((t, d), jnp.float32), jnp.zeros((), jnp.int32)))
+    def store(carry):
+        start, buf, computed = carry
+        _, y, sz = trip(start)
+        return (start + rows, lax.dynamic_update_slice_in_dim(buf, y, start, 0),
+                computed + jnp.sum(sz, dtype=jnp.int32))
+
+    zero = jnp.zeros((), jnp.int32)
+    if not whole:
+        _, out, computed = lax.while_loop(
+            lambda c: c[0] < n_held, chunk, (zero, jnp.zeros((t, d), jnp.float32), zero))
+        return out, computed
+    # every real token's k rows are among the held: the trips leave their rows
+    # by sorted position (whole trips: the buffer is ``rows`` longer than the
+    # assignments at most; what no trip reaches stays zero, and a pad's
+    # choices sort there), and each token sums its own k
+    _, buf, computed = lax.while_loop(
+        lambda c: c[0] < n_held, store,
+        (zero, jnp.zeros((-(-a // rows) * rows, d), jnp.float32), zero))
+    with jax.named_scope("combine"):
+        at = jnp.argsort(order).astype(jnp.int32).reshape(t, k)  # the sort's inverse
+        out = buf[at[:, 0]]
+        for j in range(1, k):
+            out = out + buf[at[:, j]]
     return out, computed

@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 __all__ = [
     "full_attention",
     "blocked_causal_attention",
+    "blocked_key_ranges",
     "ring_attention",
     "ring_attention_sharded",
 ]
@@ -57,11 +58,14 @@ def full_attention(
     kv_mask: jnp.ndarray | None = None,
     q_positions: jnp.ndarray | None = None,
     kv_positions: jnp.ndarray | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Plain softmax attention, fp32 accumulation.
 
     q: [b, sq, h, d]; k/v: [b, sk, h_kv, d]; kv_mask: [b, sk] (True = attend).
     Positions default to ``arange`` and only matter for causal masking.
+    ``window`` (causal only): key ``j`` is visible to query ``t`` iff
+    ``t - window < j <= t`` — the query itself and the ``window - 1`` before it.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -75,6 +79,8 @@ def full_attention(
         qpos = jnp.arange(sq) if q_positions is None else q_positions
         kpos = jnp.arange(sk) if kv_positions is None else kv_positions
         causal_mask = kpos[None, :] <= qpos[:, None]  # [sq, sk]
+        if window is not None:
+            causal_mask &= kpos[None, :] > qpos[:, None] - window
         scores = jnp.where(causal_mask[None, None], scores, _NEG_INF)
     if kv_mask is not None:
         scores = jnp.where(kv_mask[:, None, None, :], scores, _NEG_INF)
@@ -95,24 +101,36 @@ def blocked_causal_attention(
     *,
     kv_mask: jnp.ndarray | None = None,
     block_q: int = 256,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Causal self-attention in blocks over the queries: block ``i`` attends
     to keys ``[0, end of block i)`` only, so the scores are never whole
     (``[b, h, block_q, keys]`` at a time) and the blocks above the diagonal
-    are never computed. The query/key width may differ from the value width
-    (latent attention: 192 against 128); the scale is the query width's.
+    are never computed; with a ``window`` (:func:`full_attention`'s) a block
+    reads from its first query's oldest visible key on, never the whole
+    prefix (:func:`blocked_key_ranges`). The query/key width may differ from
+    the value width (latent attention: 192 against 128); the scale is the
+    query width's.
 
     q: [b, s, h, dk]; k: [b, s, h_kv, dk]; v: [b, s, h_kv, dv]; kv_mask:
     [b, s] (True = attend). Returns [b, s, h, dv]."""
-    s = q.shape[1]
     outs = []
-    for start in range(0, s, block_q):
-        end = min(start + block_q, s)
+    for start, end, lo in blocked_key_ranges(q.shape[1], block_q, window):
         outs.append(full_attention(
-            q[:, start:end], k[:, :end], v[:, :end], causal=True,
-            kv_mask=None if kv_mask is None else kv_mask[:, :end],
-            q_positions=jnp.arange(start, end)))
+            q[:, start:end], k[:, lo:end], v[:, lo:end], causal=True,
+            kv_mask=None if kv_mask is None else kv_mask[:, lo:end],
+            q_positions=jnp.arange(start, end),
+            kv_positions=jnp.arange(lo, end) if lo else None, window=window))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def blocked_key_ranges(s: int, block_q: int, window: int | None = None):
+    """``(start, end, lo)`` of every block of :func:`blocked_causal_attention`
+    over ``s`` positions: queries ``[start, end)`` read keys ``[lo, end)``, so
+    ``sum((end - start) * (end - lo))`` is the query-key pairs it multiplies."""
+    for start in range(0, s, block_q):
+        yield (start, min(start + block_q, s),
+               0 if window is None else max(0, start - window + 1))
 
 
 def ring_attention(

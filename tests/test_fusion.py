@@ -376,13 +376,14 @@ def test_presets_cover_reference_launch_scripts():
     from deepdfa_tpu.llm.presets import PRESETS
 
     # 5 MSIVD launch scripts + the 2 LineVul configs of BASELINE config #3
-    # + the three frozen decoders (two routed-expert, one state-space), each with its test-size twin
+    # + the four frozen decoders (three routed-expert, one state-space), each with its test-size twin
     assert set(PRESETS) == {
         "bigvul_ft_bigvul", "pretrained_bigvul", "pb_ft_pb",
         "pb_ft_pb_noexpl", "pretrained_pb", "linevul", "linevul_fusion",
         "longcat_flash_msivd", "tiny_longcat_msivd",
         "openpangu_ultra_msivd", "tiny_pangu_moe_msivd",
         "jamba2_3b_msivd", "tiny_jamba_msivd",
+        "smallthinker_21b_msivd", "tiny_smallthinker_msivd",
     }
     p = PRESETS["bigvul_ft_bigvul"]
     assert p.llm.hidden_size == 4096 and p.joint.block_size == 256

@@ -193,3 +193,82 @@ def test_the_count_is_the_positions_the_loop_visits(monkeypatch, rows, n_held):
     # whole trips are walked whole; the last one up to its last held position
     assert sum(visited) == n_held // rows * rows + int(blocks) * width
     assert n_held <= sum(visited) < n_held + width
+
+
+# -- the activation, and the combine where nothing is absent ------------------
+
+
+def _fixed():
+    """Dyadic inputs, two hidden units: every sum in the three products is exact or of two
+    terms, so the bits do not depend on the order a backend adds in."""
+    rng = np.random.default_rng(39)
+    t, k, d, f, n = 12, 2, 4, 2, 3
+    q = lambda *shape: jnp.asarray(rng.integers(-8, 9, size=shape) / 8.0, jnp.float32)
+    u, gates = q(t, d), q(t, k)
+    choice = jnp.asarray(rng.integers(-1, 5, size=(t, k)), jnp.int32)  # held [1, 4); -1, 0, 4 not
+    return u, choice, gates, q(n, d, f), q(n, d, f), q(n, f, d)
+
+
+# ``held_expert_ffn(*_fixed(), lo=1, rows=8)`` of the commit before ``activation`` and
+# ``whole`` became arguments (PR 38's tree, jax 0.9.0, CPU), as the bytes of its float32 rows
+_BEFORE = (
+    "00000000000000000000000000000000a4bc563b9675aabd135dfcbcc142f03d4ed7d5bcade700bc0d34133c"
+    "b61a85bde0a670be8c43973efd8109bd5bdc243ceccfc13cbe0e3fbe1a91443e0ebd953c468b713c468b71bc"
+    "468b713c468bf1bb6acd013da20510bc7433ddbc6271073e00000000000000000000000000000000f48a843d"
+    "0cb7b8bdb6b3443c2c4e8cbc39f704bc4eb7883d243781bd8ef840bc00000000000000000000000000000000"
+    "551154ba071e853b528211bc7164833b")
+
+
+def test_the_default_activation_gives_the_bits_it_gave():
+    out, computed = held_expert_ffn(*_fixed(), lo=1, rows=8)
+    assert int(computed) == 10 and np.asarray(out).tobytes().hex() == _BEFORE
+    named, _ = held_expert_ffn(*_fixed(), lo=1, rows=8, activation=jax.nn.silu)
+    assert np.array_equal(out, named)
+
+
+def test_a_relu_gate_is_the_activation_it_is_given():
+    u, choice, gates, w_gate, w_up, w_down = _fixed()
+    out, computed = held_expert_ffn(u, choice, gates, w_gate, w_up, w_down, lo=1, rows=8,
+                                    activation=jax.nn.relu)
+    want = np.zeros(u.shape, np.float32)
+    for t_, row in enumerate(np.asarray(choice)):
+        for j, e in enumerate(row):
+            if 1 <= e < 4:
+                x = np.asarray(u[t_])
+                h = np.maximum(x @ np.asarray(w_gate[e - 1]), 0.0) * (x @ np.asarray(w_up[e - 1]))
+                want[t_] += float(gates[t_, j]) * (h @ np.asarray(w_down[e - 1]))
+    assert int(computed) == 10
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+    silu, _ = held_expert_ffn(u, choice, gates, w_gate, w_up, w_down, lo=1, rows=8)
+    assert not np.allclose(out, silu, atol=1e-3)
+
+
+@pytest.mark.parametrize("rows", [8, 32, 4096])
+@pytest.mark.parametrize("pads", [0, 7])
+def test_the_gather_equals_the_one_hot_combine_where_nothing_is_absent(rows, pads):
+    """Every expert of the router held: each real token has its k rows among the held and
+    ``whole`` sums them through the sort's inverse; the one-hot product over the same
+    assignments gives the same float32 sums. Pads (-1) have no row in either."""
+    rng = np.random.default_rng(rows + pads)
+    t, k, d, f, n = 40, 3, 16, 8, 4
+    u = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    choice = rng.integers(0, n, size=(t, k)).astype(np.int32)
+    choice[:pads] = -1
+    gates = jnp.asarray(rng.random((t, k)), jnp.float32)
+    w = _weights(rng, n, d, f)
+    args = (u, jnp.asarray(choice), gates, *w)
+    gathered, computed = jax.jit(lambda *a: held_expert_ffn(*a, lo=0, rows=rows, whole=True))(*args)
+    onehot, computed_1 = held_expert_ffn(*args, lo=0, rows=rows)
+    assert int(computed) == int(computed_1) == (t - pads) * k
+    assert gathered.dtype == jnp.float32 and not np.asarray(gathered[:pads]).any()
+    _same(gathered, onehot)
+    _same(gathered, scatter_add_ffn(*args, lo=0, rows=rows)[0])
+
+
+@pytest.mark.parametrize("dim,cap,tile", [
+    (768, 1024, 768), (2048, 1024, 1024), (6144, 1024, 1024), (7680, 1024, 1024),
+    (2560, 1024, 640), (2560, 512, 512), (1000, 512, 512)])
+def test_tiles_are_chosen_by_shape(dim, cap, tile):
+    """The two routed cells' widths keep the tile they had (1024 over 6144 / 2048 / 7680);
+    a 2560-deep product is cut into four whole tiles, not two and a half."""
+    assert grouped._tile(dim, cap) == tile

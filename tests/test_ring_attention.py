@@ -90,3 +90,45 @@ def test_fully_masked_rows_emit_zeros():
         assert np.isfinite(out).all()
         np.testing.assert_allclose(out[0], 0.0)
         assert np.abs(out[1]).sum() > 0  # the live example is untouched
+
+
+# -- the window ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("window,block_q", [(1, 8), (5, 8), (8, 8), (13, 4), (20, 32)])
+def test_a_window_is_the_band_of_a_masked_full_attention(window, block_q):
+    """Blocks that read only ``[start - window + 1, end)`` against the whole scores under
+    the band's mask, grouped-query heads and left padding included."""
+    from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, blocked_key_ranges
+
+    q, k, v = _qkv(s=32, h=4, h_kv=2)
+    kv_mask = jnp.asarray(np.arange(32)[None] >= np.array([[0], [9]]))
+    got = blocked_causal_attention(q, k, v, kv_mask=kv_mask, block_q=block_q, window=window)
+    t = np.arange(32)
+    band = (t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window)
+    rep = lambda x: jnp.repeat(x, 2, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, rep(k)) * 8 ** -0.5
+    ok = band[None, None] & np.asarray(kv_mask)[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(ok, scores, -1e30), -1) * ok.any(-1, keepdims=True)
+    want = jnp.einsum("bhqk,bkhd->bqhd", probs, rep(v))
+    m = np.asarray(kv_mask)
+    np.testing.assert_allclose(np.asarray(got)[m], np.asarray(want)[m], atol=1e-5)
+    whole = full_attention(q, k, v, causal=True, kv_mask=kv_mask, window=window)
+    np.testing.assert_allclose(np.asarray(whole)[m], np.asarray(want)[m], atol=1e-5)
+    # no block reads a key its first query cannot see, nor the whole prefix past the window
+    for start, end, lo in blocked_key_ranges(32, block_q, window):
+        assert lo == max(0, start - window + 1) and end - lo <= window - 1 + block_q
+
+
+@pytest.mark.parametrize("window", [32, 33, 4096])
+def test_a_window_as_long_as_the_sequence_is_no_window(window):
+    from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, blocked_key_ranges
+
+    q, k, v = _qkv(s=32, h=4, h_kv=1)
+    kv_mask = jnp.asarray(np.arange(32)[None] >= np.array([[3], [0]]))
+    none = blocked_causal_attention(q, k, v, kv_mask=kv_mask, block_q=8)
+    got = blocked_causal_attention(q, k, v, kv_mask=kv_mask, block_q=8, window=window)
+    assert np.array_equal(np.asarray(none), np.asarray(got))
+    assert list(blocked_key_ranges(32, 8, window)) == list(blocked_key_ranges(32, 8))
+    shorter = blocked_causal_attention(q, k, v, kv_mask=kv_mask, block_q=8, window=31)
+    assert not np.array_equal(np.asarray(none)[:, -1], np.asarray(shorter)[:, -1])

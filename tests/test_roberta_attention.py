@@ -354,3 +354,22 @@ def test_the_selective_scan_kernel_compiles_for_the_v5e_at_the_decoders_size(one
     text = compiled.as_text()
     assert "selective_scan_fwd" in text and " while(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_the_grouped_query_attention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
+    """Here for the fixture's sake too: ``ops/gqa_attention`` at
+    [2, 8192, 28 | 4 x 128] bfloat16, global and with the 4096-token window
+    (tiling, the resident row of keys and values in VMEM, the three loops with
+    bounds from SMEM), and nothing near a query block's 0.94 GB of float32
+    scores among the temporaries."""
+    from deepdfa_tpu.ops.gqa_attention import gqa_attention
+
+    b, s, h, hk = 2, 8192, 28, 4
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    for window in (None, 4096):
+        compiled = jax.jit(functools.partial(gqa_attention, num_kv_heads=hk, window=window)).trace(
+            shape(b, s, h * 128), shape(b, s, hk * 128), shape(b, s, hk * 128),
+            shape(b, s, dtype=jnp.bool_),
+        ).lower(lowering_platforms=("tpu",)).compile()
+        assert "gqa_attention_fwd" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
