@@ -48,7 +48,9 @@ experts and none is built.
 
 ``stats`` (read by the joint trainer where it reads the loss): ``moe`` — the
 routing counts ``longcat.sow_and_count`` gives, summed over layers, plus
-``gathered``, the held assignments the gather combined — and ``attn`` —
+``gathered``, the held assignments the gather combined, and ``gather_slots``,
+the token rows that gather visited times ``k`` (``ops/grouped.gather_slots``:
+``assigned`` over it is how full the visited chunks were) — and ``attn`` —
 ``layers``, ``window_layers``, ``fused`` (the layers whose attention ran the
 kernel: all or none), ``pairs_needed`` (a head's real query-key pairs inside
 causal and band, from the pad mask) and ``pairs_computed`` (those the path
@@ -78,6 +80,7 @@ from deepdfa_tpu.llm.longcat import (
     mask_pads,
     sow_and_count,
 )
+from deepdfa_tpu.ops.grouped import gather_slots
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, blocked_key_ranges
 
 __all__ = ["SmallThinkerConfig", "SmallThinkerModel", "smallthinker_21b", "tiny_smallthinker",
@@ -274,6 +277,7 @@ class ExpertLayer(nn.Module):
             activation=jax.nn.relu)
         counts = sow_and_count(self, choice, computed, (b, s))
         counts["gathered"] = counts["held"] * cfg.holds_every_expert
+        counts["gather_slots"] = gather_slots(choice) * cfg.holds_every_expert
         return out.astype(jnp.dtype(cfg.dtype)).reshape(b, s, d), counts
 
 

@@ -38,7 +38,15 @@ Mechanism — ``ops/segment.py``'s problem on the MXU:
    section 7) — and the sort's inverse says where each token's rows lie: the
    trips write their rows into one ``[t x k, d]`` float32 buffer by sorted
    position and a token's result is the sum of the ``k`` rows gathered from
-   it, float32 sums of float32 rows still, no product and no scatter.
+   it, float32 sums of float32 rows still, no product and no scatter. The
+   gather walks the token rows in chunks of ``_GATHER_BLOCK``: a chunk in
+   which no (token, choice) is an assignment (``choice >= 0``) could only
+   gather the buffer's zero rows, so it is zeros without a gather — left
+   padding makes the pads whole leading chunks, scattered pads cost what
+   every row cost before (:func:`gather_slots` counts the token rows visited
+   times ``k``). What decides a chunk is ``choice`` alone: a real token one of
+   whose choices is -1 still has its chunk visited and misses that one row
+   (its position sorts past the held, where the buffer holds zeros).
 
 The count of rows handed to the grouped products (the sum of the group sizes
 each call was given: rows outside a group are not computed) is returned
@@ -52,7 +60,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["combine_blocks", "combined_positions", "grouped_matmul", "held_expert_ffn"]
+__all__ = ["combine_blocks", "combined_positions", "gather_slots", "grouped_matmul",
+           "held_expert_ffn"]
 
 # megablox tiles (m, k, n) for one v5e core: [512, 1024] and [1024, 1024]
 # bf16 operand tiles double-buffered plus a [512, 1024] f32 accumulator stay
@@ -63,6 +72,10 @@ _GMM_TILING = (512, 1024, 1024)
 # sorted positions a block of the combine takes: [t, 3 x 512] one-hot columns
 # against [3 x 512, d] addends, one pass over ``out`` a block
 _COMBINE_BLOCK = 512
+
+# token rows a chunk of the gather combine takes (``whole``): a chunk is the
+# unit that is skipped where it holds no assignment
+_GATHER_BLOCK = 512
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -113,6 +126,23 @@ def combined_positions(n_held, rows: int):
     return n_held // rows * rows + last * width
 
 
+def _gather_chunks(choice: jnp.ndarray):
+    """``(live [chunks] bool, width)``: the gather combine walks ``choice``'s
+    ``t`` token rows in chunks of ``width``; a chunk is live where any of its
+    (token, choice) holds an assignment (``choice >= 0``)."""
+    t, k = choice.shape
+    width = _GATHER_BLOCK if t % _GATHER_BLOCK == 0 else t
+    return jnp.any((choice >= 0).reshape(t // width, width * k), axis=1), width
+
+
+def gather_slots(choice: jnp.ndarray):
+    """Rows the gather combine reads for ``choice`` [t, k] (``whole``): the
+    token rows of its live chunks times ``k`` — the assignments over it is how
+    full the visited chunks were."""
+    live, width = _gather_chunks(choice)
+    return jnp.sum(live, dtype=jnp.int32) * (width * choice.shape[1])
+
+
 def _add_rows(out: jnp.ndarray, tok: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """``out.at[tok].add(y)`` as one product: ``onehot[t, 3w] @ [hi; mid; lo]``.
 
@@ -149,7 +179,8 @@ def held_expert_ffn(
     ``activation``: the gate's, float32 in and out; ``whole``: the caller's
     word that ``[lo, lo + n)`` is everything the router can choose (every
     ``choice >= 0`` is held), which picks the gather for the combine (module
-    docstring, step 4).
+    docstring, step 4), taken over the chunks of ``_GATHER_BLOCK`` token rows
+    that hold a ``choice >= 0``; the others are zeros.
 
     Returns ``(out [t, d] float32, computed)``: ``computed`` sums the group
     sizes the grouped products were given, trip by trip — the rows they
@@ -220,7 +251,20 @@ def held_expert_ffn(
         (zero, jnp.zeros((-(-a // rows) * rows, d), jnp.float32), zero))
     with jax.named_scope("combine"):
         at = jnp.argsort(order).astype(jnp.int32).reshape(t, k)  # the sort's inverse
-        out = buf[at[:, 0]]
-        for j in range(1, k):
-            out = out + buf[at[:, j]]
-    return out, computed
+        live, width = _gather_chunks(choice)
+
+        def gathered(c):
+            """Token rows ``[c * width, (c + 1) * width)``: each one's ``k``
+            rows, summed in the order of its choices."""
+            at_c = lax.dynamic_slice_in_dim(at, c * width, width)
+            acc = buf[at_c[:, 0]]
+            for j in range(1, k):
+                acc = acc + buf[at_c[:, j]]
+            return acc
+
+        # a chunk none of whose choices is an assignment (left pads) reads
+        # zero rows only: it is zeros without the gathers
+        out = lax.map(lambda c: lax.cond(
+            live[c], gathered, lambda _: jnp.zeros((width, d), jnp.float32), c),
+            jnp.arange(t // width, dtype=jnp.int32))
+    return out.reshape(t, d), computed

@@ -1,7 +1,9 @@
 """The combine of ``ops/grouped.py:held_expert_ffn`` — the held experts' rows
 back onto their tokens as a one-hot product — against the scatter-add it
 replaced (written here), in float32; the blocks it walks against
-``combined_positions``, the count the ``moe`` stats carry."""
+``combined_positions``, the count the ``moe`` stats carry; and where every
+expert is held (``whole``) the gather over the chunks of token rows that hold
+an assignment against both, the chunks it visits against ``gather_slots``."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,7 @@ from deepdfa_tpu.ops import grouped
 from deepdfa_tpu.ops.grouped import (
     combine_blocks,
     combined_positions,
+    gather_slots,
     grouped_matmul,
     held_expert_ffn,
 )
@@ -263,6 +266,140 @@ def test_the_gather_equals_the_one_hot_combine_where_nothing_is_absent(rows, pad
     assert gathered.dtype == jnp.float32 and not np.asarray(gathered[:pads]).any()
     _same(gathered, onehot)
     _same(gathered, scatter_add_ffn(*args, lo=0, rows=rows)[0])
+
+
+# -- the whole branch's combine: the gather over the chunks that hold an assignment -----------
+
+N_WHOLE = 4  # experts, all held
+CHUNK = 8  # what the tests below set ``_GATHER_BLOCK`` to
+
+
+def _left_padded(rng, t, k, pads):
+    choice = rng.integers(0, N_WHOLE, size=(t, k)).astype(np.int32)
+    choice[pads] = -1
+    return choice
+
+
+def _two_left_padded_rows(rng, t, k):
+    """Two rows of ``t // 2`` positions, 3 and 1 whole leading chunks of pads."""
+    pads = np.zeros(t, bool)
+    pads[:3 * CHUNK], pads[t // 2:t // 2 + CHUNK] = True, True
+    return _left_padded(rng, t, k, pads)
+
+
+def _one_choice_missing(rng, t, k):
+    """A real token with one of its choices -1 (what ``expert_skipped`` of
+    ``benchmark/tools/prove_frozen_smallthinker.py`` hands this branch), beside a left pad."""
+    choice = _left_padded(rng, t, k, np.arange(t) < 8)
+    choice[11, 1] = -1
+    choice[40:48, 0] = -1  # and a whole chunk of them
+    return choice
+
+
+# name -> (t, rows, choices)
+WHOLE_CASES = {
+    "two left-padded rows": (64, 32, _two_left_padded_rows),
+    "pads scattered inside chunks": (64, 32, lambda rng, t, k: _left_padded(
+        rng, t, k, rng.random(t) < 0.4)),
+    "no pad": (64, 32, lambda rng, t, k: _left_padded(rng, t, k, np.zeros(t, bool))),
+    "every token a pad": (64, 32, lambda rng, t, k: np.full((t, k), -1, np.int32)),
+    "t not a multiple of the chunk": (44, 32, lambda rng, t, k: _left_padded(
+        rng, t, k, np.arange(t) < 9)),
+    "every token on one expert, several trips": (64, 16, lambda rng, t, k: np.where(
+        np.arange(t)[:, None] < 16, -1, 2).astype(np.int32) * np.ones((1, k), np.int32)),
+    "a real token with one choice -1": (64, 32, _one_choice_missing),
+}
+
+
+def _whole_case(monkeypatch, name):
+    t, rows, draw = WHOLE_CASES[name]
+    monkeypatch.setattr(grouped, "_GATHER_BLOCK", CHUNK)
+    rng = np.random.default_rng(len(name))
+    k, d, f = 3, 16, 8
+    u = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    choice = jnp.asarray(draw(rng, t, k), jnp.int32)
+    # a pad's gates are not zeroed here: what a token receives is decided by ``choice`` alone
+    gates = jnp.asarray(0.25 + rng.random((t, k)), jnp.float32)
+    return (u, choice, gates, *_weights(rng, N_WHOLE, d, f)), rows
+
+
+def _nan_past_the_groups(real):
+    """``grouped_matmul`` with every output row at or past the sum of its group sizes NaN:
+    what the chip may hold where the kernel visited no tile or masked a row. No such row
+    may reach a token, whichever chunks the combine visits."""
+    def poisoned(x, w, sizes):
+        y = real(x, w, sizes)
+        return jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None], y, jnp.nan)
+    return poisoned
+
+
+@pytest.mark.parametrize("poison", [False, True], ids=["", "rows past the groups NaN"])
+@pytest.mark.parametrize("name", list(WHOLE_CASES))
+def test_the_whole_branch_equals_the_one_hot_combine_and_the_scatter_add(monkeypatch, name, poison):
+    args, rows = _whole_case(monkeypatch, name)
+    choice = np.asarray(args[1])
+    want, n_held = scatter_add_ffn(*args, lo=0, rows=rows)
+    onehot, computed_1 = held_expert_ffn(*args, lo=0, rows=rows)
+    if poison:
+        monkeypatch.setattr(grouped, "grouped_matmul", _nan_past_the_groups(grouped.grouped_matmul))
+    out, computed = jax.jit(lambda *a: held_expert_ffn(*a, lo=0, rows=rows, whole=True))(*args)
+    assert int(computed) == int(computed_1) == n_held == int((choice >= 0).sum())
+    assert out.dtype == jnp.float32 and out.shape == want.shape
+    assert np.isfinite(np.asarray(out)).all()
+    assert not np.asarray(out)[(choice < 0).all(axis=1)].any()  # a pad reads zeros
+    _same(out, onehot)
+    _same(out, want)
+    if name == "every token a pad":
+        assert n_held == 0 and not np.asarray(out).any()
+    elif name == "every token on one expert, several trips":
+        assert n_held > 2 * rows
+
+
+def test_a_choice_of_minus_one_on_a_real_token_leaves_out_that_row_alone(monkeypatch):
+    args, rows = _whole_case(monkeypatch, "a real token with one choice -1")
+    u, choice, gates, *w = args
+    kept = jnp.where(choice < 0, 0, choice)  # the same tokens with the missing choices put back
+    run = lambda c, g: np.asarray(held_expert_ffn(u, c, g, *w, lo=0, rows=rows, whole=True)[0])
+    out = run(choice, gates)
+    full = run(kept, jnp.where((choice < 0).all(axis=1, keepdims=True), 0.0, gates))
+    gone = (np.asarray(choice) < 0) & ~(np.asarray(choice) < 0).all(axis=1, keepdims=True)
+    assert gone[11, 1] and gone[40:48, 0].all() and gone.sum() == 9
+    x = np.asarray(u)
+    row = lambda t_, e: (np.asarray(jax.nn.silu(x[t_] @ w[0][e])) * (x[t_] @ np.asarray(w[1][e]))
+                         ) @ np.asarray(w[2][e])
+    for t_, j in zip(*np.nonzero(gone)):
+        np.testing.assert_allclose(
+            full[t_] - out[t_], float(gates[t_, j]) * row(t_, 0), rtol=1e-4, atol=1e-4)
+    untouched = ~gone.any(axis=1)
+    assert np.array_equal(out[untouched], full[untouched])
+
+
+@pytest.mark.parametrize("name", list(WHOLE_CASES))
+def test_gather_slots_counts_the_chunks_that_hold_an_assignment(monkeypatch, name):
+    """``gather_slots`` (what the ``moe`` stats carry) against the chunks counted by hand and
+    against a run of the branch in which every chunk the combine gathers for is counted."""
+    args, rows = _whole_case(monkeypatch, name)
+    choice = np.asarray(args[1])
+    t, k = choice.shape
+    width = t if t % CHUNK else CHUNK  # not whole chunks: one chunk
+    live = (choice >= 0).reshape(t // width, width * k).any(axis=1)
+    assert int(gather_slots(args[1])) == k * int(live.sum()) * width
+    assert int((choice >= 0).sum()) <= int(gather_slots(args[1])) <= t * k
+    if name == "two left-padded rows":
+        assert live.tolist() == [False] * 3 + [True] + [False] + [True] * 3
+    visited = []
+    real_slice = lax.dynamic_slice_in_dim
+
+    def counted(x, start, size, axis=0):
+        # the slice of the sort's inverse, [t, k] int32, that a live chunk's gather takes
+        if x.dtype == jnp.int32 and x.shape == choice.shape:
+            jax.debug.callback(lambda: visited.append(size))
+        return real_slice(x, start, size, axis)
+    monkeypatch.setattr(lax, "dynamic_slice_in_dim", counted)
+    out, _ = held_expert_ffn(*args, lo=0, rows=rows, whole=True)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    assert set(visited) <= {width} and k * sum(visited) == int(gather_slots(args[1]))
 
 
 @pytest.mark.parametrize("dim,cap,tile", [

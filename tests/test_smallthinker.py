@@ -189,10 +189,15 @@ def test_routing_and_attention_counts_are_on_the_loss_sync_spans(followed):
     spans = [s for s in followed["driver"].trainer.telemetry.tracer.spans()
              if s.name == "loss.sync" and "moe_gathered" in s.attrs and t0 <= s.start_s <= t1]
     assert len(spans) >= followed["driver"].setup_steps - 1  # the step in flight is not read
+    jcfg, cfg = followed["driver"].jcfg, followed["driver"].llm_cfg
+    every_slot = (cfg.num_hidden_layers * jcfg.train_batch_size * jcfg.block_size
+                  * cfg.moe_num_active_primary_experts)
     for s in spans:
         a = s.attrs
         assert a["moe_dropped"] == a["moe_zero"] == a["moe_absent"] == a["moe_combined"] == 0
         assert a["moe_held"] == a["moe_assigned"] == a["moe_gathered"] > 0 and a["moe_layers"] == 8
+        # the token rows the gather visited times k, beside the assignments it combined
+        assert 0 < a["moe_assigned"] <= a["moe_gather_slots"] <= every_slot
         assert a["attn_layers"] == 8 and a["attn_window_layers"] == 6
         assert 0 < a["attn_pairs_needed"] < a["attn_pairs_computed"]
     tie = followed["run"]["readings"]["tie"]
