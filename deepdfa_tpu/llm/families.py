@@ -105,6 +105,10 @@ FAMILIES: dict[str, EncoderFamily] = {
         # before attention, ReLU-gated routed experts and nothing beside them, frozen; no converter
         EncoderFamily("smallthinker", "SmallThinkerConfig", "SmallThinkerModel", "tiny_smallthinker", pool="last",
                       trained=False, from_seed=_sparse_from_seed),
+        # causal, dense, degree-2 power retention (gated linear attention, a [8256, 128] state a key/value
+        # head) in place of softmax attention, identical layers as one scan, frozen; no converter
+        EncoderFamily("brumby", "BrumbyConfig", "BrumbyModel", "tiny_brumby", pool="last", trained=False,
+                      from_seed=_sparse_from_seed),
     ]
 }
 

@@ -10,8 +10,10 @@ chip's share of an expert-parallel deployment each, and their test-size
 twins), a hybrid state-space decoder whole (``"jamba"``, and its twin), or
 a grouped-query decoder of global and windowed layers that holds every one of
 its routed experts (``"smallthinker"``: SmallThinker-21BA3B, 12 of its 52
-layers, on 8k-token blocks; and its twin).
-What a family is lives in ``llm/families.py`` (six today; a further
+layers, on 8k-token blocks; and its twin), or a dense decoder whose attention is
+degree-2 power retention (``"brumby"``: Brumby-14B-Base, 10 of its 40 layers,
+on the same 8k-token blocks; and its twin).
+What a family is lives in ``llm/families.py`` (seven today; a further
 one is one row there and one model file); a preset's
 ``llm`` must be its family's config class, checked at construction.
 ``finetuned`` marks presets that start from a LoRA-finetuned model
@@ -30,6 +32,7 @@ import dataclasses
 
 from deepdfa_tpu.config import MeshConfig
 from deepdfa_tpu.llm.families import FAMILIES
+from deepdfa_tpu.llm.brumby import BrumbyConfig, brumby_14b, tiny_brumby
 from deepdfa_tpu.llm.jamba import JambaConfig, jamba2_3b, tiny_jamba
 from deepdfa_tpu.llm.joint import JointConfig
 from deepdfa_tpu.llm.llama import LlamaConfig, codellama_7b, codellama_13b
@@ -45,7 +48,8 @@ __all__ = ["JointPreset", "PRESETS"]
 class JointPreset:
     name: str
     # encoder_family's class
-    llm: LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig | SmallThinkerConfig
+    llm: (LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig | SmallThinkerConfig
+          | BrumbyConfig)
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
@@ -55,7 +59,8 @@ class JointPreset:
     # "pangu_moe" (causal, latent attention + routed experts, frozen), "jamba"
     # (causal, selective-scan layers + multi-query attention, frozen),
     # "smallthinker" (causal, global / windowed grouped-query attention +
-    # routed experts all held, frozen)
+    # routed experts all held, frozen), "brumby" (causal, degree-2 power
+    # retention + dense MLPs, frozen)
     encoder_family: str = "llama"
 
     def __post_init__(self):
@@ -283,6 +288,36 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="smallthinker",
+        ),
+        # the same job on the same 8k-token inputs over Brumby-14B-Base at its
+        # published widths: 10 of its 40 identical layers (degree-2 power
+        # retention, 40 query heads over 8 key/value heads of 128, a 17,408-wide
+        # MLP) as one stage of a four-stage pipeline, no layer divided, the
+        # whole vocabulary: 8.16 GB of bfloat16 weights
+        JointPreset(
+            name="brumby_14b_msivd",
+            llm=brumby_14b(num_hidden_layers=10),
+            joint=JointConfig(
+                block_size=8192, epochs=1, train_batch_size=2, eval_batch_size=2,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="brumby",
+        ),
+        # the same code at test size (CPU): 2 layers, chunks of 16 in a block of 64
+        JointPreset(
+            name="tiny_brumby_msivd",
+            llm=tiny_brumby(vocab_size=2048),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="brumby",
         ),
     ]
 }

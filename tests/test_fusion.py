@@ -376,7 +376,7 @@ def test_presets_cover_reference_launch_scripts():
     from deepdfa_tpu.llm.presets import PRESETS
 
     # 5 MSIVD launch scripts + the 2 LineVul configs of BASELINE config #3
-    # + the four frozen decoders (three routed-expert, one state-space), each with its test-size twin
+    # + the five frozen decoders (three routed-expert, one state-space, one power-retention), each with its test-size twin
     assert set(PRESETS) == {
         "bigvul_ft_bigvul", "pretrained_bigvul", "pb_ft_pb",
         "pb_ft_pb_noexpl", "pretrained_pb", "linevul", "linevul_fusion",
@@ -384,6 +384,7 @@ def test_presets_cover_reference_launch_scripts():
         "openpangu_ultra_msivd", "tiny_pangu_moe_msivd",
         "jamba2_3b_msivd", "tiny_jamba_msivd",
         "smallthinker_21b_msivd", "tiny_smallthinker_msivd",
+        "brumby_14b_msivd", "tiny_brumby_msivd",
     }
     p = PRESETS["bigvul_ft_bigvul"]
     assert p.llm.hidden_size == 4096 and p.joint.block_size == 256

@@ -373,3 +373,21 @@ def test_the_grouped_query_attention_kernel_compiles_for_the_v5e_at_the_decoders
         ).lower(lowering_platforms=("tpu",)).compile()
         assert "gqa_attention_fwd" in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_the_power_retention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
+    """Here for the fixture's sake too: ``ops/power_retention`` at [2, 8192,
+    40 | 8 x 128] bfloat16 (the 65 lane tiles of the feature map, the
+    [8320, 128] float32 state and phi(K) in VMEM, the chunk's decay from SMEM),
+    and no temporary of the feature map's size in HBM (phi(Q) alone would be
+    10.8 GB a layer)."""
+    from deepdfa_tpu.ops.power_retention import power_retention
+
+    b, s, h, hk, d = 2, 8192, 40, 8, 128
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    compiled = jax.jit(functools.partial(power_retention, chunk=128, interpret=False)).trace(
+        shape(b, s, h * d), shape(b, s, hk * d), shape(b, s, hk * d),
+        shape(b, s, hk, dtype=jnp.float32), shape(b, s, dtype=jnp.bool_)).lower(
+            lowering_platforms=("tpu",)).compile()
+    assert "power_retention_fwd" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
