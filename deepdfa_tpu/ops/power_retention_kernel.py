@@ -9,18 +9,21 @@ by side, the decoder's dtype) and writes ``o`` the same way, so nothing of the
 feature expansion passes through HBM. The head's float32 state ``[S | z]``
 stays in VMEM from the row's first visited chunk to its last.
 
-**The feature map in 65 lane tiles.** ``phi(x)`` (``x_a x_b``, ``a <= b``) has
-8,256 entries at ``d = 128``. Its rows ``a`` (``b`` from ``a`` to 127, ``128 -
-a`` entries) pair up: row ``a`` with row ``128 - a`` for ``a = 1 .. 63`` fill
-one 128-lane tile exactly (lanes ``l < 128 - a``: ``x_a x_{a+l}``; the rest:
-``x_{128-a} x_l``), row 0 fills tile 0 and row 64 half of tile 64: 65 tiles,
-8,320 lanes (64 of them zero), 0.8% over the needed width. Tile ``a`` is two
-lane rotations of ``x`` (``pltpu.roll``), two lane broadcasts and a select,
-built in VMEM per chunk (float32; rounded to bfloat16 for the MXU), five
-tiles a trip of a loop (the 65 unrolled took Mosaic 26 s to compile, the loop
-2 s). The symmetric ``[128, 128, 128]`` form (twice the operations) is not used. The
-diagonal's factor 1 and the rest's 2 (``sqrt 2`` squared) and ``1 / d`` are
-put on the key side only.
+**The feature map in 65 lane tiles, by diagonal.** ``phi(x)`` (``x_a x_b``,
+``a <= b``) has 8,256 entries at ``d = 128``. Tile ``a`` (``a = 0 .. 64``) is
+``x * roll(x, a)``: lane ``l`` holds ``x_l x_{(l + a) % 128}``, the pairs at
+circular distance ``a``. With ``p_l = q_l k_l``, ``(q . k)^2 = sum_delta sum_l
+p_l p_{l + delta}`` over all 128 offsets, and offsets ``delta`` and ``128 -
+delta`` give the same sum, which is tile ``delta`` of ``q`` dotted with tile
+``delta`` of ``k``; so the coefficients are 1 on tile 0, 2 on tiles 1 .. 63
+and 1 on tile 64, over ``d``, put on the key side only. Tiles 1 .. 63 hold each
+pair ``a < b`` once (``b - a`` is the tile or 128 less it), tile 0 the squares,
+and tile 64 each of its 64 pairs twice (lanes ``l`` and ``l + 64``), where one
+copy and 64 zeros would do: 65 tiles, 8,320 lanes, 0.8% over the needed width.
+A tile is one lane rotation (``pltpu.roll``) and one product, built in VMEM per
+chunk (float32; rounded to bfloat16 for the MXU), 13 tiles a trip of a loop
+(the 65 unrolled took Mosaic 26 s to compile, the loop 2 s). The symmetric
+``[128, 128, 128]`` form (twice the operations) is not used.
 
 **Per chunk and head group** (``G`` the log-gates summed from the chunk's
 start, ``QS`` the group's query heads stacked ``[rep C, 128]``)::
@@ -64,19 +67,21 @@ __all__ = ["retention_forward"]
 
 LANES = HEAD_DIM
 TILES = LANES // 2 + 1  # 65 lane tiles hold phi's 8,256 entries
-TRIP = 5  # tiles a trip of the loop over them (Mosaic unrolls a loop wholly or not at all)
+TRIP = 13  # tiles a trip of the loop over them (Mosaic unrolls a loop wholly or not at all)
 _VMEM_LIMIT = 64 * 1024 * 1024  # the state (4.3 MB), phi(K) (2.1 MB at C = 128) and the blocks
 
 
-def _phi_tile(x: jnp.ndarray, a, lane: jnp.ndarray) -> jnp.ndarray:
+def _phi_tile(x: jnp.ndarray, a) -> jnp.ndarray:
     """Tile ``a`` of phi (module docstring, no factors) of the rows of ``x``
-    [M, 128] float32: lanes ``l < 128 - a`` hold ``x_a x_{a+l}``, the rest
-    ``x_{128-a} x_l`` (nothing in tile 64, whose row 64 is the first part).
-    ``a`` may be traced: the rotations take it as their shift."""
-    ahead = pltpu.roll(x, (LANES - a) % LANES, 1)  # lane l: x_{(a + l) % 128}
-    behind = pltpu.roll(x, a, 1)  # lane 0: x_{128 - a}
-    second = jnp.where(a == LANES // 2, 0.0, behind[:, 0:1] * x)
-    return jnp.where(lane < LANES - a, ahead[:, 0:1] * ahead, second)
+    [M, 128] float32: lane ``l`` holds ``x_l x_{(l + a) % 128}``. ``a`` may be
+    traced: the rotation takes it as its shift."""
+    return x * pltpu.roll(x, (LANES - a) % LANES, 1)
+
+
+def _tile_coef(a):
+    """Tile ``a``'s factor on the key side: 1 on tiles 0 and 64, 2 on the rest,
+    over ``d``."""
+    return jnp.where((a == 0) | (a == LANES // 2), 1.0, 2.0) * (1.0 / LANES)
 
 
 def _kernel(first_ref, last_ref, q_ref, k_ref, v_ref, gcol_ref, grow_ref, mcol_ref, mrow_ref,
@@ -119,20 +124,18 @@ def _kernel(first_ref, last_ref, q_ref, k_ref, v_ref, gcol_ref, grow_ref, mcol_r
         den = jnp.sum(p, axis=1, keepdims=True)
 
         # from the chunks before: phi(QS) against the state, tile by tile
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
         qf, kf = qs.astype(f32), k.astype(f32)
         w_col = jnp.where(real_col, jnp.exp(g_last - g_col), 0.0)  # [C, 1]: into the state
         keep = jnp.exp(jnp.full((1, d), g_last, f32))  # [1, d]
 
         def tile(a, acc, dacc):
-            pq = _phi_tile(qf, a, lane)
+            pq = _phi_tile(qf, a)
             at = pl.multiple_of(a * d, d)
             acc += jnp.dot(pq.astype(bf16), s_ref[pl.ds(at, d), :].astype(bf16),
                            preferred_element_type=f32)
             z_a = z_ref[pl.ds(a, 1), :]
             dacc += pq * z_a
-            coef = jnp.where((lane == 0) | (lane == LANES - a), 1.0, 2.0) * (1.0 / d)
-            pk = _phi_tile(kf, a, lane) * coef  # [C, d]
+            pk = _phi_tile(kf, a) * _tile_coef(a)  # [C, d]
             pk_ref[:, pl.ds(at, d)] = pk.astype(bf16)
             z_ref[pl.ds(a, 1), :] = keep * z_a + jnp.sum(w_col * pk, axis=0, keepdims=True)
             return acc, dacc

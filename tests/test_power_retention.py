@@ -1,9 +1,10 @@
 """Degree-2 power retention (``ops/power_retention.py``, and the Pallas kernel
 ``ops/power_retention_kernel.py`` under the interpreter) against a float64
-position-by-position recurrence: the feature map's identity, every left-pad
-layout (leading chunks wholly of pads skipped, a row of pads alone), results
-that do not hang on the chunk, the grouped-query mapping, the visited-chunk
-counts, and the op raising where it is differentiated."""
+position-by-position recurrence: the feature map's identity (``phi``'s and
+the kernel's diagonal tiles'), every left-pad layout (leading chunks wholly of
+pads skipped, a row of pads alone), results that do not hang on the chunk, the
+grouped-query mapping, the visited-chunk counts, and the op raising where it is
+differentiated."""
 
 import math
 
@@ -79,6 +80,51 @@ def test_phi_is_the_square_of_the_dot_product(d):
     want = np.einsum("nd,nd->n", q, k, dtype=np.float64) ** 2
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * want.max())
     assert phi(q).shape[-1] == d * (d + 1) // 2
+
+
+def _diagonal_tiles(x, traced):
+    """The kernel's 65 tiles of phi of the rows of ``x`` [M, 128] float32, by
+    its own helper under the Pallas interpreter: [M, 65 x 128]. ``traced``
+    takes the tile's index from a loop, as the kernel does."""
+    from jax.experimental import pallas as pl
+
+    from deepdfa_tpu.ops.power_retention_kernel import LANES, TILES, _phi_tile
+
+    def kernel(x_ref, o_ref):
+        if traced:
+            def body(a, carry):
+                o_ref[:, pl.ds(pl.multiple_of(a * LANES, LANES), LANES)] = _phi_tile(x_ref[...], a)
+                return carry
+
+            jax.lax.fori_loop(0, TILES, body, 0)
+        else:
+            for a in range(TILES):
+                o_ref[:, a * LANES:(a + 1) * LANES] = _phi_tile(x_ref[...], a)
+
+    out = jax.ShapeDtypeStruct((x.shape[0], TILES * LANES), jnp.float32)
+    return np.asarray(pl.pallas_call(kernel, out_shape=out, interpret=True)(jnp.asarray(x)))
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static_tile", "traced_tile"])
+def test_the_kernels_diagonal_tiles_are_phi(traced):
+    """Tile ``a`` is ``x * roll(x, a)``: with the key side's factors (1, 2, ..,
+    2, 1 over d) the tiles' dot product is ``(q . k)^2 / d``, and they hold
+    phi's products — tile 64's 64 pairs twice, every other once."""
+    from deepdfa_tpu.ops.power_retention_kernel import LANES, TILES, _tile_coef
+
+    rng = np.random.default_rng(128)
+    q, k = rng.standard_normal((2, 8, LANES)).astype(np.float32)
+    tq, tk = _diagonal_tiles(q, traced), _diagonal_tiles(k, traced)
+    coef = np.repeat([float(_tile_coef(a)) for a in range(TILES)], LANES)
+    got = np.einsum("nD,nD->n", tq.astype(np.float64), tk * coef)
+    want = np.einsum("nd,nd->n", q, k, dtype=np.float64) ** 2 / LANES
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * want.max())
+    a, b = np.triu_indices(LANES)
+    for x, tiles in ((q, tq), (k, tk)):
+        last = tiles[:, -LANES:]
+        np.testing.assert_array_equal(last[:, :LANES // 2], last[:, LANES // 2:])
+        np.testing.assert_array_equal(np.sort(tiles[:, :-(LANES // 2)], axis=1),
+                                      np.sort(x[:, a] * x[:, b], axis=1))
 
 
 @pytest.mark.parametrize("layout", list(LAYOUTS))
