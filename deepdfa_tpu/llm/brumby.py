@@ -27,6 +27,19 @@ its events under one name. Under left padding everything starts at a row's
 first real token: a pad adds nothing to the retention state, decays nothing,
 and RoPE's positions count real tokens.
 
+**A layer's position-wise halves skip the chunks of positions that hold no
+real token.** ``pre`` (input norm, the q, k, v and gate projections, q/k
+norms, RoPE, gates) and ``post`` (``o_proj``, residual, post-attention norm,
+MLP, residual) read no other position, so each runs as a lifted ``nn.scan``
+over chunks of :data:`DENSE_BLOCK` positions (a whole row where that does not
+divide the block; :func:`dense_chunks`), the layer's weights broadcast, and an
+``nn.cond`` computes a chunk only where it holds a real token. A chunk of pads
+yields zeros from ``pre`` and its own input from ``post``: no real token reads
+a pad. The retention between the halves takes whole rows. ``init`` runs both
+halves over the whole block (``BrumbyLayer.whole``), so it draws the leaves it
+always drew. The width is the one ``scripts/bench_brumby_layer.py`` times
+best on the chip at the benchmark cell's shape.
+
 Precision: weights and activations ``dtype``, products accumulate in float32;
 RMSNorm, the q/k norms, the rotation, the gates and the retention's state and
 ratio in float32 (q and k handed to the retention in ``dtype``); the gate's
@@ -39,10 +52,11 @@ elsewhere. It has no backward: the decoder is frozen.
 ``layers``, ``fused`` (the layers whose retention ran the kernel: all or
 none), ``chunks_needed`` (a row's chunks that hold a real token),
 ``chunks_computed`` (those the path that ran visited), ``tokens_visited``
-(``chunks_computed`` times the chunk) and ``tokens_real``, each summed over
-the layers. The scopes ``layers/retention/proj``,
-``layers/retention/kernel`` and ``layers/mlp`` are what
-``benchmark/tools/program_trace.py`` sums.
+(``chunks_computed`` times the chunk), ``tokens_real`` and ``tokens_dense``
+(the positions the halves computed: live chunks times their width), each
+summed over the layers. The scopes ``layers.pre/retention.project/proj``,
+``layers/retention.retain/kernel``, ``layers.post/o_proj`` and
+``layers.post/mlp`` are what ``benchmark/tools/program_trace.py`` sums.
 """
 
 from __future__ import annotations
@@ -68,6 +82,7 @@ __all__ = ["BrumbyConfig", "BrumbyModel", "BrumbyLayer", "brumby_14b", "tiny_bru
            "gate_bias_init"]
 
 HALF_LIVES = (64, 8192)  # the gate biases' half-lives, log-uniform across the kv heads (tokens)
+DENSE_BLOCK = 512  # positions a chunk of a layer's position-wise halves (module docstring)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,49 +164,120 @@ def _fused_retention(cfg: BrumbyConfig, seq_len: int) -> bool | None:
 
 
 class PowerRetention(nn.Module):
-    """Projections, q/k norms, RoPE and gates, then the retention (module
-    docstring), then ``o_proj``."""
+    """The retention's weights, ``project`` (q, k, v and gate projections,
+    q/k norms, RoPE and gates) and ``retain`` (the retention over whole rows);
+    ``o_proj`` is the layer's to call."""
 
     cfg: BrumbyConfig
 
-    @nn.compact
-    def __call__(self, x, mask, positions):
+    def setup(self):
+        cfg = self.cfg
+        h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        self.q_proj = _proj(h * d, ("embed", "heads"), cfg, "q_proj")
+        self.k_proj = _proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")
+        self.v_proj = _proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")
+        self.g_bias = self.param(  # float32: it holds the half-lives
+            "g_bias", nn.with_logical_partitioning(gate_bias_init, (None,)), (hk,), jnp.float32)
+        self.g_proj = _proj(hk, ("embed", None), cfg, "g_proj")
+        self.q_norm = RMSNorm(cfg.rms_norm_eps, dtype=jnp.float32, name="q_norm")
+        self.k_norm = RMSNorm(cfg.rms_norm_eps, dtype=jnp.float32, name="k_norm")
+        self.o_proj = _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")
+
+    def project(self, x, positions):
+        """``(q, k, v, log_g)`` of ``x`` [b, s, hidden] at ``positions`` [b, s]."""
         cfg = self.cfg
         h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         b, s, _ = x.shape
         dtype = jnp.dtype(cfg.dtype)
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype=jnp.float32, name=name)
         with jax.named_scope("proj"):
-            q = _proj(h * d, ("embed", "heads"), cfg, "q_proj")(x).reshape(b, s, h, d)
-            k = _proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")(x).reshape(b, s, hk, d)
-            v = _proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")(x)
-            g_bias = self.param(  # float32: it holds the half-lives
-                "g_bias", nn.with_logical_partitioning(gate_bias_init, (None,)), (hk,),
-                jnp.float32)
-            g = _proj(hk, ("embed", None), cfg, "g_proj")(x)
-            log_g = jax.nn.log_sigmoid(g.astype(jnp.float32) + g_bias)
+            q = self.q_proj(x).reshape(b, s, h, d)
+            k = self.k_proj(x).reshape(b, s, hk, d)
+            log_g = jax.nn.log_sigmoid(self.g_proj(x).astype(jnp.float32) + self.g_bias)
             cos, sin = rope_cos_sin(positions, d, cfg.rope_theta)
-            q = apply_rope(norm("q_norm")(q), cos, sin).astype(dtype)
-            k = apply_rope(norm("k_norm")(k), cos, sin).astype(dtype)
+            q = apply_rope(self.q_norm(q), cos, sin).astype(dtype)
+            k = apply_rope(self.k_norm(k), cos, sin).astype(dtype)
+        return q.reshape(b, s, h * d), k.reshape(b, s, hk * d), self.v_proj(x), log_g
+
+    def retain(self, q, k, v, log_g, mask):
+        """The retention's ``o`` [b, s, heads x d] over whole rows."""
+        cfg = self.cfg
         with jax.named_scope("kernel"):
-            o = power_retention(q.reshape(b, s, h * d), k.reshape(b, s, hk * d), v, log_g, mask,
-                                chunk=cfg.retention_chunk, interpret=_fused_retention(cfg, s))
-        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(o)
+            return power_retention(q, k, v, log_g, mask, chunk=cfg.retention_chunk,
+                                   interpret=_fused_retention(cfg, mask.shape[1]))
+
+
+def dense_chunks(mask: jnp.ndarray):
+    """``(live [chunks] bool, width)``: a layer's position-wise halves walk
+    the ``b * s`` positions of ``mask`` [b, s] in chunks of ``width`` —
+    :data:`DENSE_BLOCK` where it divides ``s``, else the whole row — and a
+    chunk is live where it holds a real token."""
+    b, s = mask.shape
+    width = DENSE_BLOCK if s % DENSE_BLOCK == 0 else s
+    return jnp.any(mask.reshape(b * s // width, width), axis=1), width
+
+
+def _over_live(layer: nn.Module, half, skip, live, *chunks):
+    """``half(layer, *c)`` for the chunks ``c`` of ``chunks`` (each [n, ...],
+    chunk by chunk on the leading axis) where ``live``, ``skip(*c)``
+    elsewhere: one lifted scan over the chunks, the layer's parameters
+    broadcast into each trip; stacked on the leading axis."""
+    def trip(mdl, carry, xs):
+        on, *c = xs
+        return carry, nn.cond(on, half, lambda _, *c: skip(*c), mdl, *c)
+
+    scan = nn.scan(trip, variable_broadcast="params", split_rngs={"params": False})
+    return scan(layer, None, (live, *chunks))[1]
 
 
 class BrumbyLayer(nn.Module):
     """The retention and the MLP, each behind a norm: one body of the
-    ``nn.scan`` (carry ``x``; ``mask`` and ``positions`` broadcast)."""
+    ``nn.scan`` (carry ``x``; ``mask`` and ``positions`` broadcast). The two
+    position-wise halves, ``pre`` and ``post``, run over the chunks of
+    positions that hold a real token (:func:`dense_chunks`); the retention
+    between them over whole rows."""
 
     cfg: BrumbyConfig
 
-    @nn.compact
-    def __call__(self, x, mask, positions):
+    def setup(self):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype=dtype, name=name)
-        a = x + PowerRetention(cfg, name="retention")(norm("input_norm")(x), mask, positions)
-        y = a + DenseFFN(cfg, cfg.intermediate_size, name="mlp")(norm("post_attn_norm")(a))
+        self.input_norm = RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="input_norm")
+        self.retention = PowerRetention(cfg)
+        self.post_attn_norm = RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="post_attn_norm")
+        self.mlp = DenseFFN(cfg, cfg.intermediate_size)
+
+    def pre(self, x, positions):
+        """The input norm and the retention's projections: ``(q, k, v, log_g)``."""
+        return self.retention.project(self.input_norm(x), positions)
+
+    def post(self, x, o):
+        """``o_proj``, the residual, the post-attention norm, the MLP and the
+        residual: the layer's output."""
+        a = x + self.retention.o_proj(o)
+        return a + self.mlp(self.post_attn_norm(a))
+
+    def whole(self, x, mask, positions):
+        """The layer with both halves over the whole block: what ``init`` runs."""
+        return self.post(x, self.retention.retain(*self.pre(x, positions), mask))
+
+    def __call__(self, x, mask, positions):
+        cfg = self.cfg
+        if self.is_initializing():  # init draws the leaves it always drew
+            return nn.with_logical_constraint(self.whole(x, mask, positions),
+                                              ("batch", "seq", "embed")), None
+        h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        dtype = jnp.dtype(cfg.dtype)
+        b, s, _ = x.shape
+        live, width = dense_chunks(mask)
+        cut = lambda a: a.reshape(live.shape[0], 1, width, *a.shape[2:])  # a chunk is a [1, width] row
+        rows = lambda a: a.reshape(b, s, *a.shape[3:])
+        nothing = lambda c, _: (  # a chunk of pads yields nothing a real token reads
+            jnp.zeros((1, width, h * d), dtype), jnp.zeros((1, width, hk * d), dtype),
+            jnp.zeros((1, width, hk * d), dtype), jnp.zeros((1, width, hk), jnp.float32))
+        q, k, v, log_g = map(rows, _over_live(self, BrumbyLayer.pre, nothing, live, cut(x),
+                                              cut(positions)))
+        o = self.retention.retain(q, k, v, log_g, mask)
+        y = rows(_over_live(self, BrumbyLayer.post, lambda c, _: c, live, cut(x), cut(o)))
         return nn.with_logical_constraint(y, ("batch", "seq", "embed")), None
 
 
@@ -222,10 +308,12 @@ class BrumbyModel(nn.Module):
         layers, chunk = cfg.num_hidden_layers, cfg.retention_chunk
         fused = _fused_retention(cfg, s) is not None  # every layer's or none's
         computed = layers * chunks_computed(attn_mask, chunk, fused)
+        live, width = dense_chunks(attn_mask)
         self.sow("stats", "retention", {
             "layers": jnp.int32(layers), "fused": jnp.int32(layers * fused),
             "chunks_needed": layers * chunks_needed(attn_mask, chunk),
             "chunks_computed": computed, "tokens_visited": computed * chunk,
             "tokens_real": layers * jnp.sum(attn_mask, dtype=jnp.int32),
+            "tokens_dense": layers * jnp.sum(live, dtype=jnp.int32) * width,
         }, reduce_fn=lambda _, new: new, init_fn=dict)
         return RMSNorm(cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype), name="norm")(x)

@@ -98,17 +98,23 @@ def test_bfloat16_hidden_states_stay_near_the_float32_reference(bench):
     assert got.dtype == jnp.bfloat16 and _gap(got, want, np.asarray(mask)) < 5e-2
 
 
-def test_the_scanned_stack_is_a_loop_over_its_layers(bench):
-    ids, mask = _rows(bench, (0, 9))
+def _layer_by_layer(bench, ids, mask, method=None):
+    """The stack as a loop over its layers, each a ``BrumbyLayer.apply``
+    (``method`` in place of ``__call__``), then the final norm."""
     cfg, p = bench["llm_cfg"], bench["params"]
     positions = jnp.maximum(jnp.cumsum(mask.astype(jnp.int32), axis=-1) - 1, 0)
     x = p["embed_tokens"]["embedding"][ids]
     for i in range(cfg.num_hidden_layers):
         layer = jax.tree.map(lambda leaf: leaf[i], p["layers"])
-        x, _ = BrumbyLayer(cfg).apply({"params": layer}, x, mask, positions)
+        x, _ = BrumbyLayer(cfg).apply({"params": layer}, x, mask, positions, method=method)
     x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + cfg.rms_norm_eps)
-    want = p["norm"]["weight"] * x
-    got = bench["model"].apply({"params": p}, ids, mask)
+    return p["norm"]["weight"] * x
+
+
+def test_the_scanned_stack_is_a_loop_over_its_layers(bench):
+    ids, mask = _rows(bench, (0, 9))
+    want = _layer_by_layer(bench, ids, mask)
+    got = bench["model"].apply({"params": bench["params"]}, ids, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
@@ -133,6 +139,79 @@ def test_the_kernels_path_is_the_plain_forms(monkeypatch):
     assert int(r_fused["chunks_needed"]) == int(r_plain["chunks_needed"]) == 2 * 3
 
 
+@pytest.mark.parametrize("pads", [(0, 0), (5, 16), (40, 63), (64, 3)])
+def test_the_chunked_halves_are_the_whole_blocks(bench, pads, monkeypatch):
+    """Chunks of 16 positions at the block of 64: the real tokens' final
+    states are the whole-block form's to float32 rounding (products of fewer
+    than 64 rows take another CPU kernel; the worst token, a row's first,
+    read 1.4e-6), and the reference's."""
+    from deepdfa_tpu.llm import brumby
+
+    monkeypatch.setattr(brumby, "DENSE_BLOCK", 16)
+    ids, mask = _rows(bench, pads)
+    real = np.asarray(mask)
+    got = bench["model"].apply({"params": bench["params"]}, ids, mask)
+    whole = _layer_by_layer(bench, ids, mask, lambda layer, *a: (layer.whole(*a), None))
+    assert _gap(got, whole, real) < 5e-6
+    want = bench["reference"].decoder(bench["cfg"], bench["w"], ids, mask)
+    assert _gap(got, want, real) < 1e-5
+
+
+def test_the_kernel_and_the_chunked_halves_skip_together(monkeypatch):
+    """The kernel (interpreted) after halves in chunks of 32: a row's leading
+    pad chunk is skipped by both, and its states are the plain form's over
+    whole rows to bfloat16 rounding."""
+    from deepdfa_tpu.llm import brumby, roberta
+
+    cfg = tiny_brumby(hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+                      head_dim=128, retention_chunk=32)
+    ids = jnp.asarray(np.random.default_rng(0).integers(3, 320, (2, 64)))
+    mask = jnp.asarray(np.arange(64)[None] >= np.array([[0], [40]]))
+    model = BrumbyModel(cfg)
+    params = jax.jit(model.init)(jax.random.key(0), ids, mask)
+    plain, s_plain = model.apply(params, ids, mask, mutable=["stats"])
+    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(brumby, "DENSE_BLOCK", 32)
+    fused, s_fused = BrumbyModel(cfg).apply(params, ids, mask, mutable=["stats"])
+    assert _gap(fused, plain, np.asarray(mask)) < 2e-2
+    r_plain, r_fused = s_plain["stats"]["retention"], s_fused["stats"]["retention"]
+    assert int(r_fused["fused"]) == 2 and int(r_fused["chunks_computed"]) == 2 * (2 + 1)
+    assert int(r_plain["tokens_dense"]) == 2 * 2 * 64  # whole rows: DENSE_BLOCK does not tile 64
+    assert int(r_fused["tokens_dense"]) == 2 * (2 + 1) * 32
+
+
+# the tree ``init`` draws for ``tiny_brumby`` at key 0, which the chunked halves leave as the
+# whole-block layer draws it: shape and the sum of |leaf| (float64)
+TINY_TREE = {
+    "embed_tokens/embedding": ((320, 64), 325.0097708759616),
+    "layers/input_norm/weight": ((2, 64), 128.0),
+    "layers/mlp/down_proj/kernel": ((2, 128, 64), 1188.8028025953774),
+    "layers/mlp/gate_proj/kernel": ((2, 64, 128), 1665.8254128245517),
+    "layers/mlp/up_proj/kernel": ((2, 64, 128), 1685.2013822040576),
+    "layers/post_attn_norm/weight": ((2, 64), 128.0),
+    "layers/retention/g_bias": ((2, 2), 27.794021606445312),
+    "layers/retention/g_proj/kernel": ((2, 64, 2), 27.056049299601),
+    "layers/retention/k_norm/weight": ((2, 16), 32.0),
+    "layers/retention/k_proj/kernel": ((2, 64, 32), 420.37668945161386),
+    "layers/retention/o_proj/kernel": ((2, 64, 64), 843.0934264053758),
+    "layers/retention/q_norm/weight": ((2, 16), 32.0),
+    "layers/retention/q_proj/kernel": ((2, 64, 64), 845.4300169268863),
+    "layers/retention/v_proj/kernel": ((2, 64, 32), 428.5556168733683),
+    "norm/weight": ((64,), 64.0),
+}
+
+
+def test_init_draws_the_leaves_it_always_drew():
+    from flax.traverse_util import flatten_dict
+
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = nn.meta.unbox(BrumbyModel(tiny_brumby()).init(jax.random.key(0), ids, ids == 0))
+    leaves = flatten_dict(params["params"], sep="/")
+    assert {n: x.shape for n, x in leaves.items()} == {n: s for n, (s, _) in TINY_TREE.items()}
+    for n, (_, total) in TINY_TREE.items():
+        assert np.abs(np.asarray(leaves[n], np.float64)).sum() == pytest.approx(total, rel=1e-6), n
+
+
 def test_a_later_token_changes_no_earlier_state(bench):
     ids, mask = _rows(bench, (3,))
     base = bench["model"].apply({"params": bench["params"]}, ids, mask)
@@ -148,7 +227,17 @@ def test_the_stats_count_every_layer_and_the_chunks(bench):
     layers = bench["llm_cfg"].num_hidden_layers
     assert r == {"layers": layers, "fused": 0, "chunks_needed": layers * (4 + 3 + 0),
                  "chunks_computed": layers * 12, "tokens_visited": layers * 12 * 16,
-                 "tokens_real": layers * (64 + 44)}
+                 "tokens_real": layers * (64 + 44), "tokens_dense": layers * 2 * 64}
+
+
+def test_the_dense_tokens_are_the_live_chunks_times_their_width(bench, monkeypatch):
+    from deepdfa_tpu.llm import brumby
+
+    monkeypatch.setattr(brumby, "DENSE_BLOCK", 16)
+    ids, mask = _rows(bench, (0, 20, 64))
+    _, sown = bench["model"].apply({"params": bench["params"]}, ids, mask, mutable=["stats"])
+    layers = bench["llm_cfg"].num_hidden_layers
+    assert int(sown["stats"]["retention"]["tokens_dense"]) == layers * (4 + 3 + 0) * 16
 
 
 def test_seeded_gates_start_at_the_assumed_half_lives(bench):
@@ -223,7 +312,7 @@ def test_the_retention_counts_are_on_the_loss_sync_spans(followed):
     for s in spans:
         r = {k[len("retention_"):]: v for k, v in s.attrs.items() if k.startswith("retention_")}
         assert set(r) == {"layers", "fused", "chunks_needed", "chunks_computed",
-                          "tokens_visited", "tokens_real"}
+                          "tokens_visited", "tokens_real", "tokens_dense"}
         assert r["layers"] == 2 and r["fused"] == 0  # the CPU runs the plain form
         assert r["tokens_visited"] == 16 * r["chunks_computed"] >= r["tokens_real"] > 0
         assert r["chunks_needed"] <= r["chunks_computed"] == 2 * 4 * 4  # every chunk of 4 rows
