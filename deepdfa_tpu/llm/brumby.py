@@ -68,15 +68,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepdfa_tpu.llm import roberta
-from deepdfa_tpu.llm.llama import RMSNorm, apply_rope, rope_cos_sin
-from deepdfa_tpu.llm.longcat import DenseFFN, _proj, embed_tokens
-from deepdfa_tpu.ops.power_retention import (
-    chunks_computed,
-    chunks_needed,
-    power_retention,
-    supports,
+from deepdfa_tpu.llm.layers import (
+    DenseFFN,
+    RMSNorm,
+    apply_rope,
+    embed_tokens,
+    proj,
+    rope_cos_sin,
+    sow_stats,
 )
+from deepdfa_tpu.ops.dispatch import kernel_mode
+from deepdfa_tpu.ops.power_retention import chunks_computed, chunks_needed, power_retention
 
 __all__ = ["BrumbyConfig", "BrumbyModel", "BrumbyLayer", "brumby_14b", "tiny_brumby",
            "gate_bias_init"]
@@ -153,14 +155,9 @@ def gate_bias_init(key, shape, dtype=jnp.float32):
 
 def _fused_retention(cfg: BrumbyConfig, seq_len: int) -> bool | None:
     """The ``interpret`` flag for the retention kernel, or ``None`` where the
-    plain form has to run: no kernel here (the rule is
-    ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
-    take."""
-    interpret = roberta._attention_kernel()
-    if interpret is None or not supports(seq_len, cfg.num_attention_heads, cfg.num_key_value_heads,
-                                         cfg.head_dim, cfg.retention_chunk):
-        return None
-    return interpret
+    plain form has to run (``ops/dispatch.py``)."""
+    return kernel_mode("power_retention", seq_len, cfg.num_attention_heads,
+                       cfg.num_key_value_heads, cfg.head_dim, cfg.retention_chunk)
 
 
 class PowerRetention(nn.Module):
@@ -173,15 +170,15 @@ class PowerRetention(nn.Module):
     def setup(self):
         cfg = self.cfg
         h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        self.q_proj = _proj(h * d, ("embed", "heads"), cfg, "q_proj")
-        self.k_proj = _proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")
-        self.v_proj = _proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")
+        self.q_proj = proj(h * d, ("embed", "heads"), cfg, "q_proj")
+        self.k_proj = proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")
+        self.v_proj = proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")
         self.g_bias = self.param(  # float32: it holds the half-lives
             "g_bias", nn.with_logical_partitioning(gate_bias_init, (None,)), (hk,), jnp.float32)
-        self.g_proj = _proj(hk, ("embed", None), cfg, "g_proj")
+        self.g_proj = proj(hk, ("embed", None), cfg, "g_proj")
         self.q_norm = RMSNorm(cfg.rms_norm_eps, dtype=jnp.float32, name="q_norm")
         self.k_norm = RMSNorm(cfg.rms_norm_eps, dtype=jnp.float32, name="k_norm")
-        self.o_proj = _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")
+        self.o_proj = proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")
 
     def project(self, x, positions):
         """``(q, k, v, log_g)`` of ``x`` [b, s, hidden] at ``positions`` [b, s]."""
@@ -309,11 +306,11 @@ class BrumbyModel(nn.Module):
         fused = _fused_retention(cfg, s) is not None  # every layer's or none's
         computed = layers * chunks_computed(attn_mask, chunk, fused)
         live, width = dense_chunks(attn_mask)
-        self.sow("stats", "retention", {
+        sow_stats(self, "retention", {
             "layers": jnp.int32(layers), "fused": jnp.int32(layers * fused),
             "chunks_needed": layers * chunks_needed(attn_mask, chunk),
             "chunks_computed": computed, "tokens_visited": computed * chunk,
             "tokens_real": layers * jnp.sum(attn_mask, dtype=jnp.int32),
             "tokens_dense": layers * jnp.sum(live, dtype=jnp.int32) * width,
-        }, reduce_fn=lambda _, new: new, init_fn=dict)
+        })
         return RMSNorm(cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype), name="norm")(x)

@@ -31,6 +31,7 @@ import optax
 from deepdfa_tpu.config import GGNNConfig
 from deepdfa_tpu.data.graphs import BatchedGraphs, compact_view, view_fits
 from deepdfa_tpu.data.dense import DenseBatch
+from deepdfa_tpu.llm.layers import sow_stats
 
 __all__ = ["ClassificationHead", "FusionModel", "fusion_loss"]
 
@@ -176,12 +177,9 @@ class FusionModel(nn.Module):
         else:
             pooled = self.flowgnn_encoder(graphs)
         sizes = jnp.asarray([n for n, _ in rungs] + [graphs.max_nodes], jnp.int32)
-        self.sow(
-            "stats", "ggnn",
-            {"nodes_real": graphs.node_mask.sum(dtype=jnp.int32),
-             "nodes_computed": sizes[index],
-             "compact": (index < len(rungs)).astype(jnp.int32)},
-            reduce_fn=lambda _, new: new, init_fn=dict)
+        sow_stats(self, "ggnn", {"nodes_real": graphs.node_mask.sum(dtype=jnp.int32),
+                                 "nodes_computed": sizes[index],
+                                 "compact": (index < len(rungs)).astype(jnp.int32)})
         return pooled
 
     def __call__(

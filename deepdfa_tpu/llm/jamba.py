@@ -52,11 +52,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepdfa_tpu.llm import roberta
-from deepdfa_tpu.llm.llama import RMSNorm
-from deepdfa_tpu.llm.longcat import DenseFFN, _proj, embed_tokens
+from deepdfa_tpu.llm.layers import DenseFFN, RMSNorm, embed_tokens, proj, sow_stats
+from deepdfa_tpu.ops.dispatch import kernel_mode
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention
-from deepdfa_tpu.ops.selective_scan import causal_conv1d, gated_scan, selective_scan, supports
+from deepdfa_tpu.ops.selective_scan import causal_conv1d, gated_scan, selective_scan
 
 __all__ = ["JambaConfig", "JambaModel", "jamba2_3b", "tiny_jamba", "dt_bias_init"]
 
@@ -161,13 +160,8 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 def _fused_scan(cfg: JambaConfig, seq_len: int) -> bool | None:
     """The ``interpret`` flag for the selective-scan kernel, or ``None`` where
-    the plain form has to run: no kernel here (the rule is
-    ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
-    take."""
-    interpret = roberta._attention_kernel()
-    if interpret is None or not supports(seq_len, cfg.d_inner, cfg.mamba_d_state):
-        return None
-    return interpret
+    the plain form has to run (``ops/dispatch.py``)."""
+    return kernel_mode("selective_scan", seq_len, cfg.d_inner, cfg.mamba_d_state)
 
 
 class MambaMixer(nn.Module):
@@ -185,16 +179,16 @@ class MambaMixer(nn.Module):
             name, nn.with_logical_partitioning(init, (None,) * (len(shape) - 1) + ("mlp",)),
             shape, dt)
 
-        uz = _proj(2 * di, ("embed", "mlp"), cfg, "in_proj")(x)
+        uz = proj(2 * di, ("embed", "mlp"), cfg, "in_proj")(x)
         u, z = uz[..., :di], uz[..., di:]
         w = channels("conv_kernel", nn.initializers.lecun_normal(in_axis=0, out_axis=1), (k, di), dtype)
         b = channels("conv_bias", nn.initializers.zeros_init(), (di,), dtype)
         with jax.named_scope("conv"):
             c = causal_conv1d(u, w, b, mask)
-        rbc = _proj(r + 2 * n, ("mlp", None), cfg, "x_proj")(c)
+        rbc = proj(r + 2 * n, ("mlp", None), cfg, "x_proj")(c)
         dt = norm("dt_norm")(rbc[..., :r])
         b_in, c_in = norm("b_norm")(rbc[..., r:r + n]), norm("c_norm")(rbc[..., r + n:])
-        dt = _proj(di, (None, "mlp"), cfg, "dt_proj")(dt)
+        dt = proj(di, (None, "mlp"), cfg, "dt_proj")(dt)
         dt_bias = channels("dt_bias", dt_bias_init, (di,), jnp.float32)
         a_log = self.param(
             "A_log", nn.with_logical_partitioning(_a_log_init, ("mlp", None)), (di, n), jnp.float32)
@@ -208,7 +202,7 @@ class MambaMixer(nn.Module):
             else:  # the same three steps as one kernel, ``z`` read where it lies in ``uz``
                 y = gated_scan(c, dt, dt_bias, -jnp.exp(a_log), b_in, c_in, d_skip, uz, mask,
                                interpret=interpret)
-        return _proj(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(y)
+        return proj(cfg.hidden_size, ("mlp", "embed"), cfg, "out_proj")(y)
 
 
 class MultiQueryAttention(nn.Module):
@@ -222,12 +216,12 @@ class MultiQueryAttention(nn.Module):
         cfg = self.cfg
         h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         b, s, _ = x.shape
-        q = _proj(h * d, ("embed", "heads"), cfg, "q_proj")(x).reshape(b, s, h, d)
-        k = _proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")(x).reshape(b, s, hk, d)
-        v = _proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")(x).reshape(b, s, hk, d)
+        q = proj(h * d, ("embed", "heads"), cfg, "q_proj")(x).reshape(b, s, h, d)
+        k = proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")(x).reshape(b, s, hk, d)
+        v = proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")(x).reshape(b, s, hk, d)
         with jax.named_scope("scores"):
             out = blocked_causal_attention(q, k, v, kv_mask=mask, block_q=cfg.attn_block_q)
-        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out.reshape(b, s, h * d))
+        return proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out.reshape(b, s, h * d))
 
 
 class JambaLayer(nn.Module):
@@ -270,6 +264,5 @@ class JambaModel(nn.Module):
         n_ssm = cfg.num_hidden_layers - n_attn
         ssm_fused = n_ssm * (_fused_scan(cfg, input_ids.shape[1]) is not None)
         for name, layers, fused in (("ssm", n_ssm, ssm_fused), ("attn", n_attn, 0)):
-            self.sow("stats", name, {"layers": jnp.int32(layers), "fused": jnp.int32(fused)},
-                     reduce_fn=lambda _, new: new, init_fn=dict)
+            sow_stats(self, name, {"layers": jnp.int32(layers), "fused": jnp.int32(fused)})
         return RMSNorm(cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype), name="norm")(x)

@@ -86,7 +86,7 @@ class JointEngine:
         max_edges: int = 8192,
     ):
         from deepdfa_tpu.llm.joint import make_joint_steps
-        from deepdfa_tpu.serve.engine import _params_content_hash
+        from deepdfa_tpu.serve.engine import params_content_hash
 
         self.llm = llm
         self.llm_params = llm_params
@@ -99,7 +99,7 @@ class JointEngine:
         self.max_edges = int(max_edges)
         # same rev scheme as tier 1 (ScoringEngine): content hash of the
         # trained tree — the drift sentinel and /metrics key on it
-        self.model_rev = _params_content_hash(fusion_params)
+        self.model_rev = params_content_hash(fusion_params)
         # the trainer's own jitted eval_step — restore→rescore parity is
         # definitional, not best-effort (tx is train-step-only; None is safe)
         _, self._eval_step = make_joint_steps(llm, fusion, None, train_llm=False)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deepdfa_tpu.llm.layers import RMSNorm, apply_rope, rope_cos_sin
 from deepdfa_tpu.ops.ring_attention import full_attention, ring_attention_sharded
 
 __all__ = [
@@ -177,53 +178,6 @@ def _dense(
         ),
         name=name,
     )
-
-
-class RMSNorm(nn.Module):
-    """LLaMA RMSNorm: fp32 variance, learned scale (HF ``LlamaRMSNorm``)."""
-
-    eps: float
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        w = self.param(
-            "weight",
-            nn.with_logical_partitioning(nn.initializers.ones, ("norm",)),
-            (x.shape[-1],),
-        )
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + self.eps)
-        return (w * y.astype(self.dtype)).astype(self.dtype)
-
-
-def rope_cos_sin(
-    positions: jnp.ndarray, head_dim: int, theta: float
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Rotary tables for integer ``positions`` [..., s] -> cos/sin [..., s, d/2]."""
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(angles), jnp.sin(angles)
-
-
-def apply_rope(
-    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
-) -> jnp.ndarray:
-    """HF llama rotary convention: rotate_half over a [d/2, d/2] split.
-
-    x: [b, s, h, d]; cos/sin: [b, s, d/2] (or broadcastable).
-    """
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    cos = cos[:, :, None, :]
-    sin = sin[:, :, None, :]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
 
 
 class Attention(nn.Module):

@@ -49,15 +49,14 @@ many of its attention blocks ran the kernel (``attn``), which the joint
 trainer reads where it reads the loss — and ``routing`` — every layer's
 choices, for a comparison with a reference.
 
-**What ``pangu_moe.py`` shares** lives here once: :class:`LatentAttention`
-(with its kernel choice and its ``scores`` scope), :func:`rope_interleaved`,
-:class:`DenseFFN`, and what an expert layer does once its router has chosen —
-:func:`mask_pads`, :func:`held_experts` (the held experts' weights and
-``held_expert_ffn``) and :func:`sow_and_count` (the ``routing`` choices and
-the ``stats`` counts); :func:`embed_tokens` and :func:`sow_attention` at a
-model's two ends; :class:`HeldRange` in the configs. They read a config by the
-published names the two families have in common; each model keeps its own
-``route`` and layer class.
+**What ``pangu_moe.py`` and ``smallthinker.py`` take from here** is
+LongCat's own: :class:`LatentAttention` (with its ``scores`` scope) and
+:func:`sow_attention` (Pangu), and :func:`held_experts` (both: the held
+experts' weights and ``held_expert_ffn``). They read a config by the
+published names the families have in common; each model keeps its own
+``route`` and layer class. What no family owns — projections, the dense FFN,
+norms, the embedding, an expert layer's pad mask and counts, the configs'
+``experts_held`` — is ``llm/layers.py``'s.
 """
 
 from __future__ import annotations
@@ -71,9 +70,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepdfa_tpu.llm import roberta
-from deepdfa_tpu.llm.llama import RMSNorm, rope_cos_sin
-from deepdfa_tpu.ops.grouped import combined_positions, held_expert_ffn
+from deepdfa_tpu.llm.layers import (
+    DenseFFN,
+    HeldRange,
+    RMSNorm,
+    embed_tokens,
+    mask_pads,
+    proj,
+    rope_cos_sin,
+    sow_and_count,
+    sow_stats,
+)
+from deepdfa_tpu.ops.dispatch import kernel_mode
+from deepdfa_tpu.ops.grouped import held_expert_ffn
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention
 
 __all__ = [
@@ -84,47 +93,9 @@ __all__ = [
     "rope_interleaved",
     "route",
     "LatentAttention",
-    "DenseFFN",
-    "mask_pads",
     "held_experts",
-    "sow_and_count",
-    "HeldRange",
-    "embed_tokens",
     "sow_attention",
 ]
-
-
-class HeldRange:
-    """What both sparse decoders' configs do alike (a mixin of their frozen
-    dataclasses): ``experts_held`` checked and as a range, and a config read
-    from a published ``config.json``'s keys."""
-
-    def _check_held(self):
-        if self.experts_held is not None:
-            lo, hi = self.experts_held
-            if not 0 <= lo < hi <= self.n_routed_experts:
-                raise ValueError(f"experts_held {self.experts_held} is no range of the "
-                                 f"{self.n_routed_experts} routed experts")
-            object.__setattr__(self, "experts_held", (int(lo), int(hi)))
-
-    @property
-    def held(self) -> tuple[int, int]:
-        return self.experts_held or (0, self.n_routed_experts)
-
-    @property
-    def holds_every_expert(self) -> bool:
-        """No choice of the router's is absent or zero-compute: the held range
-        is its whole width, so a real token's k assignments are all here (the
-        case ``held_expert_ffn`` combines by a gather)."""
-        return self.held == (0, getattr(self, "router_width", self.n_routed_experts))
-
-    @classmethod
-    def from_hf_dict(cls, d: dict):
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw = {k: v for k, v in d.items() if k in names}
-        if kw.get("experts_held") is not None:
-            kw["experts_held"] = tuple(kw["experts_held"])
-        return cls(**kw)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,15 +159,6 @@ def tiny_longcat(**kw) -> LongcatConfig:
     return LongcatConfig(**defaults)
 
 
-def _proj(features: int, axes: tuple, cfg, name: str) -> nn.Dense:
-    dtype = jnp.dtype(cfg.dtype)
-    return nn.Dense(
-        features, use_bias=False, dtype=dtype, param_dtype=dtype,
-        kernel_init=nn.with_logical_partitioning(nn.initializers.lecun_normal(), axes),
-        name=name,
-    )
-
-
 def rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` (the
     DeepSeek-V2 convention). x: [..., d]; cos/sin: broadcastable [..., d/2]."""
@@ -220,19 +182,9 @@ def route(x: jnp.ndarray, w_r: jnp.ndarray, bias: jnp.ndarray, cfg: LongcatConfi
 
 def _fused_attention(cfg, seq_len: int) -> bool | None:
     """The ``interpret`` flag for the latent-attention kernel, or ``None``
-    where ``blocked_causal_attention`` has to run: no kernel here (the rule is
-    ``roberta._attention_kernel``'s: one TPU device) or a shape it does not
-    take."""
-    interpret = roberta._attention_kernel()
-    if interpret is None:
-        return None
-    # Pallas costs a second of imports: paid only where a kernel can run
-    from deepdfa_tpu.ops.latent_attention import supports
-
-    if not supports(seq_len, cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                    cfg.qk_rope_head_dim, cfg.v_head_dim):
-        return None
-    return interpret
+    where ``blocked_causal_attention`` has to run (``ops/dispatch.py``)."""
+    return kernel_mode("latent_attention", seq_len, cfg.num_attention_heads,
+                       cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim)
 
 
 class LatentAttention(nn.Module):
@@ -254,16 +206,16 @@ class LatentAttention(nn.Module):
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype=dtype, name=name)
         scale = lambda on, rank: math.sqrt(cfg.hidden_size / rank) if on else 1.0
 
-        c_q = _proj(cfg.q_lora_rank, ("embed", "latent"), cfg, "q_a_proj")(x)
+        c_q = proj(cfg.q_lora_rank, ("embed", "latent"), cfg, "q_a_proj")(x)
         c_q = (norm("q_a_norm")(c_q) * scale(cfg.mla_scale_q_lora, cfg.q_lora_rank)).astype(dtype)
-        q = _proj(h * (dn + dr), ("latent", "heads"), cfg, "q_b_proj")(c_q)
+        q = proj(h * (dn + dr), ("latent", "heads"), cfg, "q_b_proj")(c_q)
         q = q.reshape(b, s, h, dn + dr)
 
-        ckv = _proj(cfg.kv_lora_rank + dr, ("embed", "latent"), cfg, "kv_a_proj")(x)
+        ckv = proj(cfg.kv_lora_rank + dr, ("embed", "latent"), cfg, "kv_a_proj")(x)
         c_kv, k_r = ckv[..., :cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank:]
         c_kv = (norm("kv_a_norm")(c_kv)
                 * scale(cfg.mla_scale_kv_lora, cfg.kv_lora_rank)).astype(dtype)
-        kv = _proj(h * (dn + dv), ("latent", "heads"), cfg, "kv_b_proj")(c_kv)
+        kv = proj(h * (dn + dv), ("latent", "heads"), cfg, "kv_b_proj")(c_kv)
 
         cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta)  # [b, s, dr/2]
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
@@ -285,30 +237,7 @@ class LatentAttention(nn.Module):
                 out = blocked_causal_attention(
                     jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., dn:],
                     kv_mask=attn_mask, block_q=cfg.attn_block_q).reshape(b, s, h * dv)
-        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out)
-
-
-class DenseFFN(nn.Module):
-    """``W_down(silu(W_gate x) * (W_up x))``, ``width`` wide: a dense FFN, or
-    an expert every token passes through."""
-
-    cfg: Any
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        gate = _proj(self.width, ("embed", "mlp"), cfg, "gate_proj")(x)
-        up = _proj(self.width, ("embed", "mlp"), cfg, "up_proj")(x)
-        return _proj(cfg.hidden_size, ("mlp", "embed"), cfg, "down_proj")(nn.silu(gate) * up)
-
-
-def mask_pads(choice, gates, token_mask):
-    """A pad token is routed nowhere: its choices -1, its gates 0."""
-    if token_mask is None:
-        return choice, gates
-    real = token_mask.reshape(-1, 1)
-    return jnp.where(real, choice, -1), jnp.where(real, gates, 0.0)
+        return proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out)
 
 
 def held_experts(layer: nn.Module, x, choice, gates, width: int, activation=jax.nn.silu):
@@ -336,37 +265,6 @@ def held_experts(layer: nn.Module, x, choice, gates, width: int, activation=jax.
     with jax.named_scope("held_experts"):
         return held_expert_ffn(
             x, choice, gates, w_gate, w_up, w_down, lo=lo, rows=cfg.moe_chunk_rows, **other)
-
-
-def sow_and_count(layer: nn.Module, choice, computed, batch_shape: tuple, zero=None) -> dict:
-    """Sow this layer's choices ([b, s, k]; -1: a pad) into ``routing`` and
-    return its assignments by where they went: ``load_max`` the fullest held
-    expert's; ``combined`` the sorted positions the one-hot combine of
-    ``held_expert_ffn`` visited for them (``ops/grouped.py``; ``held`` over it
-    is how full its blocks were; 0 where the gather combines them);
-    ``slots`` and ``layers`` make means of sums.
-    ``zero`` marks the choices that went to zero-compute experts (none where a
-    router has none)."""
-    lo, hi = layer.cfg.held
-    n = hi - lo
-    layer.sow("routing", "choice", choice.reshape(*batch_shape, choice.shape[-1]))
-    if zero is None:
-        zero = jnp.zeros(choice.shape, bool)
-    held = (choice >= lo) & (choice < hi)
-    n_held = jnp.sum(held, dtype=jnp.int32)
-    load = jnp.sum((choice[..., None] - lo) == jnp.arange(n), axis=(0, 1), dtype=jnp.int32)
-    return {
-        "assigned": jnp.sum(choice >= 0, dtype=jnp.int32),
-        "held": n_held,
-        "zero": jnp.sum(zero, dtype=jnp.int32),
-        "absent": jnp.sum((choice >= 0) & ~held & ~zero, dtype=jnp.int32),
-        "load_max": jnp.max(load),
-        "dropped": n_held - computed,
-        "combined": (jnp.int32(0) if layer.cfg.holds_every_expert
-                     else combined_positions(n_held, layer.cfg.moe_chunk_rows)),
-        "slots": jnp.int32(n),
-        "layers": jnp.int32(1),
-    }
 
 
 class ExpertLayer(nn.Module):
@@ -424,27 +322,13 @@ class LongcatLayer(nn.Module):
         return nn.with_logical_constraint(h, ("batch", "seq", "embed")), counts
 
 
-def embed_tokens(cfg, input_ids):
-    """The decoder's embedding of ``input_ids`` (the calling model's
-    ``embed_tokens`` submodule), [b, s, hidden]."""
-    dtype = jnp.dtype(cfg.dtype)
-    x = nn.Embed(
-        cfg.vocab_size, cfg.hidden_size, dtype=dtype, param_dtype=dtype,
-        embedding_init=nn.with_logical_partitioning(
-            nn.initializers.normal(0.02), ("vocab", "embed")),
-        name="embed_tokens",
-    )(input_ids)
-    return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-
-
 def sow_attention(model: nn.Module, blocks: int, seq_len: int) -> None:
     """Which attention the step ran, into ``stats`` (``RobertaEncoder``'s
     names): the model's latent-attention ``blocks`` and how many of them ran
     the kernel — all or none, by ``_fused_attention``."""
     blocks = jnp.int32(blocks)
     fused = _fused_attention(model.cfg, seq_len) is not None
-    model.sow("stats", "attn", {"layers": blocks, "fused": blocks * fused},
-              reduce_fn=lambda _, new: new, init_fn=dict)
+    sow_stats(model, "attn", {"layers": blocks, "fused": blocks * fused})
 
 
 class LongcatModel(nn.Module):
@@ -464,7 +348,6 @@ class LongcatModel(nn.Module):
         for i in range(cfg.num_layers):
             x, counts = LongcatLayer(cfg, name=f"layers_{i}")(x, attn_mask, positions)
             totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
-        # per step, summed over layers; replaced, not appended, on each apply
-        self.sow("stats", "moe", totals, reduce_fn=lambda _, new: new, init_fn=dict)
+        sow_stats(self, "moe", totals)  # summed over layers
         sow_attention(self, 2 * cfg.num_layers, input_ids.shape[1])  # two blocks a layer
         return RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="norm")(x)

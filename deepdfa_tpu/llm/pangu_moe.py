@@ -28,11 +28,11 @@ full width and its k, the renormalisation is over all k chosen, held or not,
 and what the absent experts would add is left out. The shared expert, like
 attention and the dense FFN, is whole on every chip.
 
-What the two sparse decoders have in common is ``longcat.py``'s, once:
-``LatentAttention`` (kernel choice and ``scores`` scope included), the rope,
-``DenseFFN`` (the dense layers' and the shared expert's), the embedding and
-the ``attn`` counts at the model's two ends, the config's ``experts_held``
-half, and an expert layer's second half — the held experts' weights and grouped products, the
+From ``longcat.py`` this decoder takes ``LatentAttention`` (kernel choice and
+``scores`` scope included), the ``attn`` counts and the held experts' weights
+and grouped products; from ``llm/layers.py`` ``DenseFFN`` (the dense layers'
+and the shared expert's), the norms, the embedding, the config's
+``experts_held`` half and the rest of an expert layer's second half — the
 ``routing`` choices and the ``stats`` counts (same names; ``zero`` is always 0
 here, ``layers`` counts expert layers only, ``attn`` has one block a layer).
 The multi-token-prediction module of the published model
@@ -49,17 +49,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepdfa_tpu.llm.llama import RMSNorm
-from deepdfa_tpu.llm.longcat import (
+from deepdfa_tpu.llm.layers import (
     DenseFFN,
     HeldRange,
-    LatentAttention,
+    RMSNorm,
     embed_tokens,
-    held_experts,
     mask_pads,
     sow_and_count,
-    sow_attention,
+    sow_stats,
 )
+from deepdfa_tpu.llm.longcat import LatentAttention, held_experts, sow_attention
 
 __all__ = ["PanguMoeConfig", "PanguMoeModel", "openpangu_ultra_moe", "tiny_pangu_moe", "route"]
 
@@ -209,9 +208,7 @@ class PanguMoeModel(nn.Module):
                 x, attn_mask, positions)
             if counts is not None:  # a leading layer has no router
                 totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
-        # ``LongcatModel``'s collections: per step, summed over the expert
-        # layers; replaced, not appended, on each apply
-        if totals is not None:
-            self.sow("stats", "moe", totals, reduce_fn=lambda _, new: new, init_fn=dict)
+        if totals is not None:  # ``LongcatModel``'s collections: summed over the expert layers
+            sow_stats(self, "moe", totals)
         sow_attention(self, cfg.num_hidden_layers, input_ids.shape[1])  # one block a layer
         return RMSNorm(cfg.rms_norm_eps, dtype=dtype, name="norm")(x)

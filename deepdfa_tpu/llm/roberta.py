@@ -52,6 +52,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepdfa_tpu.llm.layers import sow_stats
+from deepdfa_tpu.ops.dispatch import kernel_mode
+
 __all__ = [
     "RobertaConfig",
     "RobertaEncoder",
@@ -145,31 +148,13 @@ def _layer_norm(eps: float) -> nn.LayerNorm:
     )
 
 
-def _attention_kernel() -> bool | None:
-    """How this process runs ``ops/flash_attention``: ``False`` compiled for
-    its one TPU device, ``None`` not at all (more devices may shard batch or
-    heads, and a Pallas call is not GSPMD-partitionable). Tests patch this to
-    return ``True``, the Pallas interpreter."""
-    if jax.default_backend() == "tpu" and jax.device_count() == 1:
-        return False
-    return None
-
-
 def _fused_attention(cfg: RobertaConfig, seq_len: int, deterministic: bool) -> bool | None:
     """The ``interpret`` flag for the attention kernels, or ``None`` where the
-    einsum-softmax path has to run: no kernel here, a shape it does not take,
-    or an attention dropout that draws a mask."""
-    interpret = _attention_kernel()
-    if interpret is None:
-        return None
-    # Pallas costs a second of imports: paid only where a kernel can run
-    from deepdfa_tpu.ops.flash_attention import supports
-
-    if not supports(seq_len, cfg.num_attention_heads, cfg.head_dim):
-        return None
+    einsum-softmax path has to run: no kernel here, a shape it does not take
+    (``ops/dispatch.py``), or an attention dropout that draws a mask."""
     if not deterministic and cfg.attention_probs_dropout_prob != 0.0:
         return None
-    return interpret
+    return kernel_mode("flash_attention", seq_len, cfg.num_attention_heads, cfg.head_dim)
 
 
 class _SelfAttention(nn.Module):
@@ -354,9 +339,7 @@ class RobertaEncoder(nn.Module):
             # which attention the step ran, for whoever applies the encoder
             # with ``mutable=["stats"]`` (the joint step: onto ``loss.sync``)
             layers = jnp.int32(cfg.num_hidden_layers)
-            self.sow("stats", "attn",
-                     {"layers": layers, "fused": layers * (fused is not None)},
-                     reduce_fn=lambda _, new: new, init_fn=dict)
+            sow_stats(self, "attn", {"layers": layers, "fused": layers * (fused is not None)})
         return x
 
 

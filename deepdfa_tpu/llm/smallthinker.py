@@ -47,7 +47,7 @@ hands out final-norm states builds no head. The config names no *secondary*
 experts and none is built.
 
 ``stats`` (read by the joint trainer where it reads the loss): ``moe`` — the
-routing counts ``longcat.sow_and_count`` gives, summed over layers, plus
+routing counts ``layers.sow_and_count`` gives, summed over layers, plus
 ``gathered``, the held assignments the gather combined, and ``gather_slots``,
 the token rows that gather visited times ``k`` (``ops/grouped.gather_slots``:
 ``assigned`` over it is how full the visited chunks were) — and ``attn`` —
@@ -70,16 +70,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepdfa_tpu.llm import roberta
-from deepdfa_tpu.llm.llama import RMSNorm, apply_rope, rope_cos_sin
-from deepdfa_tpu.llm.longcat import (
+from deepdfa_tpu.llm.layers import (
     HeldRange,
-    _proj,
+    RMSNorm,
+    apply_rope,
     embed_tokens,
-    held_experts,
     mask_pads,
+    proj,
+    rope_cos_sin,
     sow_and_count,
+    sow_stats,
 )
+from deepdfa_tpu.llm.longcat import held_experts
+from deepdfa_tpu.ops.dispatch import kernel_mode
 from deepdfa_tpu.ops.grouped import gather_slots
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, blocked_key_ranges
 
@@ -182,18 +185,9 @@ def route(n: jnp.ndarray, w_r: jnp.ndarray, cfg: SmallThinkerConfig):
 
 def _fused_attention(cfg: SmallThinkerConfig, seq_len: int) -> bool | None:
     """The ``interpret`` flag for the grouped-query attention kernel, or
-    ``None`` where ``blocked_causal_attention`` has to run: no kernel here (the
-    rule is ``roberta._attention_kernel``'s: one TPU device) or a shape it
-    does not take."""
-    interpret = roberta._attention_kernel()
-    if interpret is None:
-        return None
-    # Pallas costs a second of imports: paid only where a kernel can run
-    from deepdfa_tpu.ops.gqa_attention import supports
-
-    if not supports(seq_len, cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim):
-        return None
-    return interpret
+    ``None`` where ``blocked_causal_attention`` has to run (``ops/dispatch.py``)."""
+    return kernel_mode("gqa_attention", seq_len, cfg.num_attention_heads,
+                       cfg.num_key_value_heads, cfg.head_dim)
 
 
 def computed_pairs(cfg: SmallThinkerConfig, mask: jnp.ndarray, window: int | None) -> jnp.ndarray:
@@ -234,9 +228,9 @@ class GroupedQueryAttention(nn.Module):
         cfg = self.cfg
         h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         b, s, _ = x.shape
-        q = _proj(h * d, ("embed", "heads"), cfg, "q_proj")(x).reshape(b, s, h, d)
-        k = _proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")(x).reshape(b, s, hk, d)
-        v = _proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")(x).reshape(b, s, hk, d)
+        q = proj(h * d, ("embed", "heads"), cfg, "q_proj")(x).reshape(b, s, h, d)
+        k = proj(hk * d, ("embed", "kv_heads"), cfg, "k_proj")(x).reshape(b, s, hk, d)
+        v = proj(hk * d, ("embed", "kv_heads"), cfg, "v_proj")(x).reshape(b, s, hk, d)
         if self.rope:
             cos, sin = rope_cos_sin(positions, d, cfg.rope_theta)
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
@@ -253,7 +247,7 @@ class GroupedQueryAttention(nn.Module):
                 out = blocked_causal_attention(
                     q, k, v, kv_mask=mask, block_q=cfg.attn_block_q,
                     window=self.window).reshape(b, s, h * d)
-        return _proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out)
+        return proj(cfg.hidden_size, ("heads", "embed"), cfg, "o_proj")(out)
 
 
 class ExpertLayer(nn.Module):
@@ -322,16 +316,15 @@ class SmallThinkerModel(nn.Module):
                 cfg, bool(cfg.rope_layout[i]), cfg.window(i), name=f"layers_{i}")(
                     x, attn_mask, positions)
             totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
-        # the other decoders' collections: per step, summed over layers; replaced on each apply
-        self.sow("stats", "moe", totals, reduce_fn=lambda _, new: new, init_fn=dict)
+        sow_stats(self, "moe", totals)  # the other decoders' collections: summed over layers
         n_win = cfg.window_layers
         n_glob = cfg.num_hidden_layers - n_win
         fused = _fused_attention(cfg, s) is not None  # every layer's attention or none's
         by_kind = lambda pairs: n_glob * pairs(None) + n_win * pairs(cfg.sliding_window_size)
-        self.sow("stats", "attn", {
+        sow_stats(self, "attn", {
             "layers": jnp.int32(cfg.num_hidden_layers), "window_layers": jnp.int32(n_win),
             "fused": jnp.int32(cfg.num_hidden_layers * fused),
             "pairs_needed": by_kind(lambda w: needed_pairs(attn_mask, w)),
             "pairs_computed": by_kind(lambda w: computed_pairs(cfg, attn_mask, w)),
-        }, reduce_fn=lambda _, new: new, init_fn=dict)
+        })
         return RMSNorm(cfg.rms_norm_eps, dtype=jnp.dtype(cfg.dtype), name="norm")(x)

@@ -272,9 +272,9 @@ class HierScorer:
         self._gw, self._gb = (f32(p["pooling"]["gate"]["kernel"]),
                               f32(p["pooling"]["gate"]["bias"]))
         if model_rev is None:
-            from deepdfa_tpu.serve.engine import _params_content_hash
+            from deepdfa_tpu.serve.engine import params_content_hash
 
-            model_rev = _params_content_hash(params)
+            model_rev = params_content_hash(params)
         self.model_rev = model_rev
         self._level2 = _build_level2(level2_hidden, level2_steps)
         self._l2_params = self._init_level2()

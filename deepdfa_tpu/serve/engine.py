@@ -131,7 +131,7 @@ def _calibration_graphs(feat_keys, buckets, n_per_bucket: int = 4,
     return out
 
 
-def _params_content_hash(params) -> str:
+def params_content_hash(params) -> str:
     """Model revision: a content address of the full parameter tree
     (structure + dtypes + bytes). Two engines share warm-store keys
     exactly when they serve the same weights."""
@@ -640,7 +640,7 @@ class ScoringEngine:
         keys = tuple(feat_keys)
         buckets = tuple(buckets or serve_buckets(max_batch))
         mega = mega_bucket(max_batch) if megabatch else None
-        model_rev = _params_content_hash(params)
+        model_rev = params_content_hash(params)
 
         def _fns(scorer, ps):
             def score_fn(batch):

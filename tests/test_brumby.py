@@ -121,7 +121,7 @@ def test_the_scanned_stack_is_a_loop_over_its_layers(bench):
 def test_the_kernels_path_is_the_plain_forms(monkeypatch):
     """At heads of 128 and whole chunks the model takes the kernel (patched to
     the interpreter): its states are the plain form's to bfloat16 rounding."""
-    from deepdfa_tpu.llm import roberta
+    from deepdfa_tpu.ops import dispatch
 
     cfg = tiny_brumby(hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
                       head_dim=128, retention_chunk=32)
@@ -130,7 +130,7 @@ def test_the_kernels_path_is_the_plain_forms(monkeypatch):
     model = BrumbyModel(cfg)
     params = jax.jit(model.init)(jax.random.key(0), ids, mask)
     plain, s_plain = model.apply(params, ids, mask, mutable=["stats"])
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
     fused, s_fused = BrumbyModel(cfg).apply(params, ids, mask, mutable=["stats"])
     assert _gap(fused, plain, np.asarray(mask)) < 2e-2
     r_plain, r_fused = s_plain["stats"]["retention"], s_fused["stats"]["retention"]
@@ -161,7 +161,8 @@ def test_the_kernel_and_the_chunked_halves_skip_together(monkeypatch):
     """The kernel (interpreted) after halves in chunks of 32: a row's leading
     pad chunk is skipped by both, and its states are the plain form's over
     whole rows to bfloat16 rounding."""
-    from deepdfa_tpu.llm import brumby, roberta
+    from deepdfa_tpu.llm import brumby
+    from deepdfa_tpu.ops import dispatch
 
     cfg = tiny_brumby(hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
                       head_dim=128, retention_chunk=32)
@@ -170,7 +171,7 @@ def test_the_kernel_and_the_chunked_halves_skip_together(monkeypatch):
     model = BrumbyModel(cfg)
     params = jax.jit(model.init)(jax.random.key(0), ids, mask)
     plain, s_plain = model.apply(params, ids, mask, mutable=["stats"])
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
     monkeypatch.setattr(brumby, "DENSE_BLOCK", 32)
     fused, s_fused = BrumbyModel(cfg).apply(params, ids, mask, mutable=["stats"])
     assert _gap(fused, plain, np.asarray(mask)) < 2e-2

@@ -4,15 +4,14 @@ and any other mask, the tiles it visits against the pairs that are needed,
 the gradient (``blocked_causal_attention``'s), and the decoder taking the
 kernel where it can run."""
 
-import dataclasses
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepdfa_tpu.llm import roberta, smallthinker
+from deepdfa_tpu.llm import smallthinker
+from deepdfa_tpu.ops import dispatch
 from deepdfa_tpu.ops.gqa_attention import (
     default_tile,
     gqa_attention,
@@ -166,9 +165,9 @@ def decoder():
 @pytest.mark.parametrize("kernel,fused", [(True, 8), (None, 0)])
 def test_the_decoder_takes_the_kernel_where_it_can_run(decoder, monkeypatch, kernel, fused):
     cfg, model, params, ids, mask = decoder
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: None)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: None)
     want, _ = model.apply({"params": params}, ids, mask, mutable=["stats"])
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     got, sown = model.apply({"params": params}, ids, mask, mutable=["stats"])
     m = np.asarray(mask)
     np.testing.assert_allclose(np.asarray(got)[m], np.asarray(want)[m], atol=2e-4)
@@ -184,12 +183,3 @@ def test_the_decoder_takes_the_kernel_where_it_can_run(decoder, monkeypatch, ker
 
         blocks = lambda w: 2 * sum((e - a) * (e - lo) for a, e, lo in blocked_key_ranges(256, 16, w))
         assert attn["pairs_computed"] == 2 * blocks(None) + 6 * blocks(160)
-
-
-def test_heads_of_another_width_keep_the_blocked_path(monkeypatch):
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
-    assert smallthinker._fused_attention(smallthinker.tiny_smallthinker(), 256) is None
-    wide = dataclasses.replace(smallthinker.tiny_smallthinker(), head_dim=128)
-    assert smallthinker._fused_attention(wide, 256) is True
-    assert smallthinker._fused_attention(wide, 200) is None
-    assert smallthinker._fused_attention(smallthinker.smallthinker_21b(), 8192) is True

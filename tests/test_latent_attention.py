@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.longcat import LongcatModel, tiny_longcat
+from deepdfa_tpu.ops import dispatch
 from deepdfa_tpu.ops import latent_attention as la
 from deepdfa_tpu.ops.ring_attention import blocked_causal_attention, full_attention
 
@@ -162,7 +162,7 @@ def decoder():
 def test_the_decoder_takes_the_kernel_where_it_can_run(decoder, monkeypatch, kernel, fused):
     cfg, model, params, ids, mask = decoder
     plain = model.apply({"params": params}, ids, mask)  # the CPU: no kernel
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     apply = lambda p, i, m: model.apply({"params": p}, i, m, mutable=["stats"])
     hidden, sown = apply(params, ids, mask)
     assert {k: int(v) for k, v in sown["stats"]["attn"].items()} == {"layers": 4, "fused": fused}
@@ -174,7 +174,7 @@ def test_the_decoder_takes_the_kernel_where_it_can_run(decoder, monkeypatch, ker
 
 
 def test_tiny_longcats_own_shapes_keep_the_blocked_path(monkeypatch):
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
     cfg = tiny_longcat()
     model = LongcatModel(cfg)
     ids = jnp.zeros((1, 128), jnp.int32)
@@ -192,7 +192,7 @@ def test_the_step_says_on_loss_sync_which_attention_it_ran(decoder, monkeypatch,
     from deepdfa_tpu.llm.joint import JointConfig, JointTrainer
     from deepdfa_tpu.obs import Tracer, TrainTelemetry
 
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     cfg, model, params, _, _ = decoder
     jcfg = JointConfig(block_size=128, train_batch_size=2, eval_batch_size=2, epochs=1,
                        train_llm=False, use_gnn=False, first_eval_steps=100)

@@ -2,7 +2,6 @@
 standing in for the chip) against the einsum-softmax path it replaces on a
 TPU, when each of the two is taken, and the counter that says which ran."""
 
-import functools
 
 import flax.linen as nn
 import jax
@@ -10,8 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.roberta import RobertaEncoder, _SelfAttention, tiny_roberta
+from deepdfa_tpu.ops import dispatch
 from deepdfa_tpu.ops import flash_attention as flash
 
 HEADS, HEAD_DIM = 4, 64
@@ -27,7 +26,7 @@ def _cfg(**kw):
 @pytest.fixture
 def interpreted(monkeypatch):
     """The kernels are there, as on a one-chip TPU, under the interpreter."""
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
 
 
 def _pad_mask(layout: str, s: int) -> np.ndarray | None:
@@ -96,7 +95,7 @@ def _grad_program(cfg, s, deterministic=True):
 
 def _program_without_kernels(cfg, s, deterministic=True):
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(roberta, "_attention_kernel", lambda: None)
+        mp.setattr(dispatch, "device_mode", lambda: None)
         return _grad_program(cfg, s, deterministic)
 
 
@@ -119,7 +118,7 @@ def test_active_attention_dropout_keeps_the_einsum_softmax_program(interpreted):
 
 def test_off_the_tpu_nothing_interprets_unasked():
     """No patch here: the CPU has no kernel, whatever the shape allows."""
-    assert roberta._attention_kernel() is None
+    assert dispatch.device_mode() is None
     cfg = _cfg()
     assert flash.supports(128, cfg.num_attention_heads, cfg.head_dim)
     text = _grad_program(cfg, 128)
@@ -159,7 +158,7 @@ def test_no_score_sized_array_outside_the_kernels(interpreted):
     fused = arrays()
     assert len(fused) > 100 and not score_sized(fused)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(roberta, "_attention_kernel", lambda: None)
+        mp.setattr(dispatch, "device_mode", lambda: None)
         assert score_sized(arrays())
 
 
@@ -258,7 +257,7 @@ def tiny_trainer():
 def test_the_step_says_on_loss_sync_which_attention_it_ran(tiny_trainer, monkeypatch, kernel, fused_layers):
     from deepdfa_tpu.obs import Tracer, TrainTelemetry
 
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     make, examples, _, _ = tiny_trainer
     trainer, state = make()
     trainer.telemetry = TrainTelemetry(tracer=Tracer(proc="train", max_spans=256))
@@ -283,111 +282,3 @@ def test_an_apply_without_the_stats_collection_returns_what_it_did(tiny_trainer,
     hidden, sown = enc.apply({"params": params}, ids, pad, mutable=["stats"])
     assert np.array_equal(np.asarray(hidden), np.asarray(plain))
     assert {k: int(v) for k, v in sown["stats"]["attn"].items()} == {"layers": 2, "fused": 2}
-
-
-@pytest.fixture(scope="module")
-def one_v5e():
-    """A described (not attached) v5e chip to compile for; the TPU compiler
-    is loaded by this fixture alone, in the worker that runs this file."""
-    import os
-
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
-    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu here, or another process holds it
-        pytest.skip(f"no v5e topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def test_the_kernels_compile_for_the_v5e_at_codeberts_size(one_v5e):
-    """Mosaic takes both kernels at [16, 512, 12 x 64] float32 (what the
-    interpreter cannot show: tiling, VMEM), and the compiled backward holds no
-    temporary near a score tensor's 201 MB."""
-    b, s, heads, d = 16, 512, 12, 64
-    x = jax.ShapeDtypeStruct((b, s, heads * d), jnp.float32, sharding=one_v5e)
-    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_v5e)
-    grads = jax.grad(lambda q, k, v, seg: jnp.sum(
-        flash.flash_attention(q, k, v, seg, num_heads=heads)), argnums=(0, 1, 2))
-    compiled = jax.jit(grads).trace(x, x, x, seg).lower(lowering_platforms=("tpu",)).compile()
-    text = compiled.as_text()
-    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
-
-
-def test_the_latent_attention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
-    """Here because this file's fixture is the one place that loads the TPU
-    compiler: ``ops/latent_attention`` at [4, 2048, 64 x (192 | 128)]
-    bfloat16 (tiling, VMEM, the loop with bounds from SMEM), and nothing near
-    a query block's 268 MB of float32 scores among the temporaries."""
-    from deepdfa_tpu.ops.latent_attention import latent_attention
-
-    b, s, h = 4, 2048, 64
-    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
-    compiled = jax.jit(functools.partial(latent_attention, num_heads=h)).trace(
-        shape(b, s, h * 128), shape(b, s, h * 64), shape(b, s, 64), shape(b, s, h * 256),
-        shape(b, s, dtype=jnp.bool_),
-    ).lower(lowering_platforms=("tpu",)).compile()
-    assert "latent_attention_fwd" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
-
-
-def test_the_selective_scan_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
-    """Here for the fixture's sake too: ``ops/selective_scan.gated_scan`` at
-    [4, 2048, 5120] x 16 states, bfloat16, ``z`` as the second half of
-    ``in_proj``'s output (the strided stores and loads of the relayout, the
-    SMEM windows, VMEM), and no float32 array of the sequence's size
-    (168 MB) among the temporaries."""
-    from deepdfa_tpu.ops.selective_scan import gated_scan
-
-    b, s, d, n = 4, 2048, 5120, 16
-    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
-    f32 = functools.partial(shape, dtype=jnp.float32)
-    compiled = jax.jit(functools.partial(gated_scan, interpret=False)).trace(
-        shape(b, s, d), shape(b, s, d), f32(d), f32(d, n), shape(b, s, n), shape(b, s, n),
-        f32(d), shape(b, s, 2 * d), shape(b, s, dtype=jnp.bool_),
-    ).lower(lowering_platforms=("tpu",)).compile()
-    text = compiled.as_text()
-    assert "selective_scan_fwd" in text and " while(" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
-
-
-def test_the_grouped_query_attention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
-    """Here for the fixture's sake too: ``ops/gqa_attention`` at
-    [2, 8192, 28 | 4 x 128] bfloat16, global and with the 4096-token window
-    (tiling, the resident row of keys and values in VMEM, the three loops with
-    bounds from SMEM), and nothing near a query block's 0.94 GB of float32
-    scores among the temporaries."""
-    from deepdfa_tpu.ops.gqa_attention import gqa_attention
-
-    b, s, h, hk = 2, 8192, 28, 4
-    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
-    for window in (None, 4096):
-        compiled = jax.jit(functools.partial(gqa_attention, num_kv_heads=hk, window=window)).trace(
-            shape(b, s, h * 128), shape(b, s, hk * 128), shape(b, s, hk * 128),
-            shape(b, s, dtype=jnp.bool_),
-        ).lower(lowering_platforms=("tpu",)).compile()
-        assert "gqa_attention_fwd" in compiled.as_text()
-        assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
-
-
-def test_the_power_retention_kernel_compiles_for_the_v5e_at_the_decoders_size(one_v5e):
-    """Here for the fixture's sake too: ``ops/power_retention`` at [2, 8192,
-    40 | 8 x 128] bfloat16 (the 65 lane tiles of the feature map, the
-    [8320, 128] float32 state and phi(K) in VMEM, the chunk's decay from SMEM),
-    and no temporary of the feature map's size in HBM (phi(Q) alone would be
-    10.8 GB a layer)."""
-    from deepdfa_tpu.ops.power_retention import power_retention
-
-    b, s, h, hk, d = 2, 8192, 40, 8, 128
-    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
-    compiled = jax.jit(functools.partial(power_retention, chunk=128, interpret=False)).trace(
-        shape(b, s, h * d), shape(b, s, hk * d), shape(b, s, hk * d),
-        shape(b, s, hk, dtype=jnp.float32), shape(b, s, dtype=jnp.bool_)).lower(
-            lowering_platforms=("tpu",)).compile()
-    assert "power_retention_fwd" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20

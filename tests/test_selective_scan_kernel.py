@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepdfa_tpu.llm import roberta
 from deepdfa_tpu.llm.jamba import JambaModel, tiny_jamba
+from deepdfa_tpu.ops import dispatch
 from deepdfa_tpu.ops import selective_scan as ss
 
 B, S, D, N = 3, 64, 1024, 16
@@ -186,7 +186,7 @@ def decoder():
 def test_the_mixer_takes_the_kernel_where_it_can_run(decoder, monkeypatch, kernel, fused):
     cfg, model, params, ids, mask = decoder
     plain = model.apply({"params": params}, ids, mask)  # the CPU: no kernel
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     apply = lambda p, i, m: model.apply({"params": p}, i, m, mutable=["stats"])
     hidden, sown = apply(params, ids, mask)
     assert jax.device_get(sown["stats"]) == {
@@ -197,7 +197,7 @@ def test_the_mixer_takes_the_kernel_where_it_can_run(decoder, monkeypatch, kerne
 
 
 def test_tiny_jambas_own_shapes_keep_the_plain_form(monkeypatch):
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: True)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
     model = JambaModel(tiny_jamba(num_hidden_layers=3))  # 128 channels, 8 states
     ids = jnp.zeros((1, 32), jnp.int32)
     _, sown = model.apply(model.init(jax.random.key(0), ids), ids, mutable=["stats"])
@@ -214,7 +214,7 @@ def test_the_step_says_on_loss_sync_which_scan_it_ran(decoder, monkeypatch, kern
     from deepdfa_tpu.llm.joint import JointConfig, JointTrainer
     from deepdfa_tpu.obs import Tracer, TrainTelemetry
 
-    monkeypatch.setattr(roberta, "_attention_kernel", lambda: kernel)
+    monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     cfg, model, params, _, _ = decoder
     jcfg = JointConfig(block_size=48, train_batch_size=2, eval_batch_size=2, epochs=1,
                        train_llm=False, use_gnn=False, first_eval_steps=100)
