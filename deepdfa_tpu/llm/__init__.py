@@ -6,13 +6,14 @@ quantization (``train.py:873-885``), HF accelerate ``device_map`` layer
 placement (``train.py:883``), ``torch.nn.DataParallel`` (``train.py:936``) —
 this package uses bf16 weights GSPMD-sharded over a named mesh (tp/fsdp for
 weights, dp for batch, sp + ring attention for long sequences; a routed
-decoder is told the range of experts its chip holds). Seven encoder families
+decoder is told the range of experts its chip holds). Eight encoder families
 drive the fusion head:
 ``llama`` (causal, dense), ``roberta`` (bidirectional), ``longcat`` and
 ``pangu_moe`` (causal, latent attention, routed experts), ``jamba`` (causal,
 selective-scan layers with multi-query attention every few), ``smallthinker``
-(grouped-query attention, global and windowed, routed experts) and ``brumby``
-(power retention): what a family is lives in ``families.py``. A further one is
+(grouped-query attention, global and windowed, routed experts), ``brumby``
+(power retention) and ``zaya`` (compressed convolutional attention, top-1
+MLP-routed experts): what a family is lives in ``families.py``. A further one is
 one row there and one model file, which builds from ``layers.py`` and asks
 ``ops/dispatch.py`` whether its kernel runs.
 """
@@ -54,9 +55,13 @@ __all__ = [
     #            attention, ReLU-gated experts (frozen decoder too)
     # brumby   — degree-2 power retention (ops/power_retention.py) in a
     #            scanned stack of dense layers (frozen decoder too)
+    # zaya     — compressed convolutional attention (ops/cca.py, then
+    #            ops/gqa_attention.py), an MLP router whose state crosses the
+    #            layers, top-1 SiLU experts or a skip (frozen decoder too;
+    #            preset zaya1_8b_msivd)
     # families — what an encoder family is, and build_encoder over it: classes,
     #            weights, tokenizer, pooling, trained or frozen
     # presets  — the launch configurations: five MSIVD scripts (llama), two
     #            LineVul (roberta), a frozen decoder and its tiny twin each for
-    #            longcat, pangu_moe, jamba, smallthinker and brumby
+    #            longcat, pangu_moe, jamba, smallthinker, brumby and zaya
 ]

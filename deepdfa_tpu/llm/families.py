@@ -109,6 +109,10 @@ FAMILIES: dict[str, EncoderFamily] = {
         # head) in place of softmax attention, identical layers as one scan, frozen; no converter
         EncoderFamily("brumby", "BrumbyConfig", "BrumbyModel", "tiny_brumby", pool="last", trained=False,
                       from_seed=_sparse_from_seed),
+        # causal, compressed convolutional attention (attention in a latent, two causal convolutions), an
+        # MLP router whose state crosses the layers, top-1 SiLU experts or a skip, frozen; no converter
+        EncoderFamily("zaya", "ZayaConfig", "ZayaModel", "tiny_zaya", pool="last", trained=False,
+                      from_seed=_sparse_from_seed),
     ]
 }
 

@@ -12,8 +12,10 @@ a grouped-query decoder of global and windowed layers that holds every one of
 its routed experts (``"smallthinker"``: SmallThinker-21BA3B, 12 of its 52
 layers, on 8k-token blocks; and its twin), or a dense decoder whose attention is
 degree-2 power retention (``"brumby"``: Brumby-14B-Base, 10 of its 40 layers,
-on the same 8k-token blocks; and its twin).
-What a family is lives in ``llm/families.py`` (seven today; a further
+on the same 8k-token blocks; and its twin), or a decoder of compressed
+convolutional attention and top-1 MLP-routed experts (``"zaya"``: ZAYA1-8B,
+20 of its 40 layers, on the same 8k-token blocks; and its twin).
+What a family is lives in ``llm/families.py`` (eight today; a further
 one is one row there and one model file); a preset's
 ``llm`` must be its family's config class, checked at construction.
 ``finetuned`` marks presets that start from a LoRA-finetuned model
@@ -40,6 +42,7 @@ from deepdfa_tpu.llm.longcat import LongcatConfig, longcat_flash, tiny_longcat
 from deepdfa_tpu.llm.pangu_moe import PanguMoeConfig, openpangu_ultra_moe, tiny_pangu_moe
 from deepdfa_tpu.llm.roberta import RobertaConfig, codebert_base
 from deepdfa_tpu.llm.smallthinker import SmallThinkerConfig, smallthinker_21b, tiny_smallthinker
+from deepdfa_tpu.llm.zaya import ZayaConfig, tiny_zaya, zaya1_8b
 
 __all__ = ["JointPreset", "PRESETS"]
 
@@ -49,7 +52,7 @@ class JointPreset:
     name: str
     # encoder_family's class
     llm: (LlamaConfig | RobertaConfig | LongcatConfig | PanguMoeConfig | JambaConfig | SmallThinkerConfig
-          | BrumbyConfig)
+          | BrumbyConfig | ZayaConfig)
     joint: JointConfig
     finetuned: bool  # load LoRA-finetuned weights first (--finetuned_path)
     mesh: MeshConfig
@@ -60,7 +63,8 @@ class JointPreset:
     # (causal, selective-scan layers + multi-query attention, frozen),
     # "smallthinker" (causal, global / windowed grouped-query attention +
     # routed experts all held, frozen), "brumby" (causal, degree-2 power
-    # retention + dense MLPs, frozen)
+    # retention + dense MLPs, frozen), "zaya" (causal, compressed
+    # convolutional attention + top-1 MLP-routed experts all held, frozen)
     encoder_family: str = "llama"
 
     def __post_init__(self):
@@ -318,6 +322,38 @@ PRESETS: dict[str, JointPreset] = {
             mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
             dataset="bigvul",
             encoder_family="brumby",
+        ),
+        # the same job on the same 8k-token inputs over ZAYA1-8B at its
+        # published widths: 20 of its 40 layers (compressed convolutional
+        # attention, 8 query heads over 2 key/value heads of 128 in a latent;
+        # an MLP router carrying its state across layers; top-1 of 16 SiLU
+        # experts 2,048 wide or a skip) as one stage of a two-stage pipeline,
+        # ALL 16 experts of each layer on this chip (no layer is divided), the
+        # whole tied vocabulary: 9.40 GB of bfloat16 weights
+        JointPreset(
+            name="zaya1_8b_msivd",
+            llm=zaya1_8b(num_hidden_layers=20, experts_held=(0, 16)),
+            joint=JointConfig(
+                block_size=8192, epochs=1, train_batch_size=2, eval_batch_size=2,
+                learning_rate=1e-6, dataset_style="precisebugs",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="precisebugs",
+            encoder_family="zaya",
+        ),
+        # the same code at test size (CPU): 4 layers, 8 experts and the skip
+        JointPreset(
+            name="tiny_zaya_msivd",
+            llm=tiny_zaya(vocab_size=2048),
+            joint=JointConfig(
+                block_size=64, epochs=1, train_batch_size=4, eval_batch_size=4,
+                learning_rate=1e-4, dataset_style="bigvul",
+            ),
+            finetuned=False,
+            mesh=MeshConfig(dp=-1, fsdp=1, tp=1, sp=1),
+            dataset="bigvul",
+            encoder_family="zaya",
         ),
     ]
 }

@@ -30,12 +30,13 @@ def _spec(tree):
 
 
 def test_the_table_is_the_seven_families():
-    assert NAMES == ["brumby", "jamba", "llama", "longcat", "pangu_moe", "roberta", "smallthinker"]
+    assert NAMES == ["brumby", "jamba", "llama", "longcat", "pangu_moe", "roberta", "smallthinker",
+                     "zaya"]
     for name, fam in FAMILIES.items():
         assert fam.name == name and fam.pool in ("cls", "last")
     # the families without a converter are the ones built from a seed
     assert [n for n in NAMES if FAMILIES[n].from_checkpoint is None] == [
-        "brumby", "jamba", "longcat", "pangu_moe", "smallthinker"]
+        "brumby", "jamba", "longcat", "pangu_moe", "smallthinker", "zaya"]
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -72,7 +73,7 @@ def test_family_agrees_with_its_presets_and_the_benchmark_configs(name):
     # where the head pools: what the cells of that family state
     cells = {"roberta": ["linevul", "linevul-fusion"], "longcat": ["longcat-flash-msivd"],
              "pangu_moe": ["openpangu-ultra-msivd"], "jamba": ["jamba2-3b-msivd"], "smallthinker": ["smallthinker-21b-msivd"],
-             "brumby": ["brumby-14b-msivd"], "llama": []}[name]
+             "brumby": ["brumby-14b-msivd"], "zaya": ["zaya1-8b-msivd"], "llama": []}[name]
     for cell in cells:
         cfg = json.loads((BENCH_CONFIGS / f"{cell}.json").read_text())
         assert cfg["head"]["pool"] == fam.pool, cell

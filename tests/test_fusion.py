@@ -376,7 +376,8 @@ def test_presets_cover_reference_launch_scripts():
     from deepdfa_tpu.llm.presets import PRESETS
 
     # 5 MSIVD launch scripts + the 2 LineVul configs of BASELINE config #3
-    # + the five frozen decoders (three routed-expert, one state-space, one power-retention), each with its test-size twin
+    # + the six frozen decoders (three routed-expert, one state-space, one power-retention, one
+    # CCA with top-1 routed experts), each with its test-size twin
     assert set(PRESETS) == {
         "bigvul_ft_bigvul", "pretrained_bigvul", "pb_ft_pb",
         "pb_ft_pb_noexpl", "pretrained_pb", "linevul", "linevul_fusion",
@@ -385,6 +386,7 @@ def test_presets_cover_reference_launch_scripts():
         "jamba2_3b_msivd", "tiny_jamba_msivd",
         "smallthinker_21b_msivd", "tiny_smallthinker_msivd",
         "brumby_14b_msivd", "tiny_brumby_msivd",
+        "zaya1_8b_msivd", "tiny_zaya_msivd",
     }
     p = PRESETS["bigvul_ft_bigvul"]
     assert p.llm.hidden_size == 4096 and p.joint.block_size == 256

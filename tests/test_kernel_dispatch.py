@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deepdfa_tpu.llm import brumby, jamba, longcat, pangu_moe, roberta, smallthinker
+from deepdfa_tpu.llm import brumby, jamba, longcat, pangu_moe, roberta, smallthinker, zaya
 from deepdfa_tpu.ops import dispatch
 from deepdfa_tpu.ops import flash_attention as flash
 
@@ -39,6 +39,9 @@ RULES = [
                  id="selective_scan-jamba"),
     pytest.param(brumby._fused_retention, (brumby.brumby_14b(), 8192),
                  (brumby.tiny_brumby(), 8192), id="power_retention-brumby"),
+    # CCA's latent: 8 query heads over 2 key/value heads of 128 at the cell's 8,192 positions
+    pytest.param(zaya._fused_attention, (zaya.zaya1_8b(), 8192), (zaya.tiny_zaya(), 8192),
+                 id="gqa_attention-zaya"),
 ]
 
 
@@ -178,3 +181,20 @@ def test_the_power_retention_kernel_compiles_for_the_v5e_at_the_decoders_size(on
             lowering_platforms=("tpu",)).compile()
     assert "power_retention_fwd" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_the_grouped_query_attention_kernel_compiles_for_the_v5e_at_ccas_size(one_v5e):
+    """Here for the fixture's sake too: ``ops/gqa_attention`` as
+    ``llm/zaya.py`` calls it, at [2, 8192, 8 | 2 x 128] bfloat16 (four query
+    heads a key/value head: a grid step's tile is 512 lanes wide), and
+    nothing near a query block's float32 scores among the temporaries."""
+    from deepdfa_tpu.ops.gqa_attention import gqa_attention
+
+    b, s, h, hk = 2, 8192, 8, 2
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    compiled = jax.jit(functools.partial(gqa_attention, num_kv_heads=hk)).trace(
+        shape(b, s, h * 128), shape(b, s, hk * 128), shape(b, s, hk * 128),
+        shape(b, s, dtype=jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert "gqa_attention_fwd" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20

@@ -1,5 +1,5 @@
 """The joint train step of every family that a benchmark cell runs, lowered
-and pinned by digest: CodeBERT trained, the five frozen decoders, each at a
+and pinned by digest: CodeBERT trained, the six frozen decoders, each at a
 tiny size whose widths its kernel takes, with the kernel rule as the CPU
 gives it (the plain forms) and forced to the Pallas interpreter. A change
 that means to move where the model code and its kernels meet, and nothing
@@ -31,6 +31,8 @@ STEPS = {
     ("smallthinker", "interpret"): "6b8d21348c0bdd65dd46404fa98bb44c8e8e59c596c96db884a2489a5b73961b",
     ("brumby", "cpu"): "63697d414b7fa79c3b77349be8759ba40f97db1df7facc38fa44c89258774e7b",
     ("brumby", "interpret"): "8d3afaf8b36036c6de4c64c8a320dbc3f984f4a9b95097914784e64d8f68fb23",
+    ("zaya", "cpu"): "753d11c43620f3d5021a737848b885262fbca9c9f5ab082495af922be96cb5a9",
+    ("zaya", "interpret"): "6577148e565ffbddbb6496e5e944f80053ca96b935f1419968e778a5f0bb293a",
 }
 
 
@@ -70,6 +72,12 @@ def _family(name: str):
         return BrumbyModel(tiny_brumby(
             vocab_size=256, hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
             head_dim=128, retention_chunk=32)), 64
+    if name == "zaya":
+        from deepdfa_tpu.llm.zaya import ZayaModel, tiny_zaya
+
+        return ZayaModel(tiny_zaya(
+            vocab_size=256, hidden_size=256, num_hidden_layers=2, head_dim=128,
+            layer_types=("hybrid",) * 2)), 128
     raise ValueError(name)
 
 
