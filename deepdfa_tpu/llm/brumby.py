@@ -32,7 +32,8 @@ real token.** ``pre`` (input norm, the q, k, v and gate projections, q/k
 norms, RoPE, gates) and ``post`` (``o_proj``, residual, post-attention norm,
 MLP, residual) read no other position, so each runs as a lifted ``nn.scan``
 over chunks of :data:`DENSE_BLOCK` positions (a whole row where that does not
-divide the block; :func:`dense_chunks`), the layer's weights broadcast, and an
+divide the block; ``llm/layers.py``'s ``dense_chunks``), the layer's weights
+broadcast, and an
 ``nn.cond`` computes a chunk only where it holds a real token. A chunk of pads
 yields zeros from ``pre`` and its own input from ``post``: no real token reads
 a pad. The retention between the halves takes whole rows. ``init`` runs both
@@ -72,6 +73,7 @@ from deepdfa_tpu.llm.layers import (
     DenseFFN,
     RMSNorm,
     apply_rope,
+    dense_chunks,
     embed_tokens,
     proj,
     rope_cos_sin,
@@ -203,16 +205,6 @@ class PowerRetention(nn.Module):
                                    interpret=_fused_retention(cfg, mask.shape[1]))
 
 
-def dense_chunks(mask: jnp.ndarray):
-    """``(live [chunks] bool, width)``: a layer's position-wise halves walk
-    the ``b * s`` positions of ``mask`` [b, s] in chunks of ``width`` —
-    :data:`DENSE_BLOCK` where it divides ``s``, else the whole row — and a
-    chunk is live where it holds a real token."""
-    b, s = mask.shape
-    width = DENSE_BLOCK if s % DENSE_BLOCK == 0 else s
-    return jnp.any(mask.reshape(b * s // width, width), axis=1), width
-
-
 def _over_live(layer: nn.Module, half, skip, live, *chunks):
     """``half(layer, *c)`` for the chunks ``c`` of ``chunks`` (each [n, ...],
     chunk by chunk on the leading axis) where ``live``, ``skip(*c)``
@@ -230,7 +222,7 @@ class BrumbyLayer(nn.Module):
     """The retention and the MLP, each behind a norm: one body of the
     ``nn.scan`` (carry ``x``; ``mask`` and ``positions`` broadcast). The two
     position-wise halves, ``pre`` and ``post``, run over the chunks of
-    positions that hold a real token (:func:`dense_chunks`); the retention
+    positions that hold a real token (``dense_chunks``); the retention
     between them over whole rows."""
 
     cfg: BrumbyConfig
@@ -265,7 +257,7 @@ class BrumbyLayer(nn.Module):
         h, hk, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         dtype = jnp.dtype(cfg.dtype)
         b, s, _ = x.shape
-        live, width = dense_chunks(mask)
+        live, width = dense_chunks(mask, DENSE_BLOCK)
         cut = lambda a: a.reshape(live.shape[0], 1, width, *a.shape[2:])  # a chunk is a [1, width] row
         rows = lambda a: a.reshape(b, s, *a.shape[3:])
         nothing = lambda c, _: (  # a chunk of pads yields nothing a real token reads
@@ -305,7 +297,7 @@ class BrumbyModel(nn.Module):
         layers, chunk = cfg.num_hidden_layers, cfg.retention_chunk
         fused = _fused_retention(cfg, s) is not None  # every layer's or none's
         computed = layers * chunks_computed(attn_mask, chunk, fused)
-        live, width = dense_chunks(attn_mask)
+        live, width = dense_chunks(attn_mask, DENSE_BLOCK)
         sow_stats(self, "retention", {
             "layers": jnp.int32(layers), "fused": jnp.int32(layers * fused),
             "chunks_needed": layers * chunks_needed(attn_mask, chunk),

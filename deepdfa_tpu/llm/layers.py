@@ -10,7 +10,10 @@ an expert layer ``experts_held``, ``n_routed_experts``, ``moe_chunk_rows``).
   and :func:`sow_and_count`: what an expert layer does once its router has
   chosen, beside ``longcat.held_experts``;
 - :func:`sow_stats`: the one form of the ``stats`` channel, the counts a
-  step hands the joint trainer where it reads the loss.
+  step hands the joint trainer where it reads the loss;
+- :func:`dense_chunks` and :func:`over_live`: a layer's position-wise halves
+  over the chunks of positions that hold a real token (a decoder passes its
+  own chunk width).
 
 Which kernel an op runs is not decided here but in ``ops/dispatch.py``.
 """
@@ -37,6 +40,8 @@ __all__ = [
     "mask_pads",
     "sow_and_count",
     "sow_stats",
+    "dense_chunks",
+    "over_live",
 ]
 
 
@@ -203,3 +208,32 @@ def sow_stats(module: nn.Module, name: str, counts: dict) -> None:
     whoever applies the model with ``mutable=["stats"]`` (the joint step:
     onto ``loss.sync``): one step's, replaced, not appended, on each apply."""
     module.sow("stats", name, counts, reduce_fn=lambda _, new: new, init_fn=dict)
+
+
+def dense_chunks(mask: jnp.ndarray, block: int):
+    """``(live [chunks] bool, width)``: a layer's position-wise halves walk
+    the ``b * s`` positions of ``mask`` [b, s] in chunks of ``width`` —
+    ``block`` where it divides ``s``, else the whole row — and a chunk is
+    live where it holds a real token."""
+    b, s = mask.shape
+    width = block if s % block == 0 else s
+    return jnp.any(mask.reshape(b * s // width, width), axis=1), width
+
+
+def over_live(half, skip, live, *chunks):
+    """``half(*c)`` for the chunks ``c`` of ``chunks`` (each [n, ...], chunk
+    by chunk on the leading axis) where ``live``, ``skip(*c)`` elsewhere,
+    stacked on the leading axis. The stack starts as ``skip`` of every chunk;
+    one loop over the chunks writes ``half``'s result over a live chunk's in
+    place (a ``lax.cond`` a trip), so the product that makes a chunk writes it
+    straight into the stack (a scan stacking each trip's output copies it
+    there in a pass of its own)."""
+    def trip(i, out):
+        def write(out):
+            new = half(*(c[i] for c in chunks))
+            return jax.tree.map(lambda o, v: jax.lax.dynamic_update_index_in_dim(o, v, i, 0),
+                                out, new)
+
+        return jax.lax.cond(live[i], write, lambda o: o, out)
+
+    return jax.lax.fori_loop(0, live.shape[0], trip, jax.vmap(skip)(*chunks))

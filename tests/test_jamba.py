@@ -105,7 +105,10 @@ def test_hidden_states_match_the_reference(bench, dtype, limit):
     assert gap < limit, gap
     assert gap > 1e-4 or dtype == "float32"  # the bfloat16 case did compute in bfloat16
     stats = jax.device_get(sown["stats"])
-    assert stats == {"ssm": {"layers": 6, "fused": 0}, "attn": {"layers": 2, "fused": 0}}
+    real, dense = int(mask.sum()), int(mask.any(1).sum()) * 64  # whole rows: 512 does not tile 64
+    assert stats == {
+        "ssm": {"layers": 6, "fused": 0, "tokens_real": 6 * real, "tokens_dense": 6 * dense},
+        "attn": {"layers": 2, "fused": 0, "tokens_real": 2 * real, "tokens_dense": 2 * dense}}
 
 
 def _scan_inputs(seed=0, b=2, s=64, d=24, n=16):
@@ -230,6 +233,144 @@ def test_a_later_token_changes_no_earlier_state(bench, kind):
     assert not np.allclose(a[t], b[t]) and not np.allclose(a[-1], b[-1])  # and does reach the later ones
 
 
+# -- the chunked halves ------------------------------------------------------------
+
+CHUNK_PADS = ((0, 5, 16, 63), (64, 17, 40, 0))  # left pads a row at the block of 64: one row
+# wholly real, one wholly pad, a chunk's edge, a chunk's middle
+
+
+def _chunked_against_whole(cfg, params, ids, mask):
+    """Each layer of the stack, chunked and over the whole block, on the
+    whole block's states of the layer before: ``[(chunked, whole)]``."""
+    x = jnp.take(params["embed_tokens"]["embedding"], ids, axis=0)
+    out = []
+    for i in range(cfg.num_hidden_layers):
+        layer, p = JambaLayer(cfg, cfg.is_attention(i)), {"params": params[f"layers_{i}"]}
+        chunked = layer.apply(p, x, mask)
+        x = layer.apply(p, x, mask, method=JambaLayer.whole)
+        out.append((np.asarray(chunked), np.asarray(x)))
+    return out
+
+
+@pytest.mark.parametrize("pads", CHUNK_PADS)
+def test_the_chunked_halves_are_the_whole_blocks(bench, pads, monkeypatch):
+    """Chunks of 16 positions at the block of 64, the plain scan: every
+    layer's real tokens are the whole-block layer's, bit for bit in float32
+    (a chunk's products are the same rows' products)."""
+    from deepdfa_tpu.llm import jamba
+
+    monkeypatch.setattr(jamba, "DENSE_BLOCK", 16)
+    ids = jnp.asarray(bench["data"]["input_ids"][:len(pads)])
+    mask = np.arange(64)[None] >= np.asarray(pads)[:, None]
+    for i, (chunked, whole) in enumerate(_chunked_against_whole(
+            bench["llm_cfg"], bench["params"], ids, jnp.asarray(mask))):
+        assert np.array_equal(chunked[mask], whole[mask]), i
+
+
+def test_the_kernel_and_the_chunked_halves_are_the_whole_blocks(monkeypatch):
+    """The same at the widths the scan's kernel takes (1,024 channels, 16
+    states), the kernel under the Pallas interpreter: chunks of 16 at the
+    block of 48, two Mamba layers and one attention layer. To float32
+    rounding, not bit for bit: the CPU multiplies 512-wide rows in another
+    order for 16 rows than for 144 (read: 1.9e-6 a token at worst)."""
+    from deepdfa_tpu.llm import jamba
+    from deepdfa_tpu.ops import dispatch
+
+    cfg = tiny_jamba(hidden_size=512, num_hidden_layers=3, mamba_d_state=16, mamba_dt_rank=16)
+    ids = jnp.asarray(np.random.default_rng(0).integers(3, cfg.vocab_size, (3, 48)))
+    mask = np.arange(48)[None] >= np.array([[0], [19], [32]])
+    params = nn.meta.unbox(JambaModel(cfg).init(jax.random.key(0), ids, jnp.asarray(mask)))
+    monkeypatch.setattr(dispatch, "device_mode", lambda: True)
+    monkeypatch.setattr(jamba, "DENSE_BLOCK", 16)
+    assert jamba._fused_scan(cfg, 48) is True
+    for i, (chunked, whole) in enumerate(_chunked_against_whole(
+            cfg, params["params"], ids, jnp.asarray(mask))):
+        assert _gap(chunked, whole, mask) < 5e-6, i
+
+
+@pytest.mark.parametrize("attention", [False, True])
+def test_a_chunk_of_pads_leaves_the_layers_input_as_it_was(bench, attention, monkeypatch):
+    """Where a chunk holds no real token the layer hands its input on: ``post``
+    is skipped there, and whatever ``pre`` would have made is never read."""
+    from deepdfa_tpu.llm import jamba
+
+    monkeypatch.setattr(jamba, "DENSE_BLOCK", 16)
+    layer, p = _one_layer(bench, attention)
+    x = np.random.default_rng(5).normal(size=(2, 64, 64)).astype(np.float32)
+    mask = np.arange(64)[None] >= np.array([[40], [0]])
+    out = np.asarray(layer.apply({"params": p}, x, mask))
+    assert np.array_equal(out[0, :32], x[0, :32])  # row 0's two leading chunks: pads alone
+    assert not np.allclose(out[0, 32:40], x[0, 32:40])  # a live chunk's pads are computed
+    assert not np.allclose(out[1], x[1])
+
+
+# the tree ``init`` draws for ``tiny_jamba`` at key 0, as the whole-block layers drew it:
+# shape and the sum of |leaf| (float64) of the first Mamba and the first attention layer
+TINY_TREE = {
+    "embed_tokens/embedding": ((320, 64), 325.0097708759616),
+    "layers_0/ffn_norm/weight": ((64,), 64.0),
+    "layers_0/input_norm/weight": ((64,), 64.0),
+    "layers_0/mamba/A_log": ((128, 8), 1357.3891906738281),
+    "layers_0/mamba/D": ((128,), 128.0),
+    "layers_0/mamba/b_norm/weight": ((8,), 8.0),
+    "layers_0/mamba/c_norm/weight": ((8,), 8.0),
+    "layers_0/mamba/conv_bias": ((128,), 0.0),
+    "layers_0/mamba/conv_kernel": ((4, 128), 209.25795178834233),
+    "layers_0/mamba/dt_bias": ((128,), 609.1960985660553),
+    "layers_0/mamba/dt_norm/weight": ((8,), 8.0),
+    "layers_0/mamba/dt_proj/kernel": ((8, 128), 295.32936238746333),
+    "layers_0/mamba/in_proj/kernel": ((64, 256), 1681.1223501330014),
+    "layers_0/mamba/out_proj/kernel": ((128, 64), 589.3698186514939),
+    "layers_0/mamba/x_proj/kernel": ((128, 24), 222.27097380716327),
+    "layers_0/mlp/down_proj/kernel": ((128, 64), 591.1019345631685),
+    "layers_0/mlp/gate_proj/kernel": ((64, 128), 828.0084562472442),
+    "layers_0/mlp/up_proj/kernel": ((64, 128), 839.8800835712937),
+    "layers_2/attn/k_proj/kernel": ((64, 16), 108.47727585904067),
+    "layers_2/attn/o_proj/kernel": ((64, 64), 420.76580575139815),
+    "layers_2/attn/q_proj/kernel": ((64, 64), 410.5947795348138),
+    "layers_2/attn/v_proj/kernel": ((64, 16), 104.83856603857566),
+    "layers_2/ffn_norm/weight": ((64,), 64.0),
+    "layers_2/input_norm/weight": ((64,), 64.0),
+    "layers_2/mlp/down_proj/kernel": ((128, 64), 594.4988215171029),
+    "layers_2/mlp/gate_proj/kernel": ((64, 128), 843.5672124120247),
+    "layers_2/mlp/up_proj/kernel": ((64, 128), 841.765269592217),
+    "norm/weight": ((64,), 64.0),
+}
+
+
+def test_init_draws_the_leaves_it_always_drew():
+    from flax.traverse_util import flatten_dict
+
+    cfg = tiny_jamba()
+    ids = jnp.zeros((1, 32), jnp.int32)
+    leaves = flatten_dict(nn.meta.unbox(JambaModel(cfg).init(
+        jax.random.key(0), ids, ids == 0)["params"]), sep="/")
+    kind = lambda i: "layers_2/" if cfg.is_attention(i) else "layers_0/"
+    assert {n: x.shape for n, x in leaves.items()} == {
+        n.replace(kind(i), f"layers_{i}/"): shape for n, (shape, _) in TINY_TREE.items()
+        for i in range(cfg.num_hidden_layers) if not n.startswith("layers_") or n.startswith(kind(i))}
+    for n, (_, total) in TINY_TREE.items():
+        assert np.abs(np.asarray(leaves[n], np.float64)).sum() == pytest.approx(total, rel=1e-6), n
+
+
+@pytest.mark.parametrize("block,live", [
+    (16, 4 + 3 + 0 + 1),  # chunks of 16 at the block of 64: the live chunks of each row
+    (512, 3),             # 512 does not tile 64: whole rows, the three that hold a real token
+])
+def test_the_dense_tokens_are_the_live_chunks_times_their_width(bench, block, live, monkeypatch):
+    from deepdfa_tpu.llm import jamba
+
+    monkeypatch.setattr(jamba, "DENSE_BLOCK", block)
+    mask = np.arange(64)[None] >= np.array([[0], [20], [64], [50]])
+    ids = jnp.asarray(bench["data"]["input_ids"][:4])
+    _, sown = bench["model"].apply({"params": bench["params"]}, ids, mask, mutable=["stats"])
+    width = min(block, 64)
+    stats = jax.device_get(sown["stats"])
+    for name, layers in (("ssm", 6), ("attn", 2)):
+        assert stats[name] == {"layers": layers, "fused": 0, "tokens_real": layers * (64 + 44 + 14),
+                               "tokens_dense": layers * live * width}
+
+
 # -- the published sizes --------------------------------------------------------
 
 
@@ -294,7 +435,8 @@ def test_the_stats_count_26_scans_and_2_attention_layers_at_the_published_depth(
     assert isinstance(plain, jax.Array)  # an apply without mutable=["stats"] sows nothing
     _, sown = model.apply({"params": params}, ids, mask, mutable=["stats"])
     assert jax.device_get(sown["stats"]) == {
-        "ssm": {"layers": 26, "fused": 0}, "attn": {"layers": 2, "fused": 0}}
+        "ssm": {"layers": 26, "fused": 0, "tokens_real": 26 * 8, "tokens_dense": 26 * 8},
+        "attn": {"layers": 2, "fused": 0, "tokens_real": 2 * 8, "tokens_dense": 2 * 8}}
 
 
 def test_seeded_weights_start_where_mamba_1_starts():
@@ -364,17 +506,23 @@ def test_train_steps_match_the_reference_closely(followed, number, limit):
     assert followed["nums"][number] <= limit
 
 
-def test_the_mixer_counts_are_on_the_loss_sync_spans(followed):
+def test_the_mixer_counts_are_on_the_loss_sync_spans(bench, followed):
     t0, t1 = followed["ran"]
     spans = [s for s in followed["driver"].trainer.telemetry.tracer.spans()
              if s.name == "loss.sync" and "ssm_layers" in s.attrs and t0 <= s.start_s <= t1]
     assert len(spans) >= followed["driver"].setup_steps - 1  # the step in flight is not read
+    block = bench["cfg"]["train"]["block_size"]  # 512 does not tile it: whole rows
     for s in spans:
         counts = {k: v for k, v in s.attrs.items() if k.startswith(("ssm_", "attn_"))}
+        real, dense = counts.pop("ssm_tokens_real"), counts.pop("ssm_tokens_dense")
+        assert counts.pop("attn_tokens_real") * 6 == real * 2 and real % 6 == 0 and real > 0
+        assert counts.pop("attn_tokens_dense") * 6 == dense * 2 and dense % (6 * block) == 0
+        assert real <= dense <= 6 * 4 * block
         assert counts == {"ssm_layers": 6, "ssm_fused": 0, "attn_layers": 2, "attn_fused": 0}
         assert not any(k.startswith("moe_") for k in s.attrs)  # nothing is routed
     tie = followed["run"]["readings"]["tie"]
     assert tie["counts"] == tie["step_counts"] and len(tie["counts"]) == 3
+    assert all(len(c) == 8 for c in tie["counts"])  # the four counts of each kind, on both sides
 
 
 # -- the reference's control and faults, and faults planted in the program -------

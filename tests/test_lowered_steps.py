@@ -25,8 +25,8 @@ STEPS = {
     ("longcat", "interpret"): "8ec61373ad3ada7cd1fd9a66f523e4f274b4382be18d93f922702a08e00a5711",
     ("pangu_moe", "cpu"): "e52fc33787687b5c33a1cc8387864e585f5c64b6241a08b3f5acac411a0ced85",
     ("pangu_moe", "interpret"): "955a168beba0e1d47573d5e355dead51d3fc24d15b17ec3a224b322c700856dd",
-    ("jamba", "cpu"): "159c9b527386fced247921475e4f5d3ea8bd059d642bd5057d70d15d6645235e",
-    ("jamba", "interpret"): "2bb6fb7f37e644424280e82db154d426375be252275809e6c306f41c1b5c3f6e",
+    ("jamba", "cpu"): "d11a713fcbdaf50ae52237c0ca48a63f3a68dcd9ba13cd279592ae5383b55f97",
+    ("jamba", "interpret"): "9dbdab9f3bd87a6140452575bf75ba49207a14b5deded9405e0156833272e4ca",
     ("smallthinker", "cpu"): "a2abdcd109eb91693864ef1686a8b316a7f5cd82763cd6b933fed66eb3987cfb",
     ("smallthinker", "interpret"): "6b8d21348c0bdd65dd46404fa98bb44c8e8e59c596c96db884a2489a5b73961b",
     ("brumby", "cpu"): "63697d414b7fa79c3b77349be8759ba40f97db1df7facc38fa44c89258774e7b",

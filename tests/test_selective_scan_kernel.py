@@ -189,8 +189,10 @@ def test_the_mixer_takes_the_kernel_where_it_can_run(decoder, monkeypatch, kerne
     monkeypatch.setattr(dispatch, "device_mode", lambda: kernel)
     apply = lambda p, i, m: model.apply({"params": p}, i, m, mutable=["stats"])
     hidden, sown = apply(params, ids, mask)
+    real, dense = int(mask.sum()), 3 * 48  # 512 does not tile 48: whole rows
     assert jax.device_get(sown["stats"]) == {
-        "ssm": {"layers": 2, "fused": fused}, "attn": {"layers": 1, "fused": 0}}
+        "ssm": {"layers": 2, "fused": fused, "tokens_real": 2 * real, "tokens_dense": 2 * dense},
+        "attn": {"layers": 1, "fused": 0, "tokens_real": real, "tokens_dense": dense}}
     assert str(jax.make_jaxpr(apply)(params, ids, mask)).count("selective_scan_fwd") == fused
     real = np.asarray(mask)
     np.testing.assert_allclose(np.asarray(hidden)[real], np.asarray(plain)[real], atol=2e-4)
@@ -202,7 +204,8 @@ def test_tiny_jambas_own_shapes_keep_the_plain_form(monkeypatch):
     ids = jnp.zeros((1, 32), jnp.int32)
     _, sown = model.apply(model.init(jax.random.key(0), ids), ids, mutable=["stats"])
     assert jax.device_get(sown["stats"]) == {
-        "ssm": {"layers": 2, "fused": 0}, "attn": {"layers": 1, "fused": 0}}
+        "ssm": {"layers": 2, "fused": 0, "tokens_real": 2 * 32, "tokens_dense": 2 * 32},
+        "attn": {"layers": 1, "fused": 0, "tokens_real": 32, "tokens_dense": 32}}
 
 
 @pytest.mark.parametrize("kernel,fused", [(True, 2), (None, 0)])
@@ -230,6 +233,10 @@ def test_the_step_says_on_loss_sync_which_scan_it_ran(decoder, monkeypatch, kern
     assert len(syncs) == 2
     for span in syncs:
         counts = {k: v for k, v in span.attrs.items() if k.startswith(("ssm_", "attn_"))}
+        real = counts.pop("attn_tokens_real")
+        assert 0 < real <= 2 * 48 and counts.pop("ssm_tokens_real") == 2 * real
+        assert counts.pop("attn_tokens_dense") == 2 * 48  # both rows hold a real token
+        assert counts.pop("ssm_tokens_dense") == 2 * 2 * 48
         assert counts == {"ssm_layers": 2, "ssm_fused": fused, "attn_layers": 1, "attn_fused": 0}
     assert all(np.isfinite(e["train_loss"]) for e in trainer.history if "train_loss" in e)
 
